@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import DigcSpec, digc
-from repro.core.perfmodel import tpu_digc_estimate
+from repro.core.perfmodel import TPUConfig, device_peaks, tpu_digc_estimate
 from benchmarks.common import emit, timeit
 
 
@@ -37,7 +37,7 @@ def _hillclimb():
     for name, kw in iters:
         e = tpu_digc_estimate(**w, **kw)
         base = base or e["latency_s"]
-        mxu = e["flops"] / 197e12 / e["latency_s"]
+        mxu = e["flops"] / TPUConfig().peak_flops / e["latency_s"]
         emit(f"kernel/{name}_us", e["latency_s"] * 1e6,
              f"bound={e['bound']};speedup={base/e['latency_s']:.2f}x;mxu_frac={mxu:.3f}")
 
@@ -93,9 +93,12 @@ def _group_w_ablation(x, k, iters=2):
 
 
 def _merge_sweep(smoke: bool = False, iters=2):
-    """Kernel merge-strategy sweep: measured interpret wall-clock (the
-    CPU floor) plus the modeled TPU bound/mxu_frac for the same config —
-    the derived fields are what the interpret numbers cannot show."""
+    """Kernel merge-strategy sweep: measured wall-clock (interpret mode
+    off-TPU: the CPU floor) plus the modeled TPU bound/mxu_frac for the
+    same config. On a TPU the row also carries the measured MXU share
+    against that chip's published peak (an unlisted chip raises)."""
+    dev = jax.devices()[0]
+    peaks = device_peaks(dev.device_kind) if dev.platform == "tpu" else None
     n = 256 if smoke else 1024
     kd, bn, bm = 16, 128, 256  # bm % kd == 0, bm // kd >= 2
     rng = np.random.default_rng(1)
@@ -116,9 +119,12 @@ def _merge_sweep(smoke: bool = False, iters=2):
             bucket_rounds=kw.get("bucket_rounds", 0),
             kernel_merge=kw["kernel_merge"],
         )
-        mxu = e["flops"] / 197e12 / e["latency_s"]
+        mxu = e["flops"] / TPUConfig().peak_flops / e["latency_s"]
+        mode = ("interpret" if peaks is None else
+                f"compiled;measured_mxu_frac="
+                f"{e['flops'] / peaks['bf16_flops'] / t:.3f}")
         emit(f"kernel/merge_{name}_us", t * 1e6,
-             f"interpret;N={n};kd={kd};bn={bn};bm={bm};"
+             f"{mode};N={n};kd={kd};bn={bn};bm={bm};"
              f"bound={e['bound']};tpu_model_us={e['latency_s'] * 1e6:.1f};"
              f"mxu_frac={mxu:.3f}")
 

@@ -43,10 +43,7 @@ cost is what batching amortizes.
 """
 
 import dataclasses
-import json
 import os
-import subprocess
-import sys
 import tempfile
 import time
 
@@ -478,49 +475,6 @@ def _run_clustertick_profile(smoke):
         )
 
 
-_SHARDED_SNIPPET = """
-import json, time
-import numpy as np, jax, jax.numpy as jnp
-from repro.models import vig
-from repro.models.module import init_params
-from repro.serve.engine import VigRequest, VigServeEngine
-
-res, waves = {res}, {waves}
-cfg = vig.VIG_VARIANTS["vig_ti_iso"].replace(
-    image_size=res, patch=4, embed_dims=(32,), depths=(2,),
-    num_classes=10, k=9, digc_impl="ring")
-params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(0))
-rng = np.random.default_rng(0)
-slots = 4
-images = [rng.standard_normal((res, res, 3)).astype(np.float32)
-          for _ in range(slots)]
-wave_t = [[(w + i) % slots for i in range(size)]
-          for w, size in enumerate(waves)]
-
-def trace(eng):
-    uid = 0
-    t0 = time.perf_counter()
-    for wave in wave_t:
-        for tenant in wave:
-            eng.submit(VigRequest(uid=uid, image=images[tenant],
-                                  tenant=tenant))
-            uid += 1
-        eng.step()
-    return time.perf_counter() - t0
-
-out = {{}}
-for ndev in (1, {ndev}):
-    mesh = jax.make_mesh((ndev,), ("ring",))
-    eng = VigServeEngine(cfg, params, digc_impl="ring", autotune=False,
-                         buckets=(1, 2, 4), mesh=mesh, mesh_axis="ring")
-    cold = trace(eng)
-    warm = trace(eng)
-    out[ndev] = dict(cold=cold, warm=warm, programs=eng.compile_count,
-                     n=cfg.base_grid ** 2, requests=sum(waves))
-print("SHARDED_JSON " + json.dumps(out))
-"""
-
-
 def _run_multires(smoke):
     """Multi-resolution lattice rows (DESIGN.md §13): one
     ``image_sizes=`` engine serving a mixed ragged-resolution trace vs
@@ -647,46 +601,50 @@ def _run_multires(smoke):
 
 def _run_sharded(smoke):
     """Sharded-trace rows: the same ragged multi-tenant trace served by
-    the mesh-native ring engine on a 1-device vs a 4-device (forced
-    host) mesh. On CPU fake devices this measures the shard_map
-    orchestration overhead, not ICI overlap — the row exists so the
-    perf record tracks the sharded serving path (DESIGN.md §10) and a
-    real-TPU run lands in the same rows. Runs in a subprocess because
-    the forced device count must be set before jax initializes."""
-    ndev = 4
+    the mesh-native ring engine on a 1-device mesh and on a mesh over
+    every local device (up to 4), in this process and on the devices
+    JAX was given (DESIGN.md §10). On a TPU host that is the chips and
+    their ICI; on a one-device host only the 1-device row exists."""
+    from repro.launch.mesh import make_mesh
+    from repro.models import vig
+    from repro.models.module import init_params
+    from repro.serve.engine import VigRequest, VigServeEngine
+
     res, waves = (32, (1, 3, 2, 4)) if smoke else (64, (1, 3, 4, 2, 4, 1))
-    code = _SHARDED_SNIPPET.format(res=res, waves=tuple(waves), ndev=ndev)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = (
-        "src" + os.pathsep + env.get("PYTHONPATH", "")
-    ).rstrip(os.pathsep)
-    proc = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        env=env, timeout=1200,
-    )
-    if proc.returncode != 0:
-        # No row on failure: a NaN us_per_call would make the dumped
-        # BENCH_digc.json invalid per-spec JSON. Comment lines are the
-        # established skip idiom (bench_strategies' ring row).
-        tail = (proc.stderr.strip().splitlines()[-1][:160]
-                if proc.stderr.strip() else "subprocess failed")
-        print(f"# serve/sharded: skipped ({tail})", flush=True)
-        return
-    payload = next(
-        line for line in proc.stdout.splitlines()
-        if line.startswith("SHARDED_JSON ")
-    )
-    rows = json.loads(payload[len("SHARDED_JSON "):])
-    for ndev_s, r in sorted(rows.items(), key=lambda kv: int(kv[0])):
-        total = r["requests"]
-        for phase in ("cold", "warm"):
+    cfg = vig.VIG_VARIANTS["vig_ti_iso"].replace(
+        image_size=res, patch=4, embed_dims=(32,), depths=(2,),
+        num_classes=10, k=9, digc_impl="ring")
+    params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    slots = 4
+    images = [rng.standard_normal((res, res, 3)).astype(np.float32)
+              for _ in range(slots)]
+    wave_t = [[(w + i) % slots for i in range(size)]
+              for w, size in enumerate(waves)]
+
+    def trace(eng):
+        uid = 0
+        t0 = time.perf_counter()
+        for wave in wave_t:
+            for tenant in wave:
+                eng.submit(VigRequest(uid=uid, image=images[tenant],
+                                      tenant=tenant))
+                uid += 1
+            eng.step()
+        return time.perf_counter() - t0
+
+    total = sum(waves)
+    platform = jax.devices()[0].platform
+    for ndev in sorted({1, min(4, jax.device_count())}):
+        mesh = make_mesh((ndev,), ("ring",))
+        eng = VigServeEngine(cfg, params, digc_impl="ring", autotune=False,
+                             buckets=(1, 2, 4), mesh=mesh, mesh_axis="ring")
+        for phase, secs in (("cold", trace(eng)), ("warm", trace(eng))):
             emit(
-                f"serve/sharded_mesh{ndev_s}_{phase}_us",
-                r[phase] / total * 1e6,
-                f"N={r['n']};requests={total};programs={r['programs']};"
-                f"ring mesh={ndev_s} forced-host dev;per-request"
+                f"serve/sharded_mesh{ndev}_{phase}_us", secs / total * 1e6,
+                f"N={cfg.base_grid ** 2};requests={total};"
+                f"programs={eng.compile_count};ring mesh={ndev} "
+                f"{platform} dev;per-request"
                 + (";incl. compiles" if phase == "cold" else ";steady"),
             )
 

@@ -17,6 +17,7 @@ from benchmarks import (
     bench_serve,
     bench_strategies,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "table1": bench_table1_cycles.run,
@@ -102,6 +103,7 @@ def check_regress(baseline_path: str) -> list[str]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", nargs="*", default=list(SUITES))
     ap.add_argument("--fast", action="store_true",

@@ -1,0 +1,456 @@
+"""Chip smoke test: ViG-Ti served through ``VigServeEngine`` on a TPU.
+
+    python chip_smoke.py             # one chip
+    python chip_smoke.py --chips 4   # the mesh-native ring tier on four
+
+One chip: ``vig_ti_iso`` at its published widths (embed 192, depth 12,
+k=9, 1000 classes, patch 16) with seeded random weights answers a few
+requests from a few tenants at 224 px (N=196) and 896 px (N=3136), on
+the ``pallas`` tier (both fused kernels on the path) and on the default
+``blocked`` tier, with the engine's default guards. It then checks the
+fused top-k kernel's neighbour recall at N=3136 against the plain
+reference, both at full f32 matmul precision, beside a bf16 control.
+
+``--chips 4``: the same requests, at full f32 matmul precision, through
+the mesh-native ``ring`` engine on a 4-chip mesh and through the
+one-chip ``blocked`` engine. Each engine's neighbour lists, captured
+from its own cell program (``VigServeEngine.cell_graphs``), must be the
+exact top-k of their block's features up to f32 ties; the two engines'
+lists and logits are compared, and a frozen-gallery ring state entry
+shows that its ``sq_y`` norms span the four chips.
+
+Every phase must pass: no TPU, a raised error, a failed request, a
+descent of the degradation ladder or a recall under its floor exits
+non-zero. The last line of stdout is then, and only then,
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+from pathlib import Path
+
+SRC = Path(__file__).resolve().parent / "src"
+SEED = 0
+SIZES = (224, 896)  # N = 196 and N = 3136 patch nodes
+TENANTS = ("A", "B", "C")
+ROUNDS = 2  # the second round serves every tenant warm
+RECALL_N, RECALL_D = 3136, 192  # the recall check's workload (N = 896px)
+# Neighbour recall floor of the compiled f32 kernel vs the reference,
+# both at `highest` matmul precision. On a v5e the f32 kernel reads
+# 1.000000 there, and its mxu_bf16 path (bf16 contraction; the control
+# run beside it) reads 0.996280; the floor sits between the two, so a
+# contraction that drops to bf16 fails it. At the default precision the
+# TPU rounds every f32 matmul operand to bf16 (one MXU pass; Pallas and
+# XLA alike): the kernel, the blocked tier and the reference all read
+# 0.997201 there, which is printed, not gated.
+RECALL_FLOOR = 0.999
+# The --chips 4 phase serves both engines at `highest` precision, so
+# that their neighbour lists can be held to f32 ties: a list member may
+# differ from the exact one only where two candidates' float64
+# distances agree within TIE_RTOL of |x|^2 + |y|^2. f32 rounding of a
+# D=192 contraction is ~1e-7 of that scale (a v5e reads at most
+# 1.42e-07); a wrong neighbour is off by the spacing of its row's
+# order statistics.
+TIE_RTOL = 1e-4
+# Ring-engine vs one-chip blocked-engine logits, relative to max |logit|.
+# Where both engines' lists agree exactly at every block, only the
+# rounding of the dense layers separates them: LOGIT_TIGHT (a v5e reads
+# 8.85e-04 at 224 px, the CPU 5e-06; the chip's larger gap is not
+# explained yet). Where a row
+# lists two tied candidates in swapped order, the stride-d dilation may
+# keep a different one of them; with random weights one such swap in
+# any of the 12 blocks moves the logits by percents (0.2 of ~10 on a
+# v5e), so those logits are held to LOGIT_RTOL only, and the lists
+# themselves carry the check.
+LOGIT_TIGHT = 1e-3
+LOGIT_RTOL = 0.05
+
+
+class SmokeError(Exception):
+    """A phase ran but its result is wrong."""
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise SmokeError(what)
+
+
+class CompileClock:
+    """Seconds the backend compiler (XLA and Mosaic) spends, and the
+    persistent-cache hits, from JAX's own monitoring events. Tracing
+    and lowering are left out: their events nest (a jitted kernel
+    inside a jitted forward) and would count twice."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.seconds = 0.0
+        self.cache_hits = 0
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event, duration, **_):
+        if event == self.EVENT:
+            self.seconds += duration
+
+    def _event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+
+    def take(self) -> tuple[float, int]:
+        out = (self.seconds, self.cache_hits)
+        self.seconds, self.cache_hits = 0.0, 0
+        return out
+
+
+def _model():
+    import jax
+
+    from repro.models import vig
+    from repro.models.module import init_params
+
+    cfg = vig.VIG_VARIANTS["vig_ti_iso"]
+    check((cfg.embed_dims, cfg.depths, cfg.k, cfg.num_classes, cfg.patch)
+          == ((192,), (12,), 9, 1000, 16), f"vig_ti_iso widths: {cfg}")
+    params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(SEED))
+    return cfg, params
+
+
+def _requests(cfg):
+    """Per size, ROUNDS waves of one request per tenant (same images
+    for every engine, so their answers can be compared)."""
+    import numpy as np
+
+    rng = np.random.default_rng(SEED)
+    return {
+        size: [[rng.standard_normal((size, size, cfg.in_chans))
+                .astype(np.float32) for _ in TENANTS]
+               for _ in range(ROUNDS)]
+        for size in SIZES
+    }
+
+
+def _serve(name, eng, images, clock):
+    """Serve every wave at every size; returns {size: logits (R, T, C)}."""
+    import numpy as np
+
+    from repro.serve.engine import VigRequest
+
+    clock.take()
+    logits = {}
+    uid = 0
+    t0 = time.perf_counter()
+    for size in SIZES:
+        per_round = []
+        for wave in images[size]:
+            reqs = [VigRequest(uid=uid + i, image=img, tenant=t)
+                    for i, (t, img) in enumerate(zip(TENANTS, wave))]
+            uid += len(reqs)
+            for r in reqs:
+                eng.submit(r)
+            done = eng.run()
+            check(len(done) == len(reqs)
+                  and all(r.logits is not None for r in reqs),
+                  f"{name}: {sum(r.logits is None for r in reqs)} of "
+                  f"{len(reqs)} requests at {size}px failed")
+            per_round.append(np.stack([r.logits for r in reqs]))
+        logits[size] = np.stack(per_round)
+    wall = time.perf_counter() - t0
+    compile_s, hits = clock.take()
+    st = eng.stats()
+    degrades = [f["kind"] for f in st["faults"]
+                if f["kind"] in ("compile_degrade", "deadline_degrade")]
+    print(f"{name}: served {st['requests_served']} failed "
+          f"{st['requests_failed']} programs {st['compiled_programs']} "
+          f"backend_compile_s {compile_s:.1f} cache_hits {hits} "
+          f"wall_s {wall:.1f} "
+          f"fallback_level {st['fallback_level']} "
+          f"fallback_impl {st.get('fallback_impl')}", flush=True)
+    check(st["requests_failed"] == 0 and st["quarantines"] == 0,
+          f"{name}: {st['requests_failed']} failed requests")
+    check(st["fallback_level"] == 0 and not degrades,
+          f"{name}: descended the degradation ladder ({degrades}, "
+          f"fallback_impl={st.get('fallback_impl')})")
+    for size, lg in logits.items():
+        check(lg.shape == (ROUNDS, len(TENANTS), eng.cfg.num_classes),
+              f"{name}: logits shape {lg.shape} at {size}px")
+        check(bool(np.isfinite(lg).all()),
+              f"{name}: non-finite logits at {size}px")
+    print(f"{name}: logits finite, shape ({eng.cfg.num_classes},) per "
+          "request", flush=True)
+    return logits
+
+
+def _recall(a, b) -> float:
+    import numpy as np
+
+    a, b = np.asarray(a).reshape(-1, a.shape[-1]), np.asarray(b)
+    b = b.reshape(-1, b.shape[-1])
+    hits = sum(len(set(r) & set(s)) for r, s in zip(a, b))
+    return hits / a.size
+
+
+def one_chip(clock) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import digc
+    from repro.kernels import ops
+    from repro.kernels import ref as kref
+    from repro.serve.engine import VigServeEngine
+
+    cfg, params = _model()
+    images = _requests(cfg)
+    # autotune=False: the tuner measures candidate schedules, which is
+    # a benchmark's job; the tiers serve their default tiles here.
+    results = {}
+    for tier in ("pallas", "blocked"):
+        eng = VigServeEngine(cfg, params, digc_impl=tier, autotune=False,
+                             image_sizes=SIZES)
+        results[tier] = _serve(f"engine[{tier}]", eng, images, clock)
+        if tier == "pallas":
+            text = eng.program_text(eng.bucket_for(len(TENANTS)), SIZES[-1])
+            n = text.count("tpu_custom_call")
+            print(f"engine[pallas]: {n} tpu_custom_call in the "
+                  f"{SIZES[-1]}px serving program", flush=True)
+            check(n > 0, "the pallas tier's program holds no Pallas kernel")
+        del eng
+
+    # Fused top-k kernel vs the plain reference at N=3136, D=192, k=9.
+    n, d, k = RECALL_N, RECALL_D, cfg.k
+    x = jnp.asarray(np.random.default_rng(SEED + 1).standard_normal(
+        (1, n, d)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        _, i_ref = kref.digc_reference(x[0], x[0], kd=k)
+        i_kernel = ops.digc_topk(x, x, k=k)
+        i_bf16 = ops.digc_topk(x, x, k=k, mxu_bf16=True)
+    i_default = ops.digc_topk(x, x, k=k)
+    i_blocked = digc(x, k=k, impl="blocked")
+    rec = {name: _recall(np.asarray(i_ref), np.asarray(i)[0])
+           for name, i in (("kernel", i_kernel), ("bf16", i_bf16),
+                           ("default", i_default), ("blocked", i_blocked))}
+    print(f"recall@{k} N={n} D={d} vs the highest-precision reference: "
+          f"pallas kernel at highest {rec['kernel']:.6f} (floor "
+          f"{RECALL_FLOOR}), mxu_bf16 control {rec['bf16']:.6f}; at the "
+          f"default precision pallas kernel {rec['default']:.6f}, blocked "
+          f"tier {rec['blocked']:.6f} (not gated)", flush=True)
+    check(rec["kernel"] >= RECALL_FLOOR,
+          f"pallas kernel recall {rec['kernel']:.6f} < floor {RECALL_FLOOR}")
+    check(rec["bf16"] < RECALL_FLOOR,
+          f"the floor {RECALL_FLOOR} does not fail a bf16 contraction "
+          f"({rec['bf16']:.6f})")
+    for s in SIZES:
+        diff = float(np.abs(results["pallas"][s] - results["blocked"][s])
+                     .max())
+        print(f"pallas vs blocked engine {s}px: max |logit diff| "
+              f"{diff:.3e}, max |logit| "
+              f"{float(np.abs(results['blocked'][s]).max()):.3e} "
+              "(information only: neighbour ties may differ)", flush=True)
+
+
+def four_chips(clock) -> None:
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import DigcSpec, digc
+    from repro.core.state import DigcState, state_entry
+    from repro.launch.mesh import make_mesh
+    from repro.models import vig
+    from repro.serve.engine import VigServeEngine
+
+    cfg, params = _model()
+    images = _requests(cfg)
+    mesh = make_mesh((4,), ("ring",))
+    served, graphs = {}, {}
+    # Both engines serve at `highest` precision (see TIE_RTOL); the
+    # neighbour lists come from each engine's own cell program, re-run
+    # on the first wave with its DIGC calls captured.
+    with jax.default_matmul_precision("highest"):
+        ring = VigServeEngine(cfg.replace(digc_impl="ring"), params,
+                              digc_impl="ring", autotune=False, mesh=mesh,
+                              mesh_axis="ring", image_sizes=SIZES)
+        served["ring"] = _serve("engine[ring x4]", ring, images, clock)
+        graphs["ring"] = {s: ring.cell_graphs(images[s][0], s)
+                          for s in SIZES}
+        for size in SIZES:
+            for key, entry in ring.slot_state(size).entries.items():
+                for f in dataclasses.fields(entry):
+                    v = getattr(entry, f.name)
+                    if v is not None:
+                        print(f"ring state {size}px {key}.{f.name} "
+                              f"{tuple(v.shape)}: {v.sharding}", flush=True)
+        del ring
+        blocked = VigServeEngine(cfg, params, autotune=False,
+                                 image_sizes=SIZES)
+        served["blocked"] = _serve("engine[blocked x1]", blocked, images,
+                                   clock)
+        graphs["blocked"] = {s: blocked.cell_graphs(images[s][0], s)
+                             for s in SIZES}
+        del blocked
+
+    for size in SIZES:
+        rows = vig.count_digc_work(cfg, grid=size // cfg.patch)
+        scale = float(np.abs(served["blocked"][size]).max())
+        probe_ok = True
+        for name in ("ring", "blocked"):
+            probe, calls = graphs[name][size]
+            err = float(np.abs(probe - served[name][size][0]).max())
+            probe_ok &= err <= LOGIT_TIGHT * scale
+            print(f"{name} {size}px: the captured tick's logits vs the "
+                  f"served ones: max |diff| {err:.3e}", flush=True)
+            check(err <= LOGIT_RTOL * scale,
+                  f"{name}: the captured tick is not the served one")
+            check(len(calls) == len(rows),
+                  f"{name}: {len(calls)} DIGC calls, want {len(rows)}")
+            gap = max(_exact(nodes, co, idx, row["dilation"])
+                      for (_, nodes, co, idx), row in zip(calls, rows))
+            print(f"{name} {size}px: every block's lists are the exact "
+                  f"stride-d top-k up to a distance gap of {gap:.2e} of "
+                  f"|x|^2 + |y|^2 (tie rtol {TIE_RTOL})", flush=True)
+            check(gap <= TIE_RTOL,
+                  f"{name} {size}px: a neighbour list is not exact")
+        same = True
+        for bi, (r, b) in enumerate(zip(graphs["ring"][size][1],
+                                        graphs["blocked"][size][1])):
+            differ = int((r[3] != b[3]).any(-1).sum())
+            same &= differ == 0
+            print(f"{size}px block {bi}: ring vs blocked lists differ in "
+                  f"{differ} of {r[3].shape[0] * r[3].shape[1]} rows; "
+                  f"max |nodes diff| {float(np.abs(r[1] - b[1]).max()):.2e}",
+                  flush=True)
+        # Block 0 sees the same stem output on both engines: their lists
+        # must agree up to ties there.
+        (_, h, _, i_r), (_, _, _, i_b) = (graphs["ring"][size][1][0],
+                                          graphs["blocked"][size][1][0])
+        _agree(f"{size}px block 0", h, h, i_r, i_b)
+        err = float(np.abs(served["ring"][size]
+                           - served["blocked"][size]).max())
+        tol = LOGIT_TIGHT if same and probe_ok else LOGIT_RTOL
+        print(f"logits {size}px: max |ring - blocked| {err:.3e}, "
+              f"max |logit| {scale:.3e}, rtol {tol}", flush=True)
+        check(err <= tol * scale, f"ring logits off by {err:.3e} at {size}px")
+
+    # A frozen-gallery entry keeps its co-node norms sharded over the
+    # ring: each chip holds a quarter of sq_y, cold and warm.
+    rng = np.random.default_rng(SEED + 2)
+    x = jnp.asarray(rng.standard_normal((2, 3136, 192)), jnp.float32)
+    y = jnp.asarray(rng.standard_normal((2, 3136, 192)), jnp.float32)
+    st = DigcState.init({"g": state_entry(sq_y_shape=(2, 3136), rows=2,
+                                          mesh=mesh, axis_name="ring")})
+    spec = DigcSpec(impl="ring", mesh=mesh, axis_name="ring", k=cfg.k)
+    with jax.default_matmul_precision("highest"):
+        i_blk = digc(x, y, k=cfg.k, impl="blocked")
+        for phase in ("cold", "warm"):
+            i_ring, st = digc(x, y, spec=spec, state=st, state_key="g")
+            sq = st.entries["g"].sq_y
+            shards = {s.device.id: tuple(s.data.shape)
+                      for s in sq.addressable_shards}
+            print(f"gallery {phase}: sq_y {tuple(sq.shape)} {sq.sharding} "
+                  f"shards {shards}", flush=True)
+            check(len(shards) == 4
+                  and all(s == (2, 3136 // 4) for s in shards.values()),
+                  f"sq_y is not split over 4 chips: {shards}")
+            _agree(f"gallery {phase} k={cfg.k}", x, y, i_ring, i_blk)
+
+
+def _exact(nodes, co_nodes, idx, dilation) -> float:
+    """Largest gap, over all (B, N) rows, between the float64 distances
+    of a row's k listed neighbours, sorted, and the row's exact float64
+    order statistics 0, d, ..., (k-1)d (the stride-d pick of its top
+    k*d), relative to |x|^2 + |y|^2. Tie order cannot move an order
+    statistic, so an exact list has gap ~0 and a wrong member the
+    spacing of its row's order statistics."""
+    import numpy as np
+
+    x = np.asarray(nodes, np.float64)
+    y = x if co_nodes is None else np.asarray(co_nodes, np.float64)
+    k = idx.shape[-1]
+    d = dilation if k * dilation <= y.shape[1] else 1
+    gap = 0.0
+    for b in range(x.shape[0]):
+        sx, sy = (x[b] ** 2).sum(-1), (y[b] ** 2).sum(-1)
+        dist = sx[:, None] - 2.0 * x[b] @ y[b].T + sy[None, :]
+        want = np.sort(np.partition(dist, k * d - 1, axis=1)[:, :k * d],
+                       axis=1)[:, ::d]
+        got = np.sort(np.take_along_axis(dist, idx[b], axis=1), axis=1)
+        gap = max(gap, float((np.abs(got - want).max(1)
+                              / (sx + sy.max())).max()))
+    return gap
+
+
+def _agree(name, x, y, i_a, i_b) -> None:
+    """Two neighbour lists of the same (B, N) rows agree up to ties:
+    wherever they differ, the float64 distances of their members,
+    sorted, are equal within TIE_RTOL of |x|^2 + |y|^2."""
+    import numpy as np
+
+    x = np.asarray(x, np.float64)
+    y = np.asarray(y, np.float64)
+    i_a, i_b = np.asarray(i_a), np.asarray(i_b)
+    differ = np.argwhere((i_a != i_b).any(-1))
+    gap = 0.0
+    for b, n in differ:
+        d_a = np.sort(((y[b, i_a[b, n]] - x[b, n]) ** 2).sum(-1))
+        d_b = np.sort(((y[b, i_b[b, n]] - x[b, n]) ** 2).sum(-1))
+        members = np.concatenate([i_a[b, n], i_b[b, n]])
+        scale = (x[b, n] ** 2).sum() + (y[b, members] ** 2).sum(-1).max()
+        gap = max(gap, float(np.abs(d_a - d_b).max() / scale))
+    print(f"{name}: ring vs blocked, {len(differ)} of "
+          f"{i_a.shape[0] * i_a.shape[1]} rows differ, max distance gap "
+          f"{gap:.2e} of |x|^2 + |y|^2 (tie rtol {TIE_RTOL})", flush=True)
+    check(gap <= TIE_RTOL, f"{name}: neighbour lists differ beyond ties")
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    args = ap.parse_args(argv)
+    if not (SRC / "repro").is_dir():
+        print(f"chip_smoke: FAIL: no repro package under {SRC}; run from a "
+              "checkout of the repository", file=sys.stderr)
+        return 2
+    if str(SRC) not in sys.path:
+        sys.path.insert(0, str(SRC))
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"chip_smoke: FAIL: no TPU; JAX found {dev.platform} "
+              f"({dev.device_kind})", file=sys.stderr)
+        return 1
+    if jax.device_count() < args.chips:
+        print(f"chip_smoke: FAIL: --chips {args.chips} needs {args.chips} "
+              f"devices; JAX found {jax.device_count()}", file=sys.stderr)
+        return 1
+    from repro.launch.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    print(f"device_kind {dev.device_kind} platform {dev.platform} "
+          f"count {jax.device_count()} jax {jax.__version__}", flush=True)
+    print(f"compile cache {cache_dir}", flush=True)
+    clock = CompileClock()
+    t0 = time.perf_counter()
+    try:
+        (one_chip if args.chips == 1 else four_chips)(clock)
+    except SmokeError as e:
+        print(f"chip_smoke: FAIL: {e}", file=sys.stderr)
+        return 1
+    print(f"total_s {time.perf_counter() - t0:.1f}", flush=True)
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": jax.device_count()}}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

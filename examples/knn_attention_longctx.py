@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.knn_attention import knn_attention_mha
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def dense_causal(q, k, v):
@@ -27,6 +28,7 @@ def dense_causal(q, k, v):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--seq", type=int, default=2048)
     ap.add_argument("--heads", type=int, default=4)

@@ -18,11 +18,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import DigcSpec, digc
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import vig
 from repro.models.module import init_params
 
 
 def main():
+    enable_compile_cache()
     debug_nans = jax.config.jax_debug_nans
     print(f"jax_debug_nans={debug_nans} "
           f"(JAX_DEBUG_NANS={os.environ.get('JAX_DEBUG_NANS', '<unset>')})")

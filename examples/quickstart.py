@@ -17,11 +17,13 @@ from repro.core import (
     edge_list,
     fpga_cycles,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import vig
 from repro.models.module import init_params
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
 
     # --- 1. DIGC on the paper's ViG-Tiny workload: N=M=196, D=192 -----

@@ -12,11 +12,13 @@ import jax
 
 from repro.configs import get_smoke
 from repro.launch.api import get_api
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.module import init_params
 from repro.serve.engine import Request, ServeEngine
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--requests", type=int, default=6)

@@ -25,6 +25,7 @@ import argparse
 import numpy as np
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import vig
 from repro.models.module import init_params
 from repro.serve.engine import VigServeEngine
@@ -55,6 +56,7 @@ def _report(tag, eng, ticks):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--image-size", type=int, default=32)
     ap.add_argument("--patch", type=int, default=8)

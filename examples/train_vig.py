@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from repro.ckpt import checkpoint as ckpt
 from repro.core import available_impls, get_builder
 from repro.data.pipeline import DataConfig, image_pipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import vig
 from repro.models.module import init_params
 from repro.train.optimizer import OptConfig
@@ -24,6 +25,7 @@ from repro.train.trainer import init_train_state, make_train_step
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--batch", type=int, default=8)

@@ -58,11 +58,12 @@ from jax import lax
 
 from repro.core.packedkey import (
     INT_BIG,
+    gmm_merge,
     idx_bits_for,
-    merge_sorted,
+    key_less,
+    lsm_topk,
     next_pow2,
     pack_keys,
-    topk_keys,
     unpack_keys,
 )
 
@@ -180,9 +181,9 @@ def merge_topk_xla(run_d, run_i, blk_d, blk_i, kd: int):
 def merge_packed_xla(run_k, blk_k, kd: int):
     """Packed-key sorted two-level merge — the XLA mirror of the Pallas
     kernel's bitonic LSM+GMM, built from the same ``core/packedkey``
-    networks: reduce the tile to its sorted top-kd_pad
-    (``topk_keys``), then one O(log kd_pad) ``merge_sorted`` against
-    the running buffer. ``run_k`` must be sorted ascending (the scan
+    networks: reduce the tile to its top-kd_pad sorted descending
+    (``lsm_topk``), then one O(log kd_pad) ``gmm_merge`` into the
+    running buffer. ``run_k`` must be sorted ascending (the scan
     invariant: the INT_BIG init is sorted, and this returns sorted).
     Keys are unique (index bits), so the result is exactly the kd
     lexicographically-smallest (dist, idx) pairs of the union."""
@@ -193,7 +194,9 @@ def merge_packed_xla(run_k, blk_k, kd: int):
                              INT_BIG, jnp.int32)],
             axis=-1,
         )
-    merged = merge_sorted(run_k[..., :kd_pad], topk_keys(blk_k, kd_pad))
+    top = lsm_topk((blk_k,), kd_pad, key_less, (INT_BIG,), descending=True)
+    (merged,) = gmm_merge((run_k[..., :kd_pad],), (top[0][..., :kd_pad],),
+                          kd_pad, key_less)
     return merged[..., :kd]
 
 

@@ -23,16 +23,16 @@ path only where two distances agree in their truncated high bits
 paths; ``kernels/digc_topk.py`` and ``core/engine.py`` expose packing
 as an opt-in knob (``DigcSpec.packed`` / ``merge="packed"``).
 
-This module also hosts the **bitonic sort/merge networks** shared by
-the Pallas kernel's LSM+GMM stages and the engine's packed merge
-(``sort_keys`` / ``merge_sorted`` / ``topk_keys``, plus the
-comparator-generic ``bitonic_*`` forms used by the kernel's exact
-two-array path). Every network is built from data-independent
-compare-exchange passes realized as reshape + elementwise min/max —
-no gathers, no data-dependent control flow, static shapes throughout —
-so the same code lowers on the VPU and runs under XLA. Because the
+This module also hosts the **bitonic networks** shared by the Pallas
+kernel's LSM+GMM stages and the engine's packed merge (``lsm_topk`` /
+``gmm_merge``, comparator-generic so the kernel's exact path moves
+(dist, idx) pairs and the packed paths one int32 key array). Every
+network is built from data-independent compare-exchange passes over
+lane rotations — no gathers, no data-dependent control flow, no
+reshape of the lane axis, static shapes throughout — so the same code
+lowers with Mosaic and runs under XLA (DESIGN.md §2). Because the
 packed-key integer order *is* the lexicographic (dist, idx) order,
-the bitonic path preserves the lowest-index tie rule exactly.
+the networks preserve the lowest-index tie rule exactly.
 """
 
 from __future__ import annotations
@@ -42,6 +42,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.pallas import tpu as pltpu
 
 # Packed-key sentinel (a very large distance with index bits zeroed).
 # A python int so it inlines as a weak-typed literal in kernels instead
@@ -91,14 +92,26 @@ def unpack_keys(keys: jax.Array, idx_bits: int) -> tuple[jax.Array, jax.Array]:
 # ---------------------------------------------------------------------------
 # Bitonic compare-exchange networks (LSM local sort + GMM sorted merge)
 #
-# The comparator-generic forms move a *tuple* of arrays through the
-# network in lockstep so the kernel's exact path can sort (dist, idx)
-# pairs under the lexicographic order; the packed wrappers specialize
-# to a single int32 key array whose integer order already encodes it.
+# The networks move a *tuple* of arrays in lockstep so the kernel's
+# exact path can sort (dist, idx) pairs under the lexicographic order
+# (``dist_idx_less``); packed callers pass a single int32 key array,
+# whose integer order already encodes it (``key_less``).
+#
+# Lowering-safe form (DESIGN.md §2): every pass keeps the last (lane)
+# axis whole. Lane p meets its partner p ^ dist through two lane
+# rotations (``pltpu.roll``, which lowers to a TPU lane rotate inside a
+# kernel and to ``jnp.roll`` under XLA) selected by a lane-iota bit;
+# directions come from lane-iota bits too. Wider inputs are cut into
+# lane chunks by static slices, and chunk pairs meet elementwise. Groups
+# are sorted in alternating directions, so two of them always meet as
+# (ascending, descending) and no reversal is ever needed.
 
 # Index fill for padded lanes in the exact two-array path: larger than
 # any real co-node index, so a padding lane loses every distance tie.
 IDX_FILL = 0x7FFFFFFF
+
+# Chunk width of the lane networks: one TPU vector register row.
+LANES = 128
 
 
 def next_pow2(v: int) -> int:
@@ -117,117 +130,166 @@ def dist_idx_less(a: Sequence[jax.Array], b: Sequence[jax.Array]) -> jax.Array:
     return (a[0] < b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
 
 
-def _ce_pass(vals: tuple, dist: int, asc_run: int | None, less: Callable):
-    """One compare-exchange pass at partner distance ``dist`` along the
-    last axis: reshape to (..., L/2d, 2, d) pairs element p with p^d —
-    no gathers, static shapes. ``asc_run`` is the sorted-run length
-    whose bit of p picks the direction (the classic ``i & k`` rule);
-    None means every pair sorts ascending (a merge/clean pass)."""
-    lead = vals[0].shape[:-1]
-    n_items = vals[0].shape[-1]
-    chunks = n_items // (2 * dist)
-    resh = [v.reshape(lead + (chunks, 2, dist)) for v in vals]
-    lo = tuple(r[..., 0, :] for r in resh)
-    hi = tuple(r[..., 1, :] for r in resh)
-    if asc_run is None:
-        asc = True
+def _lane(v: jax.Array) -> jax.Array:
+    # broadcasted_iota keeps this a traced op (TPU rejects 1D iota and
+    # captured constants).
+    return lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
+
+
+# ``pltpu.roll`` (jnp.roll semantics) has lowering rules for Mosaic
+# and XLA but no eager rule; the jit wrapper covers eager callers and
+# inlines under any trace, a kernel's included.
+_roll = jax.jit(pltpu.roll, static_argnums=(1, 2))
+
+
+def _partner(v: jax.Array, dist: int, up: jax.Array) -> jax.Array:
+    """Lane p's value at lane p ^ dist: lanes with the ``dist`` bit set
+    read dist lanes back, the others dist lanes ahead."""
+    width = v.shape[-1]
+    axis = v.ndim - 1
+    return jnp.where(up, _roll(v, dist, axis), _roll(v, width - dist, axis))
+
+
+def _ce_pass(vals: tuple, dist: int, asc, less: Callable) -> tuple:
+    """One compare-exchange pass at partner distance ``dist`` (< width)
+    along the last axis. ``asc`` (a python bool or a lane mask) is the
+    direction of the pair each lane belongs to: an ascending pair keeps
+    the lesser element in its lower lane."""
+    up = (_lane(vals[0]) & dist) != 0
+    partner = tuple(_partner(v, dist, up) for v in vals)
+    if less is key_less:
+        (v,), (p,) = vals, partner
+        take_min = up != asc
+        return (jnp.where(take_min, jnp.minimum(v, p), jnp.maximum(v, p)),)
+    # Boolean algebra only: Mosaic does not select between or compare
+    # boolean vectors.
+    first = (up & less(partner, vals)) | (~up & less(vals, partner))
+    if asc is True:
+        keep = first
+    elif asc is False:
+        keep = ~first
     else:
-        # chunk c holds positions [c*2d, (c+1)*2d); all of them share
-        # the asc_run bit because dist < asc_run. broadcasted_iota keeps
-        # this a traced op (TPU rejects 1D iota / captured constants).
-        cid = lax.broadcasted_iota(jnp.int32, (chunks, dist), 0)
-        asc = ((cid * (2 * dist)) // asc_run) % 2 == 0
-    keep = jnp.equal(less(lo, hi), asc)
-    new_lo = tuple(jnp.where(keep, a, b) for a, b in zip(lo, hi))
-    new_hi = tuple(jnp.where(keep, b, a) for a, b in zip(lo, hi))
-    return tuple(
-        jnp.stack((a, b), axis=-2).reshape(lead + (n_items,))
-        for a, b in zip(new_lo, new_hi)
-    )
+        keep = ~(first ^ asc)
+    return tuple(jnp.where(keep, v, p) for v, p in zip(vals, partner))
 
 
-def bitonic_sort(vals: tuple, less: Callable) -> tuple:
-    """Full ascending bitonic sort along the last axis (length must be a
-    power of two): log2(L)*(log2(L)+1)/2 data-independent passes."""
-    n_items = vals[0].shape[-1]
-    if n_items & (n_items - 1):
-        raise ValueError(f"bitonic_sort needs a power-of-two length; got {n_items}")
+def _lane_dir(lane: jax.Array, bit: int):
+    """Ascending where the lane's ``bit`` is clear."""
+    return (lane & bit) == 0
+
+
+def _group_sort(vals: tuple, group: int, target, less: Callable) -> tuple:
+    """Sort every run of ``group`` lanes; the final direction of each
+    run is ``target`` (a python bool or a lane mask)."""
+    lane = _lane(vals[0])
     run = 2
-    while run <= n_items:
+    while run <= group:
+        asc = target if run == group else _lane_dir(lane, run)
         dist = run // 2
         while dist >= 1:
-            vals = _ce_pass(vals, dist, run, less)
+            vals = _ce_pass(vals, dist, asc, less)
             dist //= 2
         run *= 2
     return vals
 
 
-def bitonic_merge_sorted(a: tuple, b: tuple, less: Callable) -> tuple:
-    """Merge two ascending sorted length-L sequences into the ascending
-    lowest-L of their union in 1 + log2(L) passes.
-
-    The first pass pairs a[i] with b[L-1-i] (a ++ reverse(b) is
-    bitonic): the elementwise winners are exactly the L smallest of the
-    union and form a bitonic sequence, cleaned by log2(L) ascending
-    passes — the paper's GMM heap-insert, as a sorting network."""
-    n_items = a[0].shape[-1]
-    b_rev = tuple(jnp.flip(v, axis=-1) for v in b)
-    take_a = less(a, b_rev)
-    vals = tuple(jnp.where(take_a, x, y) for x, y in zip(a, b_rev))
-    dist = n_items // 2
+def _clean(vals: tuple, group: int, asc, less: Callable) -> tuple:
+    """Sort every bitonic run of ``group`` lanes in direction ``asc``:
+    log2(group) passes."""
+    dist = group // 2
     while dist >= 1:
-        vals = _ce_pass(vals, dist, None, less)
+        vals = _ce_pass(vals, dist, asc, less)
         dist //= 2
     return vals
 
 
-def bitonic_topk(vals: tuple, k_pad: int, less: Callable, fill: tuple) -> tuple:
-    """Ascending lowest-``k_pad`` of the last axis (any width) — the
-    LSM local-sort stage: pad with ``fill`` sentinels to g*k_pad (g a
-    power of two), sort each width-k_pad group, then tournament-merge
-    group pairs with ``bitonic_merge_sorted`` until one remains.
-    Per-element pass count is O(log^2 k_pad), independent of width."""
+def _pairwise_less(a: tuple, b: tuple, less: Callable) -> tuple:
+    take_a = less(a, b)
+    return tuple(jnp.where(take_a, x, y) for x, y in zip(a, b))
+
+
+def lane_width(width: int, k_pad: int) -> int:
+    """Chunk width ``lsm_topk`` works in for a last axis of ``width``
+    lanes: one vector row (or less for narrow inputs), never narrower
+    than one group of ``k_pad``."""
+    return max(k_pad, min(LANES, next_pow2(width)))
+
+
+def lsm_topk(vals: tuple, k_pad: int, less: Callable, fill: tuple, *,
+             descending: bool = False) -> tuple:
+    """LSM: the lowest ``k_pad`` of the last axis (any width), sorted,
+    in lanes [0, k_pad) of a ``lane_width(width, k_pad)``-wide result
+    (the other lanes hold discarded candidates).
+
+    The input is cut into lane chunks (static slices, ``fill``-padded
+    to a power-of-two count); every k_pad-lane group is sorted, then
+    group pairs are tournament-merged until one group remains: first
+    chunk against chunk (elementwise, the work halving every round),
+    then within the last chunk by lane rotations. Each group is sorted
+    in the direction its next merge needs, so every merge is one
+    elementwise min of an ascending and a descending group plus
+    log2(k_pad) clean passes. ``descending`` orders the result
+    high-to-low (what ``gmm_merge`` takes)."""
     if k_pad & (k_pad - 1):
-        raise ValueError(f"bitonic_topk needs power-of-two k_pad; got {k_pad}")
+        raise ValueError(f"lsm_topk needs a power-of-two k_pad; got {k_pad}")
     lead = vals[0].shape[:-1]
     width = vals[0].shape[-1]
-    groups = next_pow2(-(-width // k_pad))
-    w_pad = groups * k_pad
-    if w_pad != width:
-        vals = tuple(
-            jnp.concatenate(
-                [v, jnp.full(lead + (w_pad - width,), f, v.dtype)], axis=-1
+    lw = lane_width(width, k_pad)
+    chunks = []
+    for c in range(-(-width // lw)):
+        part = tuple(v[..., c * lw:(c + 1) * lw] for v in vals)
+        short = lw - part[0].shape[-1]
+        if short:
+            part = tuple(
+                jnp.concatenate([p, jnp.full(lead + (short,), f, p.dtype)],
+                                axis=-1)
+                for p, f in zip(part, fill)
             )
-            for v, f in zip(vals, fill)
-        )
-    grp = tuple(v.reshape(lead + (groups, k_pad)) for v in vals)
-    grp = bitonic_sort(grp, less)
-    while groups > 1:
-        halves = [v.reshape(lead + (groups // 2, 2, k_pad)) for v in grp]
-        a = tuple(h[..., 0, :] for h in halves)
-        b = tuple(h[..., 1, :] for h in halves)
-        grp = bitonic_merge_sorted(a, b, less)
-        groups //= 2
-    return tuple(v.reshape(lead + (k_pad,)) for v in grp)
+        chunks.append(part)
+    n_chunks = next_pow2(len(chunks))
+    while len(chunks) < n_chunks:
+        chunks.append(tuple(jnp.full(lead + (lw,), f, v.dtype)
+                            for v, f in zip(vals, fill)))
+    lane = _lane(chunks[0][0])
+
+    def direction(bit, c):
+        # Direction of chunk c's groups before the merge at virtual lane
+        # distance ``bit`` (virtual lane = c * lw + lane).
+        if bit is None:
+            return not descending
+        if bit >= lw:
+            return (c * lw) & bit == 0
+        return _lane_dir(lane, bit)
+
+    dists = []
+    d = n_chunks * lw // 2
+    while d >= k_pad:
+        dists.append(d)
+        d //= 2
+    first = dists[0] if dists else None
+    chunks = [_group_sort(ch, k_pad, direction(first, c), less)
+              for c, ch in enumerate(chunks)]
+    for r, dist in enumerate(dists):
+        nxt = dists[r + 1] if r + 1 < len(dists) else None
+        if dist >= lw:
+            half = dist // lw
+            chunks = [
+                _clean(_pairwise_less(chunks[c], chunks[c + half], less),
+                       k_pad, direction(nxt, c), less)
+                for c in range(half)
+            ]
+        else:
+            (ch,) = chunks
+            ch = _ce_pass(ch, dist, True, less)
+            chunks = [_clean(ch, k_pad, direction(nxt, 0), less)]
+    return chunks[0]
 
 
-# -- packed-key wrappers (the shared kernel/engine API) ---------------------
-
-
-def sort_keys(keys: jax.Array) -> jax.Array:
-    """Ascending bitonic sort of packed keys along the last axis
-    (power-of-two length). Integer order == (dist, idx) order, so the
-    result is lexicographically sorted with ties -> lowest index."""
-    return bitonic_sort((keys,), key_less)[0]
-
-
-def merge_sorted(a: jax.Array, b: jax.Array) -> jax.Array:
-    """Sorted lowest-L of two ascending sorted packed-key lists (equal
-    power-of-two length L) in 1 + log2(L) passes."""
-    return bitonic_merge_sorted((a,), (b,), key_less)[0]
-
-
-def topk_keys(keys: jax.Array, k_pad: int) -> jax.Array:
-    """Ascending lowest-``k_pad`` packed keys of the last axis (any
-    width; ``INT_BIG``-padded internally)."""
-    return bitonic_topk((keys,), k_pad, key_less, (INT_BIG,))[0]
+def gmm_merge(run: tuple, top: tuple, k_pad: int, less: Callable) -> tuple:
+    """GMM: fold a descending list ``top`` into the ascending running
+    list ``run`` (both in lanes [0, k_pad) of equal-width arrays) in
+    1 + log2(k_pad) passes. The elementwise winners of an ascending and
+    a descending list are exactly the k_pad smallest of their union and
+    form a bitonic sequence; the clean passes sort it ascending — the
+    paper's heap insertion as a sorting network."""
+    return _clean(_pairwise_less(run, top, less), k_pad, True, less)

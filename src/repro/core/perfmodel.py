@@ -58,12 +58,38 @@ def fpga_latency_ms(n: int, m: int, d: int, k: int, clock_hz: float = 600e6,
     return sum(cyc.values()) / clock_hz * 1e3
 
 
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s of interchip interconnect per chip over its
+# four ICI links (50 GB/s per link, the bandwidth one collective hop
+# sees). A device that is not listed has no peaks: ``device_peaks``
+# raises rather than guess.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bw": 819e9,
+                    "ici_link_bw": 1600e9 / 8 / 4},
+}
+TARGET_DEVICE_KIND = "TPU v5 lite"  # the chip the kernels are tiled for
+TARGET_PEAKS = DEVICE_PEAKS[TARGET_DEVICE_KIND]  # model projections
+
+
+def device_peaks(device_kind: str) -> dict:
+    """Published peaks of one chip of ``device_kind`` (bytes/s, FLOP/s),
+    for a number reported against a run on that chip."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; add "
+            "them to perfmodel.DEVICE_PEAKS with their source"
+        ) from None
+
+
 @dataclass(frozen=True)
 class TPUConfig:
-    """TPU v5e single-core constants (target hardware)."""
+    """Single-chip constants of the target TPU (``TARGET_PEAKS``)."""
 
-    peak_flops: float = 197e12  # bf16 FLOP/s per chip
-    hbm_bw: float = 819e9  # bytes/s
+    peak_flops: float = TARGET_PEAKS["bf16_flops"]  # bf16 FLOP/s per chip
+    hbm_bw: float = TARGET_PEAKS["hbm_bw"]  # bytes/s
     vpu_lanes: int = 8 * 128  # f32 lanes per cycle (one VPU op = 1024 elems)
     clock_hz: float = 940e6
     vmem_bytes: int = 128 * 1024 * 1024

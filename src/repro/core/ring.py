@@ -40,7 +40,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.builder import (
     REUSE_KNOBS, DigcSpec, GraphBuilder, promote_batch, register,
 )
-from repro.core.compat import shard_map as _shard_map
 from repro.core.digc import BIG, dilate, merge_topk
 
 
@@ -219,9 +218,9 @@ def ring_digc(
     mask_specs = () if live_p is None else (P(bspec, axis_name),)
     mask_args = () if live_p is None else (live_p,)
     if stateful:
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             body_stateful,
-            mesh,
+            mesh=mesh,
             in_specs=(
                 P(bspec, axis_name, None),
                 P(bspec, axis_name, None),
@@ -233,17 +232,19 @@ def ring_digc(
                 P(bspec, axis_name, None),
                 P(bspec, axis_name),
             ),
+            check_vma=False,
         )
         run_d, run_i, sq_out = mapped(x_p, y_p, sq_p, valid, *mask_args)
     else:
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             body_stateless,
-            mesh,
+            mesh=mesh,
             in_specs=(
                 P(bspec, axis_name, None),
                 P(bspec, axis_name, None),
             ) + mask_specs,
             out_specs=(P(bspec, axis_name, None), P(bspec, axis_name, None)),
+            check_vma=False,
         )
         run_d, run_i = mapped(x_p, y_p, *mask_args)
         sq_out = None

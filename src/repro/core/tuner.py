@@ -630,7 +630,8 @@ def tune_reuse(
 
     ``ticks`` is a sequence of ``digc_capture`` lists — one per
     consecutive ``models.vig.vig_forward`` call on the live request
-    stream, each holding ``(layer_key, h, cond)`` per DIGC call. The
+    stream, each holding ``(layer_key, h, cond[, idx])`` per DIGC call
+    (the served ``idx`` is not read: the replay builds its own). The
     replay mirrors ``core.digc._reuse_build`` exactly (same drift
     statistic, same strict ``<`` gate, same staleness bound) but runs
     host-side against per-call exact graphs, so every candidate tau's
@@ -665,7 +666,7 @@ def tune_reuse(
     per_key: dict[tuple, list[list[dict]]] = {}
     for tick in ticks:
         seen_this_tick: dict[tuple, int] = {}
-        for layer_key, h, cond in tick:
+        for layer_key, h, cond, *_ in tick:
             x3 = h if h.ndim == 3 else h[None]
             m = cond.shape[-2] if cond is not None else x3.shape[-2]
             dil = max(base.dilation, 1)

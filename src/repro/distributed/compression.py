@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import compat
-
 
 def quantize_int8(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     scale = jnp.max(jnp.abs(x)) / 127.0 + 1e-12  # scalar per chunk
@@ -90,11 +88,12 @@ def compressed_allreduce_tree(grads, mesh: Mesh, axis_name: str = "pod"):
 
     body = functools.partial(compressed_psum, axis_name=axis_name, n_dev=n_dev)
     other = tuple(a for a in mesh.axis_names if a != axis_name)
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=P(),
         out_specs=P(),
+        check_vma=False,
     )
     summed = mapped(flat)
     if pad:

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import compat
-
 
 def pipeline_apply(
     layer_fn: Callable,
@@ -85,11 +83,12 @@ def pipeline_apply(
         _, outs = lax.fori_loop(0, ticks, tick, (state, outs))
         return outs[None]  # leading stage axis for out_specs
 
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         stage_body,
-        mesh,
+        mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(axis_name),
+        check_vma=False,
     )
     # params stacked (L, ...) -> sharded (S, L/S, ...) over stage axis
     def to_stages(p):

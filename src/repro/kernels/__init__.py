@@ -1,3 +1,18 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the paper's hot spots (fused DIGC top-k, fused
+MRConv), their padding wrappers (``ops``) and jnp references (``ref``)."""
+
+import jax
+
+
+def resolve_interpret(interpret):
+    """Whether a kernel call runs in Pallas interpret mode. ``None``
+    means compiled on a TPU backend and interpreted everywhere else;
+    interpret mode exists only off-TPU, so asking for it on a TPU is an
+    error rather than a silent slow path."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError(
+            "Pallas interpret mode runs only off-TPU; this backend is a TPU")
+    return bool(interpret)

@@ -34,9 +34,10 @@ working set = block_n*D + block_m*D + block_n*block_m + 2*block_n*kd
 floats (+ 2*block_n*kd_pad scratch), chosen to fit VMEM with
 MXU-aligned tile shapes.
 
-Validated in interpret mode on CPU against ``ref.digc_reference``; the
-lowering target is TPU v5e. ``interpret=None`` resolves to compiled on
-a TPU backend and interpret everywhere else.
+Validated in interpret mode on CPU against ``ref.digc_reference``, and
+compiled for TPU v5e by ``tests/test_chip_compile.py``. ``interpret=None``
+resolves to compiled on a TPU backend and interpret everywhere else
+(``repro.kernels.resolve_interpret``).
 """
 
 from __future__ import annotations
@@ -50,7 +51,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
+from repro.kernels import resolve_interpret
 
 # Packed (dist|idx) int32 keys and the bitonic sort/merge networks are
 # shared with the XLA engine's packed merge (core/engine.py) — one
@@ -58,13 +59,13 @@ from repro.core.compat import tpu_compiler_params
 from repro.core.packedkey import (
     IDX_FILL,
     INT_BIG,
-    bitonic_merge_sorted,
-    bitonic_topk,
     dist_idx_less,
+    gmm_merge,
     idx_bits_for,
-    merge_sorted,
+    key_less,
+    lane_width,
+    lsm_topk,
     next_pow2,
-    topk_keys,
 )
 from repro.core.packedkey import pack_keys as _pack_keys
 from repro.core.packedkey import unpack_keys as _unpack_keys
@@ -72,6 +73,10 @@ from repro.core.packedkey import unpack_keys as _unpack_keys
 BIG = float(1e30)  # plain float: jnp scalars would be captured as consts
 
 KERNEL_MERGES = ("bitonic", "legacy")
+
+# Rows the bitonic merge handles per loop step: one f32/int32 sublane
+# tile, so each lane chunk of a slab is a single vector register.
+_SLAB = 8
 
 
 def _bucket_reduce(blk_k, kd: int, rounds: int):
@@ -165,12 +170,14 @@ def _digc_kernel(x_ref, y_ref, *rest, kd: int, kd_pad: int, m_total: int,
         oi_ref = refs.pop(0)
     bitonic = kernel_merge == "bitonic"
     if bitonic:
-        # VMEM scratch accumulator (bn, kd_pad): the running sorted
-        # buffer. Outputs are written once, on the last streaming step.
+        # VMEM scratch accumulator: the running sorted buffer, written
+        # to the outputs once, on the last streaming step; and the
+        # tile's distances (or packed keys), which the merge reads one
+        # row slab at a time.
         if packed:
-            (ak_ref,) = refs
+            ak_ref, tile_ref = refs
         else:
-            ad_ref, ai_ref = refs
+            ad_ref, ai_ref, tile_ref = refs
     # grid = (B, N/bn, M/bm): program_id(0) is the batch index (its
     # blocks are squeezed out of the refs by the None BlockSpec dims).
     i = pl.program_id(1)
@@ -190,6 +197,27 @@ def _digc_kernel(x_ref, y_ref, *rest, kd: int, kd_pad: int, m_total: int,
             od_ref[...] = jnp.full(od_ref.shape, BIG, jnp.float32)
             oi_ref[...] = jnp.zeros(oi_ref.shape, jnp.int32)
 
+    def _merge_slab(r, carry):
+        # LSM: a slab's top-kd_pad per row, sorted descending; GMM: one
+        # sorted merge into the ascending running buffer. Slabs keep the
+        # unrolled networks to a few vector registers per array.
+        rows = pl.ds(pl.multiple_of(r * _SLAB, _SLAB), _SLAB)
+        if packed:
+            top = lsm_topk((tile_ref[rows, :],), kd_pad, key_less,
+                           (INT_BIG,), descending=True)
+            (ak_ref[rows, :],) = gmm_merge((ak_ref[rows, :],), top, kd_pad,
+                                           key_less)
+        else:
+            cols = j * block_m + lax.broadcasted_iota(
+                jnp.int32, (_SLAB, block_m), 1)
+            top = lsm_topk((tile_ref[rows, :], cols), kd_pad, dist_idx_less,
+                           (BIG, IDX_FILL), descending=True)
+            run_d, run_i = gmm_merge((ad_ref[rows, :], ai_ref[rows, :]), top,
+                                     kd_pad, dist_idx_less)
+            ad_ref[rows, :] = run_d
+            ai_ref[rows, :] = run_i
+        return carry
+
     def _do_tile():
         if mxu_bf16:
             # MXU-native: bf16 x bf16 -> fp32 accumulation (4x the fp32
@@ -203,9 +231,13 @@ def _digc_kernel(x_ref, y_ref, *rest, kd: int, kd_pad: int, m_total: int,
         y32 = y.astype(jnp.float32)
         sq_x = jnp.sum(x32 * x32, axis=1, keepdims=True)  # (bn, 1)
         sq_y = jnp.sum(y32 * y32, axis=1)  # (bm,)
-        # DCM: MXU contraction, fp32 accumulate.
+        # DCM: MXU contraction, fp32 accumulate. bf16 operands take one
+        # pass whatever the caller's matmul-precision scope: Mosaic
+        # refuses an fp32 contract precision on bf16 inputs.
         xy = lax.dot_general(
-            x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            x, y, (((1,), (1,)), ((), ())),
+            precision=lax.Precision.DEFAULT if mxu_bf16 else None,
+            preferred_element_type=jnp.float32,
         )  # (bn, bm)
         d_blk = sq_x - 2.0 * xy + sq_y[None, :]
         if p_ref is not None:
@@ -217,27 +249,15 @@ def _digc_kernel(x_ref, y_ref, *rest, kd: int, kd_pad: int, m_total: int,
             rows = i * block_n + lax.broadcasted_iota(jnp.int32, (bn, bm), 0)
             d_blk = jnp.where(cols <= rows, d_blk, BIG)
 
-        if packed:
+        if bitonic:
+            tile_ref[...] = (_pack_keys(d_blk, cols, idx_bits) if packed
+                             else d_blk)
+            lax.fori_loop(0, bn // _SLAB, _merge_slab, 0)
+        elif packed:
             blk_k = _pack_keys(d_blk, cols, idx_bits)
-            if bitonic:
-                # LSM: sorted top-kd_pad of the tile; GMM: one sorted
-                # merge into the running scratch buffer.
-                ak_ref[...] = merge_sorted(
-                    ak_ref[...], topk_keys(blk_k, kd_pad)
-                )
-            else:
-                if bucket_rounds > 0:
-                    blk_k = _bucket_reduce(blk_k, kd, bucket_rounds)
-                ok_ref[...] = _merge_body_packed(kd, ok_ref[...], blk_k)
-        elif bitonic:
-            tile_d, tile_i = bitonic_topk(
-                (d_blk, cols), kd_pad, dist_idx_less, (BIG, IDX_FILL)
-            )
-            run_d, run_i = bitonic_merge_sorted(
-                (ad_ref[...], ai_ref[...]), (tile_d, tile_i), dist_idx_less
-            )
-            ad_ref[...] = run_d
-            ai_ref[...] = run_i
+            if bucket_rounds > 0:
+                blk_k = _bucket_reduce(blk_k, kd, bucket_rounds)
+            ok_ref[...] = _merge_body_packed(kd, ok_ref[...], blk_k)
         else:
             run_d, run_i = _merge_body(kd, od_ref[...], oi_ref[...], d_blk, cols)
             od_ref[...] = run_d
@@ -325,8 +345,7 @@ def digc_topk_pallas(
                 "bucket_rounds requires block_m % kd == 0 and "
                 f"block_m // kd >= 2; got block_m={block_m}, kd={kd}"
             )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     squeeze = x.ndim == 2
     if squeeze:
         x = x[None]
@@ -384,12 +403,22 @@ def digc_topk_pallas(
         out_specs = [run_spec, run_spec]
     scratch_shapes = []
     if kernel_merge == "bitonic":
+        # The running list lives in lanes [0, kd_pad) of a buffer as wide
+        # as the LSM's lane chunks, so the GMM merge never changes width.
+        if block_n % _SLAB:
+            raise ValueError(
+                f"the bitonic merge needs block_n % {_SLAB} == 0; got "
+                f"block_n={block_n}"
+            )
+        lw = lane_width(block_m, kd_pad)
         if packed:
-            scratch_shapes = [pltpu.VMEM((block_n, kd_pad), jnp.int32)]
+            scratch_shapes = [pltpu.VMEM((block_n, lw), jnp.int32),
+                              pltpu.VMEM((block_n, block_m), jnp.int32)]
         else:
             scratch_shapes = [
-                pltpu.VMEM((block_n, kd_pad), jnp.float32),
-                pltpu.VMEM((block_n, kd_pad), jnp.int32),
+                pltpu.VMEM((block_n, lw), jnp.float32),
+                pltpu.VMEM((block_n, lw), jnp.int32),
+                pltpu.VMEM((block_n, block_m), jnp.float32),
             ]
     outs = pl.pallas_call(
         kernel,
@@ -399,7 +428,7 @@ def digc_topk_pallas(
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(*args)

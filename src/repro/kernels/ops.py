@@ -22,13 +22,6 @@ def _ceil_to(v: int, mult: int) -> int:
     return ((v + mult - 1) // mult) * mult
 
 
-def _auto_interpret(interpret):
-    """interpret=None -> compiled on a TPU backend, interpret elsewhere."""
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
-
-
 def mrconv(x: jax.Array, y: jax.Array, idx: jax.Array, *,
            block_n: int = 128, block_m: int = 512,
            interpret: Optional[bool] = None) -> jax.Array:
@@ -53,7 +46,7 @@ def mrconv(x: jax.Array, y: jax.Array, idx: jax.Array, *,
     y_p = jnp.pad(y, ((0, 0), (0, m_pad - m), (0, 0)))
     idx_p = jnp.pad(idx, ((0, 0), (0, n_pad - n), (0, 0)))
     out = mrconv_pallas(x_p, y_p, idx_p, block_n=block_n, block_m=block_m,
-                        interpret=_auto_interpret(interpret))
+                        interpret=interpret)
     out = out[:, :n].astype(x.dtype)
     return out[0] if squeeze else out
 
@@ -112,7 +105,7 @@ def digc_topk(
         kd=kd,
         block_n=block_n,
         block_m=block_m,
-        interpret=_auto_interpret(interpret),
+        interpret=interpret,
         m_valid=m,
         causal=causal,
         packed=packed,

@@ -126,9 +126,9 @@ def run_cell(arch: str, shape_id: str, *, multi_pod: bool, out_dir: Path,
                      ("flops", "hbm_bytes", "collective_bytes")}
 
         roof_terms = {
-            "compute_s": terms["flops"] / rl.PEAK_FLOPS,
-            "memory_s": terms["hbm_bytes"] / rl.HBM_BW,
-            "collective_s": terms["collective_bytes"] / rl.ICI_BW,
+            f"{k}_s": v for k, v in rl.roofline_terms(
+                terms["flops"], terms["hbm_bytes"],
+                terms["collective_bytes"]).items()
         }
         bound = max(
             ("compute", "memory", "collective"),

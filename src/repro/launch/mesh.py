@@ -1,4 +1,9 @@
-"""Production mesh construction.
+"""Mesh construction: every mesh of the repo is built here.
+
+``jax.make_mesh`` gives ``Explicit`` axes by default, under which the
+ring tier's gathers and scatters on sharded arrays raise
+``ShardingTypeError``; ``make_mesh`` asks for ``Auto`` axes, which the
+sharded DIGC state (DESIGN.md §10) is written for.
 
 Defined as functions (never module-level constants) so importing this
 module never touches jax device state — smoke tests must keep seeing
@@ -7,11 +12,7 @@ the single real CPU device."""
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5; older versions have neither AxisType nor the kwarg
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,11 +33,9 @@ def make_mesh(shape, axes):
             f"mesh {shape} needs {n} devices, have {len(devices)} — the "
             "dry-run entrypoint sets xla_force_host_platform_device_count"
         )
-    kwargs = {}
-    if AxisType is not None:
-        kwargs["axis_types"] = (AxisType.Auto,) * len(axes)
     return jax.make_mesh(
-        tuple(shape), tuple(axes), devices=devices[:n], **kwargs
+        tuple(shape), tuple(axes), devices=devices[:n],
+        axis_types=(AxisType.Auto,) * len(axes),
     )
 
 

@@ -11,8 +11,8 @@ applied. Collective bytes are parsed from the optimized HLO text
 shape bytes, x2 for all-reduce (ring reduce+broadcast), x(g-1)/g ring
 efficiency where the replica group size g is parseable.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Peaks are the target chip's (``perfmodel.TARGET_PEAKS``, TPU v5e); the
+collective term uses one ICI link's bandwidth.
 """
 
 from __future__ import annotations
@@ -20,9 +20,7 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+from repro.core.perfmodel import TARGET_PEAKS
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -101,19 +99,23 @@ class Roofline:
         return self.__dict__.copy()
 
 
+def roofline_terms(flops: float, hbm_bytes: float,
+                   collective_bytes: float) -> dict:
+    """Seconds each term would take at the target chip's peaks."""
+    return {
+        "compute": flops / TARGET_PEAKS["bf16_flops"],
+        "memory": hbm_bytes / TARGET_PEAKS["hbm_bw"],
+        "collective": collective_bytes / TARGET_PEAKS["ici_link_bw"],
+    }
+
+
 def analyze(compiled, *, hlo_text=None) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax < 0.5 returns [dict]
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()
     coll = parse_collectives(text)
-    terms = {
-        "compute": flops / PEAK_FLOPS,
-        "memory": hbm / HBM_BW,
-        "collective": coll["total_bytes"] / ICI_BW,
-    }
+    terms = roofline_terms(flops, hbm, coll["total_bytes"])
     bound = max(terms, key=terms.get)
     return Roofline(
         flops=flops,

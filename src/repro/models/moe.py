@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
 from repro.models.config import ModelConfig
 from repro.models.module import active_mesh, spec
 
@@ -154,9 +153,9 @@ def moe_apply(params, x, cfg: ModelConfig, *, mesh=None, model_axis="model"):
         body = functools.partial(
             _local_expert_moe, m=m, dt=dt, axis_name=model_axis, n_shards=n_shards
         )
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             body,
-            mesh,
+            mesh=mesh,
             in_specs=(
                 P(batch_axes or None, None, None),
                 P(None, None),
@@ -165,6 +164,7 @@ def moe_apply(params, x, cfg: ModelConfig, *, mesh=None, model_axis="model"):
                 P(model_axis, None, None),
             ),
             out_specs=(P(batch_axes or None, None, None), P(), P()),
+            check_vma=False,
         )
         out, aux, drop = mapped(
             x, params["router"], params["w_gate"], params["w_up"], params["w_down"]

@@ -407,10 +407,11 @@ def grapher_block(bp, x, cfg: VigConfig, grid: int, r: int, dilation: int,
     * ``cache`` (a ``DigcCache``) — the legacy eager shim: host-side,
       bypassed under jit.
 
-    ``digc_capture`` (a list) collects ``(layer_key, h, cond)`` per
-    DIGC call — the probe hook the tuner's recall-floor verification
-    and the recall-vs-drift bench replay against; works under jit when
-    the caller returns the captured arrays as outputs.
+    ``digc_capture`` (a list) collects ``(layer_key, h, cond, idx)``
+    per DIGC call — the probe hook the tuner's recall-floor
+    verification and the recall-vs-drift bench replay against, and the
+    neighbour lists the call built; works under jit when the caller
+    returns the captured arrays as outputs.
 
     ``m_valid`` ((N,) or (B, N) bool) marks live nodes when the batch
     carries N-bucket pad nodes (DESIGN.md §13): pad co-node columns are
@@ -431,8 +432,6 @@ def grapher_block(bp, x, cfg: VigConfig, grid: int, r: int, dilation: int,
     # downsample, so a fixed user grid would go stale).
     dspec = dspec.replace(k=k_eff, dilation=dilation).with_grid(grid, grid)
     builder = get_builder(dspec.impl)
-    if digc_capture is not None:
-        digc_capture.append((layer_key, h, cond))
     # Centroid warm starts are shared per stage (same co-node geometry):
     # layer l+1 starts from layer l's centroids, the next request from
     # this one's — features drift slowly, so 2 Lloyd iterations suffice.
@@ -444,6 +443,8 @@ def grapher_block(bp, x, cfg: VigConfig, grid: int, r: int, dilation: int,
     else:
         idx = digc(h, cond, spec=dspec, cache=cache,
                    cache_key=layer_key, m_valid=m_valid)  # (B, N, k)
+    if digc_capture is not None:
+        digc_capture.append((layer_key, h, cond, idx))
     aggregate = builder.aggregate if builder.aggregate is not None else mr_aggregate
     agg = aggregate(h, cond if cond is not None else h, idx)
     h = jnp.concatenate([h, agg], axis=-1) @ bp["fc_graph"]
@@ -497,8 +498,8 @@ def vig_forward(params, images, cfg: VigConfig, *,
       bypassed under jit); returns logits only.
 
     ``digc_capture`` (a list) collects every DIGC call's
-    ``(layer_key, nodes, co_nodes)`` — the recall-verification probe
-    hook (see ``grapher_block``).
+    ``(layer_key, nodes, co_nodes, idx)`` — the recall-verification
+    probe hook (see ``grapher_block``).
 
     **Resolution-parametric** (DESIGN.md §13): the serving grid is
     inferred from the image shape — H == W, divisible by ``cfg.patch``

@@ -279,7 +279,10 @@ class VigServeEngine:
     The slot/bucket/warm-gating lifecycle is unchanged: a ragged
     multi-tenant trace on an N-device mesh still compiles at most
     |bucket set| programs and each row still matches its own B=1
-    replay bit-for-bit on CPU.
+    replay bit-for-bit on CPU. Build the mesh with
+    ``repro.launch.mesh.make_mesh`` (``Auto`` axes): the default
+    ``Explicit`` axes of ``jax.make_mesh`` reject the state's row
+    gathers and scatters.
 
     **LRU state parking** (``park_capacity``, DESIGN.md §10): when a
     tenant is LRU-evicted from its slot, its state rows are copied to
@@ -1776,12 +1779,58 @@ class VigServeEngine:
         """Per-slot request counters of the canonical multi-tenant
         state (empty before the first tick). ``size`` selects an
         N-bucket on the lattice; default is the primary size."""
-        st = self._slot_states.get(
-            self.image_sizes[0] if size is None else size
-        )
-        if st is None:
-            return {}
-        return st.row_steps()
+        st = self.slot_state(size)
+        return {} if st is None else st.row_steps()
+
+    def slot_state(self, size: Optional[int] = None):
+        """The canonical per-slot ``DigcState`` of an N-bucket (default:
+        the primary size), or None before its first tick — where its
+        buffers live (``.sharding``) is what sharded serving places."""
+        return self._slot_states.get(
+            self.image_sizes[0] if size is None else size)
+
+    def program_text(self, bucket: int, size: Optional[int] = None) -> str:
+        """Optimized HLO of an already-served exact-size (B, N) cell's
+        program: which kernels its ticks run (a fused Pallas kernel
+        shows as ``tpu_custom_call``) and which collectives."""
+        size = self.image_sizes[0] if size is None else size
+        fwd = self._programs[self._program_key(bucket, size)]
+        width = self._tick_width(bucket)
+        images = jax.ShapeDtypeStruct(
+            (width, size, size, self.cfg.in_chans), jnp.float32)
+        state = self._slot_states[size].take_rows([0] * width)
+        return fwd.lower(self.params, images, state).compile().as_text()
+
+    def cell_graphs(self, images, size: Optional[int] = None):
+        """Run one exact-size tick of ``images`` (lane i on slot i's
+        state rows) through the cell's forward as a served tick builds
+        it — the tier the ladder serves, the tick width with lane-0
+        padding, the mesh, the slot-state rows — with ``digc_capture``
+        on, and return host ``(logits, graphs)`` for the live lanes:
+        one ``(layer_key, nodes, co_nodes, idx)`` per DIGC call
+        (``co_nodes`` None on a self-graph). A separate program: it
+        leaves the served programs and the slot state untouched."""
+        from repro.models.vig import vig_forward
+
+        size = self.image_sizes[0] if size is None else size
+        a = len(images)
+        bucket = self.bucket_for(a)
+        rows = list(range(a)) + [0] * (self._tick_width(bucket) - a)
+        imgs = np.stack([np.asarray(images[r], np.float32) for r in rows])
+        state = self._ensure_slot_state(size).take_rows(rows)
+        choice = self._choice_for(bucket, size)
+        cap: list = []
+
+        def fwd(p, im, st):
+            cap.clear()
+            logits, _ = vig_forward(p, im, self.cfg, digc_impl=choice,
+                                    state=st, digc_capture=cap)
+            return logits, [c[1:] for c in cap]
+
+        logits, arrays = jax.jit(fwd)(self.params, jnp.asarray(imgs), state)
+        live = lambda v: None if v is None else np.asarray(v)[:a]  # noqa: E731
+        return np.asarray(logits)[:a], [
+            (c[0], *(live(v) for v in arr)) for c, arr in zip(cap, arrays)]
 
     def stats(self) -> dict:
         out = {"requests_served": self.requests_served, "mode": self.mode,

@@ -14,6 +14,7 @@ load-bearing in a way per-test copies kept getting wrong:
   a subprocess rather than the (1-device) main test process.
 """
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -29,15 +30,20 @@ def run_snippet(
     devices: Optional[int] = 8,
     timeout: int = 600,
     check: bool = True,
+    env: Optional[dict] = None,
 ) -> subprocess.CompletedProcess:
     """Run a dedented python snippet in a pinned-env subprocess.
 
     ``devices=None`` omits XLA_FLAGS for snippets that set their own
     device count before importing jax. ``check=True`` asserts a zero
-    exit status with stderr in the failure message.
+    exit status with stderr in the failure message. ``env`` adds
+    variables to the pinned environment.
     """
-    env = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin", "HOME": "/root",
-           "JAX_PLATFORMS": "cpu"}
+    base = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
+            "JAX_PLATFORMS": "cpu"}
+    base.update({k: os.environ[k] for k in ("HOME", "TMPDIR")
+                 if k in os.environ})
+    env = {**base, **(env or {})}
     if devices is not None:
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={devices}"

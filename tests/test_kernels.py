@@ -122,3 +122,35 @@ def test_pallas_call_unpadded_direct():
     d_ref, i_ref = kref.digc_reference(x, y, kd=4)
     d_k, i_k = digc_topk_pallas(x, y, kd=4, block_n=32, block_m=128)
     assert_same_valid(i_ref, d_ref, i_k, d_k)
+
+
+def test_kernels_resolve_to_compiled_on_tpu(monkeypatch):
+    """With the backend reporting a TPU, ``interpret=None`` (every
+    caller's default) reaches ``pallas_call`` as compiled mode for both
+    kernels, and interpret mode is refused there."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    from repro.kernels import resolve_interpret
+
+    assert resolve_interpret(None) is True  # this CPU backend
+    seen = []
+
+    def fake_pallas_call(kernel, *, out_shape, interpret, **kw):
+        seen.append(interpret)
+
+        def run(*args):
+            return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                out_shape)
+        return run
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pl, "pallas_call", fake_pallas_call)
+    assert resolve_interpret(None) is False
+    with pytest.raises(ValueError, match="only off-TPU"):
+        resolve_interpret(True)
+    # Shapes no other test uses: the jitted kernels retrace here.
+    x = jnp.zeros((1, 24, 20), jnp.float32)
+    ops.digc_topk(x, x, k=3)
+    ops.mrconv(x, x, jnp.zeros((1, 24, 3), jnp.int32))
+    assert seen == [False, False]

@@ -14,19 +14,26 @@ from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
 from repro.core.packedkey import (
     IDX_FILL,
     INT_BIG,
-    bitonic_merge_sorted,
-    bitonic_sort,
-    bitonic_topk,
     dist_idx_less,
+    gmm_merge,
     idx_bits_for,
     key_less,
-    merge_sorted,
+    lsm_topk,
     next_pow2,
     pack_keys,
-    sort_keys,
-    topk_keys,
     unpack_keys,
 )
+
+
+def topk_keys(keys, k_pad):
+    """Ascending lowest-``k_pad`` packed keys (the LSM's live lanes)."""
+    (out,) = lsm_topk((keys,), k_pad, key_less, (INT_BIG,))
+    return out[..., :k_pad]
+
+
+def sort_keys(keys):
+    """Full ascending sort: the LSM with one group as wide as the row."""
+    return topk_keys(keys, keys.shape[-1])
 
 
 def _rand_keys(rng, *shape, m=256):
@@ -56,12 +63,15 @@ def test_sort_keys_rejects_non_pow2():
 
 
 def test_merge_sorted_is_lowest_l_of_union():
+    """GMM: an ascending run merged with a descending list is the
+    ascending lowest-L of their union."""
     rng = np.random.default_rng(1)
     a, _ = _rand_keys(rng, 4, 16)
     b, _ = _rand_keys(rng, 4, 16)
     a = jnp.sort(a, axis=-1)
     b = jnp.sort(b, axis=-1)
-    out = np.asarray(merge_sorted(a, b))
+    (out,) = gmm_merge((a,), (b[..., ::-1],), 16, key_less)
+    out = np.asarray(out)
     union = np.concatenate([np.asarray(a), np.asarray(b)], axis=-1)
     expect = np.sort(union, axis=-1)[..., :16]
     np.testing.assert_array_equal(out, expect)
@@ -79,6 +89,22 @@ def test_topk_keys_matches_numpy_partial_sort():
                 axis=-1),
             axis=-1)
         np.testing.assert_array_equal(out, full[..., :8])
+
+
+@pytest.mark.parametrize("width", [129, 300, 1024])
+def test_lsm_topk_multi_chunk_either_direction(width):
+    """Rows wider than one 128-lane chunk (a non-power-of-two chunk
+    count included) reduce to the lowest k_pad in lanes [0, k_pad),
+    ascending or descending."""
+    rng = np.random.default_rng(width)
+    keys, _ = _rand_keys(rng, 3, width, m=4096)
+    want = np.sort(np.asarray(keys), axis=-1)[..., :16]
+    for descending in (False, True):
+        (out,) = lsm_topk((keys,), 16, key_less, (INT_BIG,),
+                          descending=descending)
+        assert out.shape == (3, 128)
+        np.testing.assert_array_equal(
+            np.asarray(out)[..., :16], want[..., ::-1] if descending else want)
 
 
 def test_packed_ties_resolve_to_lowest_index():
@@ -100,7 +126,7 @@ def test_two_array_sort_ties_lowest_index():
     """The exact (unpacked) comparator path keeps the same tie rule."""
     d = jnp.asarray([1.0, 1.0, 0.5, 1.0], jnp.float32)
     i = jnp.asarray([9, 2, 11, 5], jnp.int32)
-    sd, si = bitonic_sort((d, i), dist_idx_less)
+    sd, si = lsm_topk((d, i), 4, dist_idx_less, (np.inf, IDX_FILL))
     np.testing.assert_array_equal(np.asarray(si), [11, 2, 5, 9])
     np.testing.assert_allclose(np.asarray(sd), [0.5, 1.0, 1.0, 1.0])
 
@@ -110,24 +136,24 @@ def test_two_array_topk_fill_loses_ties():
     with distance == BIG-sentinel still beats padding."""
     d = jnp.asarray([3.0, 1.0, 2.0], jnp.float32)
     i = jnp.asarray([0, 1, 2], jnp.int32)
-    td, ti = bitonic_topk((d, i), 4, dist_idx_less,
-                          (np.float32(3.0), IDX_FILL))
+    td, ti = lsm_topk((d, i), 4, dist_idx_less, (np.float32(3.0), IDX_FILL))
     assert np.asarray(ti).tolist() == [1, 2, 0, IDX_FILL]
     np.testing.assert_allclose(np.asarray(td), [1.0, 2.0, 3.0, 3.0])
 
 
 def test_two_array_merge_tracks_payload():
-    """bitonic_merge_sorted moves the idx payload in lockstep with the
-    dist key: merged (dist, idx) pairs stay true pairs."""
+    """gmm_merge moves the idx payload in lockstep with the dist key:
+    merged (dist, idx) pairs stay true pairs."""
     rng = np.random.default_rng(4)
     da = np.sort(rng.standard_normal((2, 8)).astype(np.float32), axis=-1)
     db = np.sort(rng.standard_normal((2, 8)).astype(np.float32), axis=-1)
     ia = np.arange(0, 8, dtype=np.int32) * 2 + np.zeros((2, 1), np.int32)
     ib = np.arange(0, 8, dtype=np.int32) * 2 + 1
     ib = np.broadcast_to(ib, (2, 8)).astype(np.int32)
-    md, mi = bitonic_merge_sorted(
+    md, mi = gmm_merge(
         (jnp.asarray(da), jnp.asarray(ia)),
-        (jnp.asarray(db), jnp.asarray(ib)), dist_idx_less)
+        (jnp.asarray(db[:, ::-1]), jnp.asarray(ib[:, ::-1])), 8,
+        dist_idx_less)
     md, mi = np.asarray(md), np.asarray(mi)
     assert (np.diff(md, axis=-1) >= 0).all()
     # every output pair exists in the input pair set, per row

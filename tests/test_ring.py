@@ -11,6 +11,7 @@ run fast enough for the tier-1 job. The fast tests ride a degenerate
 import numpy as np
 
 from _subproc import run_snippet
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -31,7 +32,7 @@ def test_ring_batched_parity_and_state_contract():
     from repro.core.state import DigcState, state_entry
 
     assert get_builder("ring").supports_state
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     rng = np.random.RandomState(5)
     x = jnp.asarray(rng.randn(2, 48, 12), jnp.float32)
     y = jnp.asarray(rng.randn(2, 40, 12), jnp.float32)
@@ -81,7 +82,7 @@ def test_ring_state_entry_planned():
     from repro.core.state import DigcState, state_entry
 
     assert get_builder("ring").supports_state
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     x = jnp.asarray(np.random.RandomState(6).randn(32, 8), jnp.float32)
     st = DigcState.init({"r": state_entry(sq_y_shape=(1, 32))})
     with mesh:
@@ -105,7 +106,7 @@ def test_ring_warm_gate_engages_stale_norms():
     from repro.core import DigcSpec, digc
     from repro.core.state import DigcState, state_entry
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     rng = np.random.RandomState(9)
     x = jnp.asarray(rng.randn(1, 24, 8), jnp.float32)
     y = jnp.asarray(rng.randn(1, 16, 8), jnp.float32)
@@ -136,7 +137,7 @@ def test_ring_mesh_shape_in_workload_key():
 
     from repro.core import DigcSpec, workload_key
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     spec = DigcSpec(impl="ring", k=4, mesh=mesh)
     assert spec.mesh_shape() == (1,)
     assert DigcSpec(impl="blocked", k=4).mesh_shape() is None
@@ -169,7 +170,8 @@ def test_ring_4dev_parity_warm_cold_and_sharded_state():
         from repro.core import DigcSpec, digc
         from repro.core.state import DigcState, state_entry
         assert jax.device_count() == 4
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
         rng = np.random.RandomState(2)
         x = jnp.asarray(rng.randn(2, 48, 12), jnp.float32)
         y = jnp.asarray(rng.randn(2, 40, 12), jnp.float32)
@@ -199,7 +201,7 @@ def test_ring_4dev_parity_warm_cold_and_sharded_state():
                         state_key="r")
         assert bool(jnp.all(i_mix == i_blk))
         # 2D mesh: data-parallel batch rows x ring-sharded co-nodes
-        mesh2 = jax.make_mesh((2, 2), ("rows", "ring"))
+        mesh2 = make_mesh((2, 2), ("rows", "ring"))
         spec2 = DigcSpec(impl="ring", k=4, mesh=mesh2, axis_name="ring",
                          batch_axis="rows")
         assert bool(jnp.all(digc(x, y, spec=spec2) == i_blk))
@@ -219,7 +221,8 @@ def test_ring_digc_exact():
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import digc
         from repro.core.ring import ring_digc
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.RandomState(2)
         for (N, M, D, k, dil) in [(64, 64, 16, 4, 1), (120, 100, 32, 4, 2), (16, 24, 8, 2, 1)]:
             x = jnp.asarray(rng.randn(N, D), jnp.float32)
@@ -241,7 +244,8 @@ def test_ring_digc_self_graph():
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import digc
         from repro.core.ring import ring_digc
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.RandomState(3)
         x = jnp.asarray(rng.randn(80, 24), jnp.float32)
         ir = digc(x, k=5, impl="reference")
@@ -263,7 +267,8 @@ def test_ring_digc_batched_registry():
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import DigcSpec, digc
         from repro.core.state import DigcState, state_entry
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.RandomState(4)
         x = jnp.asarray(rng.randn(2, 64, 16), jnp.float32)
         ir = digc(x, k=4, impl="reference")

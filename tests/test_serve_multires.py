@@ -299,7 +299,8 @@ def test_mesh_tick_padding_serves_nondividing_bucket():
         from repro.serve.engine import VigRequest, VigServeEngine
 
         assert jax.device_count() == 4
-        mesh = jax.make_mesh((2, 2), ("ring", "data"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("ring", "data"))
         cfg = vig.VIG_VARIANTS["vig_ti_iso"].replace(
             image_size=16, patch=4, embed_dims=(16,), depths=(2,),
             num_classes=3, k=3, digc_impl="ring")

@@ -27,6 +27,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core.state import DigcState
+from repro.launch.mesh import make_mesh
 from repro.models import vig
 from repro.models.module import init_params
 from repro.serve.engine import VigRequest, VigServeEngine
@@ -63,6 +64,36 @@ def _replay_tenant(cfg, params, impl, reqs, *, state=None):
         logits, state = fwd(params, jnp.asarray(r.image)[None], state)
         outs.append(np.asarray(logits)[0])
     return outs, state
+
+
+def test_cell_graphs_reproduce_a_served_tick():
+    """``cell_graphs`` re-runs a tick as it was served (bucket 4, one
+    lane-0 padding lane): its logits are the served ones, and every
+    captured neighbour list is the tier's graph of the captured nodes
+    at that block's k and dilation."""
+    from repro.core import digc
+
+    cfg, params = _tiny_vig("blocked")
+    eng = VigServeEngine(cfg, params, digc_impl="blocked", autotune=False,
+                         buckets=(1, 2, 4))
+    rng = np.random.default_rng(0)
+    imgs = [_image(rng) for _ in range(3)]
+    reqs = [VigRequest(uid=i, image=im, tenant=t)
+            for i, (t, im) in enumerate(zip("ABC", imgs))]
+    for r in reqs:
+        eng.submit(r)
+    eng.run()
+    logits, graphs = eng.cell_graphs(imgs)
+    np.testing.assert_allclose(logits, np.stack([r.logits for r in reqs]),
+                               rtol=1e-6, atol=1e-6)
+    rows = vig.count_digc_work(cfg)
+    assert len(graphs) == len(rows)
+    for (key, nodes, co_nodes, idx), row in zip(graphs, rows):
+        assert key == "stage0" and co_nodes is None
+        assert nodes.shape == (3, 16, 16) and idx.shape == (3, 16, row["k"])
+        want = digc(jnp.asarray(nodes), impl="blocked", k=row["k"],
+                    dilation=row["dilation"])
+        np.testing.assert_array_equal(idx, np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
@@ -318,7 +349,7 @@ def test_mesh_mode_rejects_invalid_configurations():
     policy serves counts that cannot all divide the axis — refusing at
     init beats crashing mid-tick after admission mutated slot state)."""
     cfg, params = _tiny_vig("ring")
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with pytest.raises(ValueError, match="mesh-native"):
         VigServeEngine(cfg, params, digc_impl="blocked", autotune=False,
                        mesh=mesh)

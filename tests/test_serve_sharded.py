@@ -34,7 +34,8 @@ def test_mesh_native_engine_bucketed_trace_matches_b1_replay():
         from repro.serve.engine import VigRequest, VigServeEngine
 
         assert jax.device_count() == 4
-        mesh = jax.make_mesh((4,), ("ring",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("ring",))
         cfg = vig.VIG_VARIANTS["vig_ti_iso"].replace(
             image_size=16, patch=4, embed_dims=(16,), depths=(2,),
             num_classes=3, k=3, digc_impl="ring")
@@ -115,7 +116,8 @@ def test_mesh_native_engine_parking_survives_slot_churn():
         from repro.models.module import init_params
         from repro.serve.engine import VigRequest, VigServeEngine
 
-        mesh = jax.make_mesh((4,), ("ring",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("ring",))
         cfg = vig.VIG_VARIANTS["vig_ti_iso"].replace(
             image_size=16, patch=4, embed_dims=(16,), depths=(2,),
             num_classes=3, k=3, digc_impl="ring")

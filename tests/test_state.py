@@ -9,6 +9,7 @@ import pytest
 
 from repro.core import DigcSpec, digc
 from repro.core.state import DigcState, DigcStateEntry, state_entry
+from repro.launch.mesh import make_mesh
 
 
 def _rand(rng, *shape):
@@ -444,7 +445,7 @@ def test_state_entry_mesh_placement():
     performance choice, never a semantic one)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     e = state_entry(sq_y_shape=(2, 8), centroids_shape=(2, 3, 4), rows=2,
                     mesh=mesh)
     assert isinstance(e.sq_y.sharding, NamedSharding)
@@ -466,7 +467,7 @@ def test_state_row_ops_preserve_named_sharding():
     mesh — an eager slot-lifecycle pass must not collapse a
     device-resident buffer onto the default device — and accept
     host-side (numpy) source rows, the parking round trip."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     st = DigcState.init({
         "s": state_entry(sq_y_shape=(4, 8), centroids_shape=(4, 2, 3),
                          rows=4, mesh=mesh),
@@ -491,7 +492,7 @@ def test_init_vig_state_mesh_placement_and_spec_mesh_wins():
     from repro.core.builder import DigcSpec
     from repro.models import vig
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     cfg = vig.VIG_VARIANTS["vig_ti_iso"].replace(
         image_size=16, patch=4, embed_dims=(16,), depths=(2,),
         num_classes=3, k=3,

@@ -363,3 +363,37 @@ def test_kernel_tile_defaults_respect_vmem():
         work = (bn * d + bm * d + bn * bm + 2 * bn * kd) * 4
         assert work <= 128 * 1024 * 1024 // 8
         assert bn >= 8 and bm >= 128
+
+
+def test_tune_raises_when_a_kernel_candidate_fails(monkeypatch):
+    """A fused-kernel candidate that fails to build raises out of the
+    tuner (on a TPU: a kernel Mosaic refuses) instead of being dropped
+    from the measured set in silence."""
+    from repro.kernels import ops
+
+    def refuse(*args, **kwargs):
+        raise RuntimeError("kernel refused by the compiler")
+
+    monkeypatch.setattr(ops, "digc_topk", refuse)
+    monkeypatch.setattr(DigcTuner, "candidates", lambda self, *a, **k: [
+        TileConfig(128, 128, "kernel", impl="pallas", kernel_merge="bitonic")])
+    x = _rand(np.random.default_rng(0), 1, 64, 8)
+    with pytest.raises(RuntimeError, match="refused by the compiler"):
+        DigcTuner().tune(x, spec=DigcSpec(impl="blocked", k=4))
+
+
+def test_device_peaks_table():
+    """Peaks live in one table keyed by device kind: the target chip's
+    feed the model projections (ICI per link), an unlisted chip has
+    none."""
+    from repro.core import perfmodel as pm
+    from repro.launch.roofline import roofline_terms
+
+    v5e = pm.device_peaks("TPU v5 lite")
+    assert pm.TPUConfig().peak_flops == v5e["bf16_flops"] == 197e12
+    assert pm.TPUConfig().hbm_bw == v5e["hbm_bw"] == 819e9
+    terms = roofline_terms(197e12, 819e9, 50e9)
+    assert terms == pytest.approx(
+        {"compute": 1.0, "memory": 1.0, "collective": 1.0})
+    with pytest.raises(KeyError, match="no published peaks"):
+        pm.device_peaks("TPU v0 unknown")
