@@ -79,36 +79,6 @@ def check(ok: bool, what: str) -> None:
         raise SmokeError(what)
 
 
-class CompileClock:
-    """Seconds the backend compiler (XLA and Mosaic) spends, and the
-    persistent-cache hits, from JAX's own monitoring events. Tracing
-    and lowering are left out: their events nest (a jitted kernel
-    inside a jitted forward) and would count twice."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        import jax.monitoring
-
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == self.EVENT:
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def take(self) -> tuple[float, int]:
-        out = (self.seconds, self.cache_hits)
-        self.seconds, self.cache_hits = 0.0, 0
-        return out
-
-
 def _model():
     import jax
 
@@ -142,7 +112,7 @@ def _serve(name, eng, images, clock):
 
     from repro.serve.engine import VigRequest
 
-    clock.take()
+    c0 = clock.snapshot()
     logits = {}
     uid = 0
     t0 = time.perf_counter()
@@ -162,7 +132,8 @@ def _serve(name, eng, images, clock):
             per_round.append(np.stack([r.logits for r in reqs]))
         logits[size] = np.stack(per_round)
     wall = time.perf_counter() - t0
-    compile_s, hits = clock.take()
+    c1 = clock.snapshot()
+    compile_s, hits = c1[0] - c0[0], c1[2] - c0[2]
     st = eng.stats()
     degrades = [f["kind"] for f in st["faults"]
                 if f["kind"] in ("compile_degrade", "deadline_degrade")]
@@ -419,8 +390,9 @@ def main(argv=None) -> int:
         print(f"chip_smoke: FAIL: no repro package under {SRC}; run from a "
               "checkout of the repository", file=sys.stderr)
         return 2
-    if str(SRC) not in sys.path:
-        sys.path.insert(0, str(SRC))
+    for path in (SRC, SRC.parent):
+        if str(path) not in sys.path:
+            sys.path.insert(0, str(path))
     import jax
 
     dev = jax.devices()[0]
@@ -438,6 +410,8 @@ def main(argv=None) -> int:
     print(f"device_kind {dev.device_kind} platform {dev.platform} "
           f"count {jax.device_count()} jax {jax.__version__}", flush=True)
     print(f"compile cache {cache_dir}", flush=True)
+    from chipbench.spans import CompileClock
+
     clock = CompileClock()
     t0 = time.perf_counter()
     try:

@@ -433,13 +433,16 @@ class DigcState:
 
     # -- integrity guards (fault-tolerant serving, DESIGN.md §11) -------
 
-    def row_fingerprints(self, rows) -> dict[str, dict[int, int]]:
+    def row_fingerprints(self, rows, to_host=np.asarray
+                         ) -> dict[str, dict[int, int]]:
         """Per-entry integrity tokens for the given slot rows.
 
         Batched variant of ``entry_row_fingerprint``: each per-row
         buffer crosses to host ONCE per call, not once per row — the
         engine checks/refreshes several lanes per tick, and the
-        device->host sync (not the crc) is the guard's real cost."""
+        device->host sync (not the crc) is the guard's real cost.
+        ``to_host`` makes each of those copies (the serving engine
+        passes its tracer's, which counts them)."""
         out: dict[str, dict[int, int]] = {}
         for k, e in self.entries.items():
             tokens = {int(r): 0 for r in rows}
@@ -447,23 +450,23 @@ class DigcState:
                 v = getattr(e, f)
                 if v is None:
                     continue
-                host = np.ascontiguousarray(np.asarray(v))
+                host = np.ascontiguousarray(to_host(v))
                 for r in tokens:
                     tokens[r] = zlib.crc32(host[r].tobytes(), tokens[r])
             out[k] = tokens
         return out
 
-    def rows_finite(self, rows) -> dict[int, bool]:
+    def rows_finite(self, rows, to_host=np.asarray) -> dict[int, bool]:
         """Which of the given slot rows are finite across every entry
-        (host-side, one transfer per buffer; per-row semantics of
-        ``entry_row_finite``)."""
+        (host-side, one transfer per buffer, made by ``to_host``; per-row
+        semantics of ``entry_row_finite``)."""
         finite = {int(r): True for r in rows}
         for e in self.entries.values():
             for f in e._row_fields():
                 v = getattr(e, f)
                 if v is None:
                     continue
-                host = np.asarray(v)
+                host = to_host(v)
                 if not np.issubdtype(host.dtype, np.floating):
                     continue
                 for r in finite:

@@ -422,6 +422,7 @@ def digc_topk_pallas(
             ]
     outs = pl.pallas_call(
         kernel,
+        name="digc_topk",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

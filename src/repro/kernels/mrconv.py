@@ -89,6 +89,7 @@ def mrconv_pallas(x: jax.Array, y: jax.Array, idx: jax.Array, *,
     kernel = functools.partial(_mrconv_kernel, block_m=block_m, k=k)
     out = pl.pallas_call(
         kernel,
+        name="mrconv",
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_n, d), lambda b, i, j: (b, i, 0)),

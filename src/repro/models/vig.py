@@ -420,9 +420,10 @@ def grapher_block(bp, x, cfg: VigConfig, grid: int, r: int, dilation: int,
     pad and live nodes); the caller (``vig_forward``) screens that.
     """
     dspec = digc_spec if digc_spec is not None else resolve_digc_spec(cfg, None)
-    h = _ln(x, bp["ln_g"]["scale"])
-    h = h @ bp["fc_in"]
-    cond = _pool_conodes(h, grid, r)  # None = self-graph
+    with jax.named_scope("graph_conv"):
+        h = _ln(x, bp["ln_g"]["scale"])
+        h = h @ bp["fc_in"]
+        cond = _pool_conodes(h, grid, r)  # None = self-graph
     m = cond.shape[1] if cond is not None else h.shape[1]
     k_eff = min(dspec.k, m // max(dilation, 1)) or 1
     if k_eff * dilation > m:
@@ -435,24 +436,30 @@ def grapher_block(bp, x, cfg: VigConfig, grid: int, r: int, dilation: int,
     # Centroid warm starts are shared per stage (same co-node geometry):
     # layer l+1 starts from layer l's centroids, the next request from
     # this one's — features drift slowly, so 2 Lloyd iterations suffice.
-    if state is not None:
-        idx, state = digc(h, cond, spec=dspec, state=state,
-                          state_key=layer_key,
-                          reuse_first=reuse_first,
-                          m_valid=m_valid)  # (B, N, k)
-    else:
-        idx = digc(h, cond, spec=dspec, cache=cache,
-                   cache_key=layer_key, m_valid=m_valid)  # (B, N, k)
+    # Named scopes (``digc``, ``graph_conv``, ``ffn``, under the caller's
+    # ``stage{si}/block{bi}``) only add metadata: the profiler's device
+    # ops carry them, and the program's ops and numbers do not change.
+    with jax.named_scope("digc"):
+        if state is not None:
+            idx, state = digc(h, cond, spec=dspec, state=state,
+                              state_key=layer_key,
+                              reuse_first=reuse_first,
+                              m_valid=m_valid)  # (B, N, k)
+        else:
+            idx = digc(h, cond, spec=dspec, cache=cache,
+                       cache_key=layer_key, m_valid=m_valid)  # (B, N, k)
     if digc_capture is not None:
         digc_capture.append((layer_key, h, cond, idx))
     aggregate = builder.aggregate if builder.aggregate is not None else mr_aggregate
-    agg = aggregate(h, cond if cond is not None else h, idx)
-    h = jnp.concatenate([h, agg], axis=-1) @ bp["fc_graph"]
-    h = jax.nn.gelu(h) @ bp["fc_out"]
-    x = x + h
-    f = _ln(x, bp["ln_f"]["scale"])
-    f = jax.nn.gelu(f @ bp["fc1"]) @ bp["fc2"]
-    return x + f, state
+    with jax.named_scope("graph_conv"):
+        agg = aggregate(h, cond if cond is not None else h, idx)
+        h = jnp.concatenate([h, agg], axis=-1) @ bp["fc_graph"]
+        h = jax.nn.gelu(h) @ bp["fc_out"]
+        x = x + h
+    with jax.named_scope("ffn"):
+        f = _ln(x, bp["ln_f"]["scale"])
+        f = jax.nn.gelu(f @ bp["fc1"]) @ bp["fc2"]
+        return x + f, state
 
 
 def run_stage(stage_params, x, cfg: VigConfig, plan: StagePlan, *,
@@ -463,12 +470,13 @@ def run_stage(stage_params, x, cfg: VigConfig, plan: StagePlan, *,
     fixed grid, sharing the stage's state key (layer l+1 warm-starts —
     or, under a reuse policy, serves — layer l's graph artifact)."""
     for bi in range(plan.depth):
-        x, state = grapher_block(
-            stage_params[f"block{bi}"], x, cfg, plan.grid, plan.r,
-            plan.dilations[bi], digc_spec=plan.spec, cache=cache,
-            layer_key=plan.key, state=state, reuse_first=(bi == 0),
-            digc_capture=digc_capture, m_valid=m_valid,
-        )
+        with jax.named_scope(f"block{bi}"):
+            x, state = grapher_block(
+                stage_params[f"block{bi}"], x, cfg, plan.grid, plan.r,
+                plan.dilations[bi], digc_spec=plan.spec, cache=cache,
+                layer_key=plan.key, state=state, reuse_first=(bi == 0),
+                digc_capture=digc_capture, m_valid=m_valid,
+            )
     return x, state
 
 
@@ -537,25 +545,29 @@ def vig_forward(params, images, cfg: VigConfig, *,
             f"rows; model {cfg.name!r} has depths={cfg.depths}, "
             f"reduce_ratios={cfg.reduce_ratios}"
         )
-    x = patchify(images, cfg.patch) @ params["stem"]
-    x = x + _pos_for_grid(params["pos"], cfg.base_grid, grid0)
+    with jax.named_scope("stem"):
+        x = patchify(images, cfg.patch) @ params["stem"]
+        x = x + _pos_for_grid(params["pos"], cfg.base_grid, grid0)
     for plan in plans:
-        x, state = run_stage(
-            params[plan.key], x, cfg, plan, cache=cache, state=state,
-            digc_capture=digc_capture, m_valid=valid_mask,
-        )
+        with jax.named_scope(f"stage{plan.index}"):
+            x, state = run_stage(
+                params[plan.key], x, cfg, plan, cache=cache, state=state,
+                digc_capture=digc_capture, m_valid=valid_mask,
+            )
         if plan.index + 1 < len(cfg.depths):
-            x = _downsample(x, plan.grid, params[f"down{plan.index}"])
-    if valid_mask is None:
-        pooled = jnp.mean(x, axis=1)
-    else:
-        mask = jnp.asarray(valid_mask, bool)
-        mask = mask[None, :] if mask.ndim == 1 else mask
-        w = mask.astype(x.dtype)[..., None]
-        pooled = jnp.sum(x * w, axis=1) / jnp.sum(
-            w, axis=1
-        ).clip(1.0)
-    logits = pooled @ params["head"]
+            with jax.named_scope(f"downsample{plan.index}"):
+                x = _downsample(x, plan.grid, params[f"down{plan.index}"])
+    with jax.named_scope("head"):
+        if valid_mask is None:
+            pooled = jnp.mean(x, axis=1)
+        else:
+            mask = jnp.asarray(valid_mask, bool)
+            mask = mask[None, :] if mask.ndim == 1 else mask
+            w = mask.astype(x.dtype)[..., None]
+            pooled = jnp.sum(x * w, axis=1) / jnp.sum(
+                w, axis=1
+            ).clip(1.0)
+        logits = pooled @ params["head"]
     if state is not None:
         return logits, state
     return logits

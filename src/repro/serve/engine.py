@@ -35,6 +35,7 @@ import numpy as np
 from repro.core.faults import FaultError, FaultInfo
 from repro.models.config import ModelConfig
 from repro.models import transformer as tr
+from repro.serve.tracer import EngineTracer
 
 
 @dataclasses.dataclass
@@ -595,6 +596,9 @@ class VigServeEngine:
         self._drift_sum = 0.0
         self._drift_n = 0
         self.last_drift: dict[str, float] = {}  # entry key -> mean drift
+        # Spans and counters of the tick (serve/tracer.py): off until a
+        # caller that measures the engine switches it on.
+        self.tracer = EngineTracer()
 
     # -- multi-resolution lattice plumbing (DESIGN.md §13) --------------
 
@@ -1126,12 +1130,13 @@ class VigServeEngine:
         targets = (self._slot_states.items() if size is None
                    else [(size, self._slot_states[size])]
                    if size in self._slot_states else [])
-        for sz, st in targets:
-            fps = st.row_fingerprints(list(slots))
-            for key, rows in fps.items():
-                self._row_tokens.setdefault(
-                    self._token_key(sz, key), {}
-                ).update(rows)
+        with self.tracer.span("engine.guard"):
+            for sz, st in targets:
+                fps = st.row_fingerprints(list(slots), self.tracer.to_host)
+                for key, rows in fps.items():
+                    self._row_tokens.setdefault(
+                        self._token_key(sz, key), {}
+                    ).update(rows)
 
     def _graph_stats_update(self, old_state, new_state, lanes) -> None:
         """Reconcile per-lane graph reuse/rebuild counters from one
@@ -1149,18 +1154,19 @@ class VigServeEngine:
         bucket-shaped tick arrays are donated into the jit program and
         gone by the time this runs."""
         rows = np.asarray(lanes, dtype=np.int64)
+        to_host = self.tracer.to_host
         for key, new_e in new_state.entries.items():
             if new_e.graph_age is None:
                 continue
             old_e = old_state.entries.get(key)
             if old_e is None or old_e.graph_age is None:
                 continue
-            new_age = np.asarray(new_e.graph_age)[rows]
+            new_age = to_host(new_e.graph_age)[rows]
             rebuilt = new_age == 0
             self.graph_rebuilds += int(rebuilt.sum())
             self.graph_reuses += int((~rebuilt).sum())
-            old_snap = np.asarray(old_e.graph_snap)[rows]
-            new_snap = np.asarray(new_e.graph_snap)[rows]
+            old_snap = to_host(old_e.graph_snap)[rows]
+            new_snap = to_host(new_e.graph_snap)[rows]
             # cold lanes carry the zero-initialized snapshot — their
             # first build is an admission, not drift
             warm = np.abs(old_snap) > 0
@@ -1187,7 +1193,7 @@ class VigServeEngine:
         if st is None:
             return True
         if fps is None:
-            fps = st.row_fingerprints([slot])
+            fps = st.row_fingerprints([slot], self.tracer.to_host)
         for key, rows in fps.items():
             want = self._row_tokens.get(
                 self._token_key(size, key), {}
@@ -1203,7 +1209,7 @@ class VigServeEngine:
         if st is None:
             return True
         if finite is None:
-            finite = st.rows_finite([slot])
+            finite = st.rows_finite([slot], self.tracer.to_host)
         return finite[slot]
 
     def _quarantine(self, slot: int, req: VigRequest,
@@ -1278,7 +1284,7 @@ class VigServeEngine:
             return
         host = {
             size: jax.tree_util.tree_map(
-                np.asarray, st.take_rows([slot])
+                self.tracer.to_host, st.take_rows([slot])
             )
             for size, st in self._slot_states.items()
         }
@@ -1532,8 +1538,20 @@ class VigServeEngine:
             self.deferrals += 1
             self._prefetch_parked()
             return 0
-        size, masked_cell = cell
         self._tick += 1
+        with self.tracer.span("engine.step", tick=self._tick) as root:
+            return self._serve(cell, eligible, root)
+
+    def _serve(self, cell, eligible, root) -> int:
+        """The tick ``step()`` dispatched, in the tracer's phases:
+        ``engine.admit`` binds slots, ``engine.stage`` reads the lane
+        images, ``engine.guard`` screens lanes and state rows (and every
+        integrity-token refresh, wherever it runs), ``engine.stage``
+        builds the batch and copies it to the device, ``engine.dispatch``
+        calls the program, ``engine.sync`` pulls the logits, and
+        ``engine.writeback`` scatters state and completes requests."""
+        span = self.tracer.span
+        size, masked_cell = cell
         self.last_resets = []
         self.last_restores = []
         self.last_quarantined = []
@@ -1541,117 +1559,110 @@ class VigServeEngine:
         assigned: dict[int, int] = {}  # id(request) -> slot
         _tkey = self._tkey
 
-        # Admission pass 1 — tenants that already own a slot reserve it
-        # first, so a new tenant admitted later in the same tick can
-        # only LRU-evict *idle* slots, never a warm tenant that is
-        # itself active this tick (queue order must not decide whose
-        # warm state survives). One lane per tenant per tick: state is
-        # a serial carry, a tenant's second request waits for the next
-        # tick so it warm-starts from the first's output.
-        for req in eligible:
-            if len(assigned) >= self.slots:
-                break
-            slot = self._tenant_slot.get(_tkey(req))
-            if slot is not None and slot not in used:
+        with span("engine.admit"):
+            # Admission pass 1 — tenants that already own a slot reserve
+            # it first, so a new tenant admitted later in the same tick
+            # can only LRU-evict *idle* slots, never a warm tenant that is
+            # itself active this tick (queue order must not decide whose
+            # warm state survives). One lane per tenant per tick: state is
+            # a serial carry, a tenant's second request waits for the next
+            # tick so it warm-starts from the first's output.
+            for req in eligible:
+                if len(assigned) >= self.slots:
+                    break
+                slot = self._tenant_slot.get(_tkey(req))
+                if slot is not None and slot not in used:
+                    used.add(slot)
+                    assigned[id(req)] = slot
+            # Admission pass 2 — new tenants, in arrival order, into free
+            # slots first, else LRU-evicting an idle slot.
+            for req in eligible:
+                if len(assigned) >= self.slots:
+                    break
+                if id(req) in assigned:
+                    continue
+                tkey = _tkey(req)
+                if self._tenant_slot.get(tkey) is not None:
+                    continue  # bound tenant already serving this tick
+                slot = self._admit(tkey, used)
+                if slot is None:
+                    continue
                 used.add(slot)
                 assigned[id(req)] = slot
-        # Admission pass 2 — new tenants, in arrival order, into free
-        # slots first, else LRU-evicting an idle slot.
-        for req in eligible:
-            if len(assigned) >= self.slots:
-                break
-            if id(req) in assigned:
-                continue
-            tkey = _tkey(req)
-            if self._tenant_slot.get(tkey) is not None:
-                continue  # bound tenant already serving this tick
-            slot = self._admit(tkey, used)
-            if slot is None:
-                continue
-            used.add(slot)
-            assigned[id(req)] = slot
-        picked = [(assigned[id(r)], r) for r in eligible
-                  if id(r) in assigned]
-        self.queue = [r for r in self.queue if id(r) not in assigned]
-        picked.sort(key=lambda sr: sr[0])
+            picked = [(assigned[id(r)], r) for r in eligible
+                      if id(r) in assigned]
+            self.queue = [r for r in self.queue if id(r) not in assigned]
+            picked.sort(key=lambda sr: sr[0])
 
-        state = self._ensure_slot_state(size)
-        # Fault site: unsanctioned state mutation (bit corruption that
-        # bypassed put_rows/reset_rows). The replaced state is adopted
-        # WITHOUT refreshing the integrity tokens — detecting exactly
-        # this is what the tokens are for.
-        mutated = self._fire("state.rows", value=state)
-        if mutated is not state:
-            self._slot_states[size] = state = mutated
+            state = self._ensure_slot_state(size)
+            # Fault site: unsanctioned state mutation (bit corruption
+            # that bypassed put_rows/reset_rows). The replaced state is
+            # adopted WITHOUT refreshing the integrity tokens — detecting
+            # exactly this is what the tokens are for.
+            mutated = self._fire("state.rows", value=state)
+            if mutated is not state:
+                self._slot_states[size] = state = mutated
+
+        with span("engine.stage"):
+            images = []
+            for _, req in picked:
+                img = np.asarray(req.image, np.float32)
+                fired = self._fire("admit.image", value=img,
+                                   tenant=req.tenant)
+                images.append(img if fired is img
+                              else np.asarray(fired, np.float32))
 
         # Guarded screening (DESIGN.md §11): each picked lane passes
         # the admission finiteness screen and the state-row checks
         # before it may reach a compiled program. A failing lane is
         # handled per the fault taxonomy — co-batched healthy tenants
         # are served exactly as if the faulty lane never existed.
-        healthy: list[tuple[int, VigRequest]] = []
-        imgs_list: list[np.ndarray] = []
-        masks_list: list[np.ndarray] = []
-        # One batched device->host pull for all picked lanes' state
-        # checks — the sync, not the crc/isfinite, is the guard cost
-        # (the serve/guarded_* bench rows price exactly this).
-        finite = fps = None
-        if self.guards and picked:
-            slots_picked = [slot for slot, _ in picked]
-            finite = state.rows_finite(slots_picked)
-            fps = state.row_fingerprints(slots_picked)
-        for slot, req in picked:
-            img = np.asarray(req.image, np.float32)
-            fired = self._fire("admit.image", value=img, tenant=req.tenant)
-            if fired is not img:
-                img = np.asarray(fired, np.float32)
-            if self.guards and not np.isfinite(img).all():
-                self._quarantine(slot, req, FaultInfo(
-                    kind="nonfinite_input", site="admit.image",
-                    tenant=req.tenant, tick=self._tick,
-                    detail="non-finite values in submitted image",
-                ))
-                continue
-            if self.guards:
-                if not self._row_finite(slot, finite, size):
-                    # Non-finite state rows: the tenant's warm carry is
-                    # poisoned — fail this request, cold-reset the slot.
+        healthy: list[tuple[int, VigRequest, np.ndarray]] = []
+        with span("engine.guard"):
+            # One batched device->host pull for all picked lanes' state
+            # checks — the sync, not the crc/isfinite, is the guard cost
+            # (the serve/guarded_* bench rows price exactly this).
+            finite = fps = None
+            if self.guards and picked:
+                slots_picked = [slot for slot, _ in picked]
+                finite = state.rows_finite(slots_picked, self.tracer.to_host)
+                fps = state.row_fingerprints(slots_picked,
+                                             self.tracer.to_host)
+            for (slot, req), img in zip(picked, images):
+                if self.guards and not np.isfinite(img).all():
                     self._quarantine(slot, req, FaultInfo(
-                        kind="nonfinite_state", site="state.rows",
+                        kind="nonfinite_input", site="admit.image",
                         tenant=req.tenant, tick=self._tick,
-                        detail=f"non-finite state rows on slot {slot}",
+                        detail="non-finite values in submitted image",
                     ))
                     continue
-                if not self._row_intact(slot, fps, size):
-                    # Finite but token-mismatched rows (silent
-                    # corruption): recover by serving this request
-                    # COLD — reset, re-fingerprint, keep the lane.
-                    state = state.reset_rows([slot])
-                    self._slot_states[size] = state
-                    self.state_resets += 1
-                    self.fault_log.append(FaultInfo(
-                        kind="state_corruption", site="state.rows",
-                        tenant=req.tenant, tick=self._tick,
-                        detail=(f"integrity token mismatch on slot "
-                                f"{slot}; cold reset"),
-                    ))
-                    self.last_resets.append(slot)
-                    self._refresh_tokens([slot], size)
-            if masked_cell and img.shape[0] < size:
-                # Zero-pad the ragged image up to its cell: the patch
-                # embed is stride-patch (node-local), so live patches
-                # see exactly their own pixels and pad patches are
-                # BIG-norm-masked out of every top-k downstream.
-                canvas = np.zeros((size, size, img.shape[-1]), np.float32)
-                canvas[:img.shape[0], :img.shape[1]] = img
-                img = canvas
-            healthy.append((slot, req))
-            imgs_list.append(img)
-            if masked_cell:
-                mask = self._req_mask(req)
-                n = (size // self.cfg.patch) ** 2
-                masks_list.append(np.ones(n, bool) if mask is None
-                                  else np.asarray(mask, bool))
+                if self.guards:
+                    if not self._row_finite(slot, finite, size):
+                        # Non-finite state rows: the tenant's warm carry
+                        # is poisoned — fail this request, cold-reset the
+                        # slot.
+                        self._quarantine(slot, req, FaultInfo(
+                            kind="nonfinite_state", site="state.rows",
+                            tenant=req.tenant, tick=self._tick,
+                            detail=f"non-finite state rows on slot {slot}",
+                        ))
+                        continue
+                    if not self._row_intact(slot, fps, size):
+                        # Finite but token-mismatched rows (silent
+                        # corruption): recover by serving this request
+                        # COLD — reset, re-fingerprint, keep the lane.
+                        state = state.reset_rows([slot])
+                        self._slot_states[size] = state
+                        self.state_resets += 1
+                        self.fault_log.append(FaultInfo(
+                            kind="state_corruption", site="state.rows",
+                            tenant=req.tenant, tick=self._tick,
+                            detail=(f"integrity token mismatch on slot "
+                                    f"{slot}; cold reset"),
+                        ))
+                        self.last_resets.append(slot)
+                        self._refresh_tokens([slot], size)
+                healthy.append((slot, req, img))
 
         if not healthy:
             self.last_lanes = []
@@ -1660,8 +1671,9 @@ class VigServeEngine:
             self._prefetch_parked()
             return 0
 
-        lanes = [slot for slot, _ in healthy]
+        lanes = [slot for slot, _, _ in healthy]
         a = len(lanes)
+        root.set(lanes=a)
         bucket = self.bucket_for(a)
         self.last_lanes = list(lanes)
         self.last_bucket = bucket
@@ -1675,80 +1687,98 @@ class VigServeEngine:
         # sharded — non-dividing buckets pad instead of failing.
         width = self._tick_width(bucket)
         rows = lanes + [lanes[0]] * (width - a)
-        imgs = np.stack(imgs_list + [imgs_list[0]] * (width - a))
-        state = self._slot_states[size]
-        bucket_state = state.take_rows(rows)
-        fwd = self._program_for(bucket, size, masked_cell)
-        pkey = self._program_key(bucket, size, masked_cell)
+        with span("engine.stage"):
+            imgs_list: list[np.ndarray] = []
+            masks_list: list[np.ndarray] = []
+            for _, req, img in healthy:
+                if masked_cell and img.shape[0] < size:
+                    # Zero-pad the ragged image up to its cell: the patch
+                    # embed is stride-patch (node-local), so live patches
+                    # see exactly their own pixels and pad patches are
+                    # BIG-norm-masked out of every top-k downstream.
+                    canvas = np.zeros((size, size, img.shape[-1]),
+                                      np.float32)
+                    canvas[:img.shape[0], :img.shape[1]] = img
+                    img = canvas
+                imgs_list.append(img)
+                if masked_cell:
+                    mask = self._req_mask(req)
+                    n = (size // self.cfg.patch) ** 2
+                    masks_list.append(np.ones(n, bool) if mask is None
+                                      else np.asarray(mask, bool))
+            imgs = jnp.asarray(np.stack(
+                imgs_list + [imgs_list[0]] * (width - a)))
+            masks = (jnp.asarray(np.stack(
+                masks_list + [masks_list[0]] * (width - a))),
+            ) if masked_cell else ()
+            state = self._slot_states[size]
+            bucket_state = state.take_rows(rows)
         # The timed serve section: dispatch + device compute + the
         # host sync that materializes the logits. A per-engine
         # deadline budget (deadline_ms) turns stragglers into counted
         # misses; deadline_strikes consecutive misses descend the
         # degradation ladder.
-        t0 = time.perf_counter()
-        self._fire("tick.serve", bucket=bucket)
-        if masked_cell:
-            masks = np.stack(
-                masks_list + [masks_list[0]] * (width - a)
-            )
-            logits, new_bucket_state = fwd(
-                self.params, jnp.asarray(imgs), bucket_state,
-                jnp.asarray(masks),
-            )
-        else:
-            logits, new_bucket_state = fwd(
-                self.params, jnp.asarray(imgs), bucket_state
-            )
-        # Scatter live lanes only: src rows >= a (padding) are dropped.
-        self._slot_states[size] = state.put_rows(new_bucket_state, lanes)
-        logits_np = np.asarray(logits)  # host sync closes the region
-        self._graph_stats_update(state, self._slot_states[size], lanes)
-        elapsed_ms = (time.perf_counter() - t0) * 1e3
-        first_tick = pkey not in self._program_ticks
-        self._program_ticks[pkey] = self._program_ticks.get(pkey, 0) + 1
-        if self.deadline_ms is not None and not first_tick:
-            # A bucket program's first served tick includes its jit
-            # compile — never a deadline signal.
-            if elapsed_ms > self.deadline_ms:
-                self.deadline_misses += 1
-                self._consecutive_misses += 1
-                info = FaultInfo(
-                    kind="deadline_miss", site="tick.serve",
-                    tick=self._tick,
-                    detail=(f"bucket {bucket} tick {elapsed_ms:.2f}ms > "
-                            f"budget {self.deadline_ms}ms"),
-                )
-                self.fault_log.append(info)
-                if self._consecutive_misses >= self.deadline_strikes:
-                    self._degrade(dataclasses.replace(
-                        info, kind="deadline_degrade",
-                        detail=(f"{self._consecutive_misses} consecutive "
-                                "misses; descending ladder"),
-                    ))
-            else:
-                self._consecutive_misses = 0
-        self._refresh_tokens(lanes, size)
-        for i, (slot, req) in enumerate(healthy):
-            req.logits = logits_np[i]
-            req.done = True
-            self._slot_last_tick[slot] = self._tick
-            if req.tenant is None:
-                # anonymous one-shot: free the slot immediately so it
-                # never pins out live warm tenants under LRU eviction
-                # (the next occupant is cold-reset on admission)
-                self.slot_tenant[slot] = None
-                self._tenant_slot.pop(("req", req.uid), None)
-        self.requests_served += a
-        self.bucket_ticks[bucket] = self.bucket_ticks.get(bucket, 0) + 1
-        cell = (size, bucket)
-        self.cell_ticks[cell] = self.cell_ticks.get(cell, 0) + 1
-        # padding-waste accounting (stats()/retune_buckets): the
-        # invariant the property tests pin is padded_lanes ==
-        # sum over ticks of (width - live), exactly.
-        self.live_lanes += a
-        self.padded_lanes += width - a
-        self.lane_hist[(size, a)] = self.lane_hist.get((size, a), 0) + 1
-        self._prefetch_parked()
+        with span("engine.dispatch"):
+            fwd = self._program_for(bucket, size, masked_cell)
+            pkey = self._program_key(bucket, size, masked_cell)
+            t0 = time.perf_counter()
+            self._fire("tick.serve", bucket=bucket)
+            logits, new_bucket_state = fwd(self.params, imgs, bucket_state,
+                                           *masks)
+        with span("engine.writeback"):
+            # Scatter live lanes only: src rows >= a (padding) are dropped.
+            self._slot_states[size] = state.put_rows(new_bucket_state, lanes)
+        with span("engine.sync"):
+            logits_np = self.tracer.to_host(logits)  # closes the region
+        with span("engine.writeback"):
+            self._graph_stats_update(state, self._slot_states[size], lanes)
+            elapsed_ms = (time.perf_counter() - t0) * 1e3
+            first_tick = pkey not in self._program_ticks
+            self._program_ticks[pkey] = self._program_ticks.get(pkey, 0) + 1
+            if self.deadline_ms is not None and not first_tick:
+                # A bucket program's first served tick includes its jit
+                # compile — never a deadline signal.
+                if elapsed_ms > self.deadline_ms:
+                    self.deadline_misses += 1
+                    self._consecutive_misses += 1
+                    info = FaultInfo(
+                        kind="deadline_miss", site="tick.serve",
+                        tick=self._tick,
+                        detail=(f"bucket {bucket} tick {elapsed_ms:.2f}ms "
+                                f"> budget {self.deadline_ms}ms"),
+                    )
+                    self.fault_log.append(info)
+                    if self._consecutive_misses >= self.deadline_strikes:
+                        self._degrade(dataclasses.replace(
+                            info, kind="deadline_degrade",
+                            detail=(f"{self._consecutive_misses} "
+                                    "consecutive misses; descending "
+                                    "ladder"),
+                        ))
+                else:
+                    self._consecutive_misses = 0
+            self._refresh_tokens(lanes, size)
+            for i, (slot, req, _) in enumerate(healthy):
+                req.logits = logits_np[i]
+                req.done = True
+                self._slot_last_tick[slot] = self._tick
+                if req.tenant is None:
+                    # anonymous one-shot: free the slot immediately so it
+                    # never pins out live warm tenants under LRU eviction
+                    # (the next occupant is cold-reset on admission)
+                    self.slot_tenant[slot] = None
+                    self._tenant_slot.pop(("req", req.uid), None)
+            self.requests_served += a
+            self.bucket_ticks[bucket] = self.bucket_ticks.get(bucket, 0) + 1
+            cell = (size, bucket)
+            self.cell_ticks[cell] = self.cell_ticks.get(cell, 0) + 1
+            # padding-waste accounting (stats()/retune_buckets): the
+            # invariant the property tests pin is padded_lanes ==
+            # sum over ticks of (width - live), exactly.
+            self.live_lanes += a
+            self.padded_lanes += width - a
+            self.lane_hist[(size, a)] = self.lane_hist.get((size, a), 0) + 1
+            self._prefetch_parked()
         return a
 
     def run(self) -> list[VigRequest]:
@@ -1881,7 +1911,9 @@ class VigServeEngine:
                             if self._drift_n else 0.0),
                    "last": dict(self.last_drift),
                },
-               "faults": [f.as_dict() for f in self.fault_log[-16:]]}
+               "faults": [f.as_dict() for f in self.fault_log[-16:]],
+               # spans and counters of the tick (serve/tracer.py)
+               "tracer": self.tracer.totals()}
         if self.fallback_level > 0:
             from repro.core.builder import fallback_chain
 

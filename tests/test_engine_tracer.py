@@ -1,0 +1,174 @@
+"""The serving engine's tracer (``serve/tracer.py``) and the named scopes
+of the ViG forward: off by default and free of effect on the answers;
+when recording, the tick's spans partition it, and ``host_pulls`` counts
+every device-to-host copy the tick makes."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core import DigcSpec
+from repro.models import vig
+from repro.models.module import init_params
+from repro.serve.engine import VigRequest, VigServeEngine
+from repro.serve.tracer import NULL_SPAN
+
+CHILDREN = ("engine.admit", "engine.guard", "engine.stage",
+            "engine.dispatch", "engine.sync", "engine.writeback")
+
+
+def _iso():
+    return vig.VIG_VARIANTS["vig_ti_iso"].replace(
+        image_size=16, patch=4, embed_dims=(16,), depths=(2,),
+        num_classes=3, k=3)
+
+
+def _pyr():
+    return vig.VIG_VARIANTS["vig_ti_pyr"].replace(
+        image_size=32, embed_dims=(8, 16, 24, 32), depths=(1, 1, 1, 1),
+        num_classes=3, k=3)
+
+
+def _engine(cfg, impl="blocked", **kw):
+    params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(0))
+    return VigServeEngine(cfg, params, digc_impl=impl, autotune=False, **kw)
+
+
+def _serve(eng, images, uid0=0):
+    """One tick of anonymous requests; their logits in lane order."""
+    reqs = [VigRequest(uid=uid0 + i, image=im) for i, im in enumerate(images)]
+    for r in reqs:
+        eng.submit(r)
+    assert eng.step() == len(reqs)
+    return [r.logits for r in reqs]
+
+
+def _images(cfg, n, seed):
+    rng = np.random.default_rng(seed)
+    s = cfg.image_size
+    return [rng.standard_normal((s, s, 3)).astype(np.float32)
+            for _ in range(n)]
+
+
+def test_tracer_off_records_nothing_and_leaves_answers_bit_equal():
+    cfg = _iso()
+    off, on = _engine(cfg), _engine(cfg)
+    on.tracer.recording = True
+    assert off.tracer.span("engine.step") is NULL_SPAN
+    for t in range(2):
+        imgs = _images(cfg, 3, t)
+        for a, b in zip(_serve(off, imgs), _serve(on, imgs)):
+            np.testing.assert_array_equal(a, b)
+    assert off.stats()["tracer"] == {"spans": {}, "counters": {}}
+    assert on.stats()["tracer"]["spans"]["engine.step"]["calls"] == 2
+
+
+@pytest.mark.parametrize("maker", [_iso, _pyr], ids=["iso", "pyr"])
+def test_spans_partition_the_tick(maker):
+    cfg = maker()
+    eng = _engine(cfg)
+    _serve(eng, _images(cfg, 4, 0))  # compiles outside the record
+    eng.tracer.recording = True
+    _serve(eng, _images(cfg, 4, 1), uid0=10)
+    spans = eng.tracer.totals()["spans"]
+    assert set(spans) == {"engine.step", *CHILDREN}
+    root = spans["engine.step"]
+    assert root["calls"] == 1
+    # guard runs inside admission (cold resets) and writeback (token
+    # refresh) besides its own screening pass
+    assert spans["engine.guard"]["calls"] == 4 + 2
+    parts = root["self_s"] + sum(spans[c]["self_s"] for c in CHILDREN)
+    assert parts == pytest.approx(root["total_s"], rel=1e-9, abs=1e-12)
+    assert all(spans[c]["self_s"] > 0 for c in CHILDREN)
+
+
+def _row_buffers(state) -> int:
+    """Device buffers a whole-state pull copies: one per per-row field
+    of each entry."""
+    return sum(getattr(e, f) is not None
+               for e in state.entries.values() for f in e._row_fields())
+
+
+def _graph_pulls(state) -> int:
+    """``graph_age`` and the two ``graph_snap`` reads per entry with a
+    cached graph."""
+    return sum(3 for e in state.entries.values() if e.graph_age is not None)
+
+
+# the stale-graph policy keeps a cached graph per entry, which the
+# tick's reuse accounting reads back
+REUSE = DigcSpec(impl="blocked", k=3, reuse="tick", drift_tau=0.05,
+                 max_stale=8)
+
+
+@pytest.mark.parametrize("guards", [True, False], ids=["guards", "bare"])
+@pytest.mark.parametrize("maker,impl", [(_iso, "blocked"), (_pyr, "blocked"),
+                                        (_iso, REUSE)],
+                         ids=["iso", "pyr", "iso_reuse"])
+def test_host_pulls_count_every_copy_to_the_host(maker, impl, guards):
+    cfg = maker()
+    eng = _engine(cfg, impl, guards=guards)
+    _serve(eng, _images(cfg, 4, 0))
+    eng.tracer.recording = True
+    n = 3
+    _serve(eng, _images(cfg, n, 1), uid0=10)
+    state = eng.slot_state()
+    buffers = _row_buffers(state)
+    assert buffers > 0 and (_graph_pulls(state) > 0) == (impl is REUSE)
+    # guarded: each of the n anonymous lanes is cold-reset on admission
+    # and re-fingerprinted; then one finiteness and one fingerprint pull
+    # screen the picked lanes, and one refresh follows the scatter
+    guard_pulls = (n + 3) * buffers if guards else 0
+    want = guard_pulls + _graph_pulls(state) + 1  # + the logits
+    assert eng.tracer.totals()["counters"] == {"host_pulls": want}
+
+
+def _lowered(cfg, impl):
+    params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(0))
+    st = vig.init_vig_state(cfg, 2, impl, per_slot=True)
+    s = cfg.image_size
+    f = jax.jit(lambda p, im, st: vig.vig_forward(p, im, cfg, digc_impl=impl,
+                                                   state=st))
+    return f.lower(params, jnp.zeros((2, s, s, 3)), st)
+
+
+@pytest.mark.parametrize("maker", [_iso, _pyr], ids=["iso", "pyr"])
+def test_named_scopes_land_in_the_program(maker):
+    cfg = maker()
+    lowered = _lowered(cfg, "blocked")
+    text = lowered.as_text(debug_info=True)
+    for scope in ("stem/", "stage0/block0/digc/", "stage0/block0/graph_conv/",
+                  "stage0/block0/ffn/", "head/"):
+        assert scope in text, scope
+    if len(cfg.depths) > 1:
+        assert "downsample0/" in text and "stage1/block0/digc/" in text
+    # the blocked tier's merge loops sit under the blocks' digc scopes
+    whiles = [re.search(r'op_name="([^"]*)"', ln)
+              for ln in lowered.compile().as_text().splitlines()
+              if re.search(r"^\s*%while\S* = .* while\(", ln)]
+    assert whiles
+    assert all(m and re.search(r"/stage\d+/block\d+/digc/", m.group(1))
+               for m in whiles)
+
+
+def test_pallas_kernels_carry_stable_names():
+    cfg = _iso()
+    params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(0))
+    st = vig.init_vig_state(cfg, 2, "pallas", per_slot=True)
+    jaxpr = jax.make_jaxpr(lambda p, im, st: vig.vig_forward(
+        p, im, cfg, digc_impl="pallas", state=st))(
+            params, jnp.zeros((2, 16, 16, 3)), st)
+
+    def names(jx):
+        for e in jx.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e.params["name"]
+            for v in e.params.values():
+                sub = getattr(v, "jaxpr", v)
+                if hasattr(sub, "eqns"):
+                    yield from names(sub)
+
+    assert set(names(jaxpr.jaxpr)) == {"digc_topk", "mrconv"}
