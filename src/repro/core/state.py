@@ -168,10 +168,8 @@ class DigcStateEntry:
         entry is typically donated into a jit, and an aliased buffer
         would invalidate the source entry's counter on real backends."""
         rows = jnp.asarray(rows, jnp.int32)
-        updates = {
-            f: _like_sharding(getattr(self, f), getattr(self, f)[rows])
-            for f in self._row_fields() if getattr(self, f) is not None
-        }
+        updates = {f: _like_sharding(v, v[rows])
+                   for f, v in self.row_buffers().items()}
         updates["step"] = self.step + 0
         return dataclasses.replace(self, **updates)
 
@@ -191,21 +189,41 @@ class DigcStateEntry:
             updates[f] = _like_sharding(dst_v, dst_v.at[rows].set(src_v[:n]))
         return dataclasses.replace(self, **updates)
 
+    def row_buffers(self) -> dict[str, jax.Array]:
+        """The allocated per-row buffers, by field name."""
+        return {f: getattr(self, f) for f in self._row_fields()
+                if getattr(self, f) is not None}
+
     def reset_rows(self, rows) -> "DigcStateEntry":
         """Zero the given rows (cold: ``row_step`` 0 routes builders to
         their cold path; the zeroed buffers are never read as values).
         Called when a slot is reassigned to a new tenant, so warm state
-        never leaks across tenants."""
-        rows = jnp.asarray(rows, jnp.int32)
-        updates = {}
-        for f in self._row_fields():
-            v = getattr(self, f)
-            if v is None:
-                continue
-            updates[f] = _like_sharding(
-                v, v.at[rows].set(jnp.zeros((), v.dtype))
-            )
-        return dataclasses.replace(self, **updates)
+        never leaks across tenants. One compiled call (``_zero_rows``)."""
+        return dataclasses.replace(self, **_zeroed(self.row_buffers(), rows))
+
+
+@jax.jit
+def _zero_rows(buffers, mask):
+    """Zero the rows ``mask`` marks in every buffer of ``buffers`` (any
+    pytree of arrays with a leading row axis). The mask's length is fixed
+    by the slot count, so one compile serves every set of rows reset.
+    Not donated: a caller may still hold the state it resets."""
+    def zero(v):
+        m = mask[: v.shape[0]].reshape((v.shape[0],) + (1,) * (v.ndim - 1))
+        return jnp.where(m, jnp.zeros((), v.dtype), v)
+
+    return jax.tree_util.tree_map(zero, buffers)
+
+
+def _zeroed(buffers, rows):
+    """``buffers`` with ``rows`` zeroed by one ``_zero_rows`` call, each
+    re-placed with its input's NamedSharding (jit may hand a sharded
+    buffer back under an equivalent spec that is not equal to it)."""
+    lens = [v.shape[0] for v in jax.tree_util.tree_leaves(buffers)]
+    mask = np.zeros(max(lens, default=0), bool)
+    mask[np.asarray(rows, np.int64).reshape(-1)] = True
+    return jax.tree_util.tree_map(_like_sharding, buffers,
+                                  _zero_rows(buffers, mask))
 
 
 # -- state-integrity guards (fault-tolerant serving, DESIGN.md §11) --------
@@ -228,10 +246,7 @@ def entry_row_fingerprint(entry: DigcStateEntry, row: int) -> int:
     mutated outside the lifecycle and must be cold-reset.
     """
     h = 0
-    for f in entry._row_fields():
-        v = getattr(entry, f)
-        if v is None:
-            continue
+    for v in entry.row_buffers().values():
         h = zlib.crc32(np.ascontiguousarray(np.asarray(v[row])).tobytes(), h)
     return h
 
@@ -240,10 +255,7 @@ def entry_row_finite(entry: DigcStateEntry, row: int) -> bool:
     """True when every float buffer of ``row`` is finite. A NaN/Inf in
     a warm row poisons every later request of its tenant (warm starts
     feed it back) — the engine screens served rows each tick."""
-    for f in entry._row_fields():
-        v = getattr(entry, f)
-        if v is None:
-            continue
+    for v in entry.row_buffers().values():
         host = np.asarray(v[row])
         if np.issubdtype(host.dtype, np.floating) and not np.isfinite(host).all():
             return False
@@ -297,8 +309,8 @@ def state_entry(
     counters and centroids are replicated across the mesh (they are
     per-row values every device needs). Entries placed this way stay
     device-resident through the row lifecycle: ``take_rows`` /
-    ``put_rows`` / ``reset_rows`` re-place their results with the
-    source buffer's sharding.
+    ``put_rows`` re-place their results with the source buffer's
+    sharding, and ``reset_rows``'s compiled call keeps it.
     """
     graph_b = None if graph_shape is None else graph_shape[0]
     entry = DigcStateEntry(
@@ -425,10 +437,15 @@ class DigcState:
         })
 
     def reset_rows(self, rows) -> "DigcState":
-        """Cold-reset the given rows in every entry (slot reassigned to
-        a new tenant)."""
+        """Cold-reset the given rows in every entry (slots reassigned to
+        new tenants): one compiled call over the whole state, whatever
+        the number of rows."""
+        zeroed = _zeroed(
+            {k: e.row_buffers() for k, e in self.entries.items()}, rows
+        )
         return DigcState(entries={
-            k: e.reset_rows(rows) for k, e in self.entries.items()
+            k: dataclasses.replace(e, **zeroed[k])
+            for k, e in self.entries.items()
         })
 
     # -- integrity guards (fault-tolerant serving, DESIGN.md §11) -------
@@ -446,10 +463,7 @@ class DigcState:
         out: dict[str, dict[int, int]] = {}
         for k, e in self.entries.items():
             tokens = {int(r): 0 for r in rows}
-            for f in e._row_fields():
-                v = getattr(e, f)
-                if v is None:
-                    continue
+            for v in e.row_buffers().values():
                 host = np.ascontiguousarray(to_host(v))
                 for r in tokens:
                     tokens[r] = zlib.crc32(host[r].tobytes(), tokens[r])
@@ -462,10 +476,7 @@ class DigcState:
         semantics of ``entry_row_finite``)."""
         finite = {int(r): True for r in rows}
         for e in self.entries.values():
-            for f in e._row_fields():
-                v = getattr(e, f)
-                if v is None:
-                    continue
+            for v in e.row_buffers().values():
                 host = to_host(v)
                 if not np.issubdtype(host.dtype, np.floating):
                     continue

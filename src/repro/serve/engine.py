@@ -651,10 +651,19 @@ class VigServeEngine:
         """Cold-reset ``slots``' rows in every allocated N-bucket state
         (quarantine/release/admission: a slot's occupancy changes for
         all resolutions at once, so stale warm rows at *any* size must
-        not survive into the next tenant)."""
+        not survive into the next tenant): one compiled reset per
+        N-bucket, then one token refresh over all of ``slots``."""
         for size, st in self._slot_states.items():
-            self._slot_states[size] = st.reset_rows(list(slots))
+            self._slot_states[size] = self._reset(st, slots)
         self._refresh_tokens(slots)
+
+    def _reset(self, state, slots):
+        """``state.reset_rows(slots)``, counted on the tracer: one
+        compiled reset (``reset_calls``) over ``len(slots)`` slots
+        (``reset_rows``)."""
+        self.tracer.count("reset_calls")
+        self.tracer.count("reset_rows", len(slots))
+        return state.reset_rows(slots)
 
     # -- SLO-bounded admission scheduling (DESIGN.md §14) ---------------
 
@@ -1355,7 +1364,7 @@ class VigServeEngine:
         # lay the parked copy over its own sizes.
         for size, st in self._slot_states.items():
             if size not in per_size:
-                self._slot_states[size] = st.reset_rows([slot])
+                self._slot_states[size] = self._reset(st, [slot])
         for size, rows in per_size.items():
             state = self._ensure_slot_state(size)
             self._slot_states[size] = DigcState(entries={
@@ -1486,9 +1495,10 @@ class VigServeEngine:
         """Bind a new tenant to a slot: a free one, else LRU-evict an
         idle one (never a slot already serving this tick; the evictee's
         rows are parked host-side first). The bound slot's state rows
-        are restored from the tenant's parked copy when one exists,
-        else cold-reset. Returns None when every slot is busy this
-        tick."""
+        are restored from the tenant's parked copy when one exists;
+        else the slot joins ``last_resets``, which ``_serve`` cold-resets
+        in one call once admission is done. Returns None when every
+        slot is busy this tick."""
         free = [s for s in range(self.slots) if self.slot_tenant[s] is None
                 and s not in used]
         if free:
@@ -1507,8 +1517,6 @@ class VigServeEngine:
         if self._unpark(tenant_key, slot):
             self.last_restores.append(slot)
         else:
-            if self._slot_states:
-                self._reset_rows_all([slot])
             self.last_resets.append(slot)
         return slot
 
@@ -1589,6 +1597,11 @@ class VigServeEngine:
                     continue
                 used.add(slot)
                 assigned[id(req)] = slot
+            # Every slot bound cold this tick, reset at once: nothing
+            # reads a newly bound slot's rows before this, and a slot in
+            # ``used`` is never evicted (parked) within the pass.
+            if self.last_resets:
+                self._reset_rows_all(self.last_resets)
             picked = [(assigned[id(r)], r) for r in eligible
                       if id(r) in assigned]
             self.queue = [r for r in self.queue if id(r) not in assigned]
@@ -1651,7 +1664,7 @@ class VigServeEngine:
                         # Finite but token-mismatched rows (silent
                         # corruption): recover by serving this request
                         # COLD — reset, re-fingerprint, keep the lane.
-                        state = state.reset_rows([slot])
+                        state = self._reset(state, [slot])
                         self._slot_states[size] = state
                         self.state_resets += 1
                         self.fault_log.append(FaultInfo(

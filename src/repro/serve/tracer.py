@@ -6,10 +6,12 @@ shared null span, and nothing is recorded. A caller that measures the
 engine switches it on:
 
 * ``recording`` keeps, per span name, the calls, total and self
-  nanoseconds (``time.perf_counter_ns``), and the counters (``host_pulls``:
-  device-to-host transfers made through ``to_host``). A span's self time
-  is its duration less the time its child spans cover, so the self times
-  of a root and everything under it add up to the root's duration.
+  nanoseconds (``time.perf_counter_ns``), and the counters: ``host_pulls``
+  (device-to-host transfers made through ``to_host``) and what the engine
+  adds through ``count`` (``reset_calls`` and ``reset_rows``: compiled
+  state-row resets and the slots they reset). A span's self time is its
+  duration less the time its child spans cover, so the self times of a
+  root and everything under it add up to the root's duration.
 * ``annotating`` (with ``recording``, while a profiler trace runs) also
   enters a ``jax.profiler.TraceAnnotation`` of the span's name, which
   puts the span on the profiler's clock beside the device ops.
@@ -102,11 +104,16 @@ class EngineTracer:
             return NULL_SPAN
         return _Span(self, name, meta)
 
+    def count(self, name: str, n: int = 1) -> None:
+        """Add ``n`` to the counter ``name`` while recording."""
+        if self.recording:
+            self._counters[name] = self._counters.get(name, 0) + n
+
     def to_host(self, x) -> np.ndarray:
         """``np.asarray(x)``; a device array's copy to the host is counted
         as one ``host_pulls``."""
-        if self.recording and isinstance(x, jax.Array):
-            self._counters["host_pulls"] = self._counters.get("host_pulls", 0) + 1
+        if isinstance(x, jax.Array):
+            self.count("host_pulls")
         return np.asarray(x)
 
     def totals(self) -> dict:

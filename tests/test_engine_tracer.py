@@ -77,9 +77,10 @@ def test_spans_partition_the_tick(maker):
     assert set(spans) == {"engine.step", *CHILDREN}
     root = spans["engine.step"]
     assert root["calls"] == 1
-    # guard runs inside admission (cold resets) and writeback (token
-    # refresh) besides its own screening pass
-    assert spans["engine.guard"]["calls"] == 4 + 2
+    # guard runs inside admission (one token refresh over the tick's
+    # cold resets) and writeback (token refresh) besides its own
+    # screening pass
+    assert spans["engine.guard"]["calls"] == 1 + 2
     parts = root["self_s"] + sum(spans[c]["self_s"] for c in CHILDREN)
     assert parts == pytest.approx(root["total_s"], rel=1e-9, abs=1e-12)
     assert all(spans[c]["self_s"] > 0 for c in CHILDREN)
@@ -88,8 +89,7 @@ def test_spans_partition_the_tick(maker):
 def _row_buffers(state) -> int:
     """Device buffers a whole-state pull copies: one per per-row field
     of each entry."""
-    return sum(getattr(e, f) is not None
-               for e in state.entries.values() for f in e._row_fields())
+    return sum(len(e.row_buffers()) for e in state.entries.values())
 
 
 def _graph_pulls(state) -> int:
@@ -118,12 +118,14 @@ def test_host_pulls_count_every_copy_to_the_host(maker, impl, guards):
     state = eng.slot_state()
     buffers = _row_buffers(state)
     assert buffers > 0 and (_graph_pulls(state) > 0) == (impl is REUSE)
-    # guarded: each of the n anonymous lanes is cold-reset on admission
-    # and re-fingerprinted; then one finiteness and one fingerprint pull
-    # screen the picked lanes, and one refresh follows the scatter
-    guard_pulls = (n + 3) * buffers if guards else 0
+    # guarded: the n anonymous lanes are cold-reset on admission in one
+    # call and re-fingerprinted in one refresh; then one finiteness and
+    # one fingerprint pull screen the picked lanes, and one refresh
+    # follows the scatter
+    guard_pulls = (1 + 3) * buffers if guards else 0
     want = guard_pulls + _graph_pulls(state) + 1  # + the logits
-    assert eng.tracer.totals()["counters"] == {"host_pulls": want}
+    assert eng.tracer.totals()["counters"] == {
+        "host_pulls": want, "reset_calls": 1, "reset_rows": n}
 
 
 def _lowered(cfg, impl):

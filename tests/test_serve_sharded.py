@@ -158,3 +158,65 @@ def test_mesh_native_engine_parking_survives_slot_churn():
         """
     )
     assert "SHARDED_PARKING_OK" in out
+
+
+def test_mesh_native_engine_batched_reset_keeps_placement():
+    """A tick of anonymous lanes on a 4-device ring engine cold-resets
+    them in one compiled call: each per-row buffer keeps its
+    NamedSharding, the reset rows are zero and the others untouched,
+    and every answer bit-matches a fresh-state B=1 replay (a row left
+    warm by its previous occupant would not)."""
+    out = _run(
+        """
+        import numpy as np, jax, jax.numpy as jnp
+        from repro.core import DigcSpec
+        from repro.models import vig
+        from repro.models.module import init_params
+        from repro.serve.engine import VigRequest, VigServeEngine
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((4,), ("ring",))
+        cfg = vig.VIG_VARIANTS["vig_ti_iso"].replace(
+            image_size=16, patch=4, embed_dims=(16,), depths=(2,),
+            num_classes=3, k=3, digc_impl="ring")
+        params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(0))
+        rng = np.random.default_rng(17)
+        eng = VigServeEngine(cfg, params, digc_impl="ring", autotune=False,
+                             buckets=(1, 2), mesh=mesh,
+                             mesh_axis="ring")
+        spec = DigcSpec(impl="ring", mesh=mesh, axis_name="ring")
+        fwd = jax.jit(lambda p, im, s: vig.vig_forward(
+            p, im, cfg, digc_impl=spec, state=s))
+        uid = 0
+        for t, n in enumerate((2, 2)):
+            eng.tracer.recording = t == 1
+            reqs = [VigRequest(uid=uid + i, image=rng.standard_normal(
+                        (16, 16, 3)).astype(np.float32)) for i in range(n)]
+            uid += n
+            for r in reqs:
+                eng.submit(r)
+            assert eng.step() == n
+            for r in reqs:
+                cold = vig.init_vig_state(cfg, 1, spec, per_slot=True,
+                                          mesh=mesh, mesh_axis="ring")
+                ref, _ = fwd(params, jnp.asarray(r.image)[None], cold)
+                assert np.array_equal(r.logits, np.asarray(ref)[0])
+        counters = eng.tracer.totals()["counters"]
+        assert (counters["reset_calls"], counters["reset_rows"]) == (1, 2)
+        # each slot's counters count one request's blocks: both were cold
+        assert eng.slot_row_steps()["stage0"] == [sum(cfg.depths)] * 2
+
+        st = eng.slot_state()
+        reset = st.reset_rows([0])
+        for key, e in st.entries.items():
+            for f, v in e.row_buffers().items():
+                w = getattr(reset.entries[key], f)
+                assert isinstance(v.sharding, jax.sharding.NamedSharding)
+                assert w.sharding == v.sharding, (key, f)
+                host, got = np.asarray(v), np.asarray(w)
+                assert not got[0].any() and host[1].any()
+                assert np.array_equal(got[1], host[1])
+        print("SHARDED_RESET_OK")
+        """
+    )
+    assert "SHARDED_RESET_OK" in out
