@@ -19,7 +19,7 @@ def run(res=512, depth=4):
     rng = np.random.default_rng(0)
     for vname in ("vig_ti_iso", "vig_s_iso"):
         cfg = vig.VIG_VARIANTS[vname].replace(
-            image_size=res, depths=(depth,), num_classes=100
+            image_size=res, depths=(depth,), num_classes=100, num_knn=None
         )
         params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(0))
         imgs = jnp.asarray(rng.standard_normal((1, res, res, 3)), jnp.float32)

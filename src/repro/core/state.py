@@ -167,27 +167,14 @@ class DigcStateEntry:
         row). The scalar ``step`` is copied, not aliased: the taken
         entry is typically donated into a jit, and an aliased buffer
         would invalidate the source entry's counter on real backends."""
-        rows = jnp.asarray(rows, jnp.int32)
-        updates = {f: _like_sharding(v, v[rows])
-                   for f, v in self.row_buffers().items()}
-        updates["step"] = self.step + 0
-        return dataclasses.replace(self, **updates)
+        return _take_entries({"": self}, rows)[""]
 
     def put_rows(self, src: "DigcStateEntry", rows) -> "DigcStateEntry":
         """Scatter ``src``'s leading rows back: row ``i`` of ``src``
         lands at ``rows[i]`` of self. ``src`` rows beyond ``len(rows)``
         (padding lanes) are dropped — they can never clobber live rows.
         The scalar ``step`` is taken from ``src`` (the served entry)."""
-        rows = jnp.asarray(rows, jnp.int32)
-        n = rows.shape[0]
-        updates = {"step": jnp.asarray(src.step)}
-        for f in self._row_fields():
-            dst_v, src_v = getattr(self, f), getattr(src, f)
-            if dst_v is None or src_v is None:
-                continue
-            src_v = jnp.asarray(src_v)  # parked host rows re-materialize
-            updates[f] = _like_sharding(dst_v, dst_v.at[rows].set(src_v[:n]))
-        return dataclasses.replace(self, **updates)
+        return _put_entries({"": self}, {"": src}, rows)[""]
 
     def row_buffers(self) -> dict[str, jax.Array]:
         """The allocated per-row buffers, by field name."""
@@ -213,6 +200,57 @@ def _zero_rows(buffers, mask):
         return jnp.where(m, jnp.zeros((), v.dtype), v)
 
     return jax.tree_util.tree_map(zero, buffers)
+
+
+@jax.jit
+def _take_rows(buffers, steps, rows):
+    """Rows ``rows`` of every buffer of ``buffers`` (any pytree of arrays
+    with a leading row axis), and a copy of each scalar of ``steps``: the
+    taken state is typically donated into a jit, and an aliased counter
+    would invalidate the source's. One compile per number of rows."""
+    return (jax.tree_util.tree_map(lambda v: v[rows], buffers),
+            jax.tree_util.tree_map(lambda v: v + 0, steps))
+
+
+@jax.jit
+def _put_rows(dst, src, rows):
+    """``dst`` with row ``rows[i]`` of each buffer set to row ``i`` of the
+    matching buffer of ``src``; rows of ``src`` beyond ``len(rows)`` are
+    dropped. Not donated: a caller may still read the state it writes
+    (the engine's graph statistics compare old and new rows)."""
+    n = rows.shape[0]
+    return jax.tree_util.tree_map(
+        lambda d, v: d.at[rows].set(v[:n].astype(d.dtype)), dst, src)
+
+
+def _take_entries(entries: dict, rows) -> dict:
+    """``DigcStateEntry.take_rows(rows)`` of every entry of ``entries``,
+    in one ``_take_rows`` call, each buffer re-placed with its source's
+    NamedSharding."""
+    rows = np.asarray(rows, np.int32).reshape(-1)
+    bufs = {k: e.row_buffers() for k, e in entries.items()}
+    taken, steps = _take_rows(bufs, {k: e.step for k, e in entries.items()},
+                              rows)
+    taken = jax.tree_util.tree_map(_like_sharding, bufs, taken)
+    return {k: dataclasses.replace(e, step=steps[k], **taken[k])
+            for k, e in entries.items()}
+
+
+def _put_entries(entries: dict, srcs: dict, rows) -> dict:
+    """``entries[k].put_rows(srcs[k], rows)`` for every key, in one
+    ``_put_rows`` call over the buffers both sides hold (``srcs`` may
+    hold host rows, a parked copy); each entry's ``step`` is its
+    source's."""
+    rows = np.asarray(rows, np.int32).reshape(-1)
+    dst, new = {}, {}
+    for k, e in entries.items():
+        both = [(f, getattr(e, f), getattr(srcs[k], f))
+                for f in e._row_fields()]
+        dst[k] = {f: d for f, d, v in both if d is not None and v is not None}
+        new[k] = {f: v for f, d, v in both if d is not None and v is not None}
+    put = jax.tree_util.tree_map(_like_sharding, dst, _put_rows(dst, new, rows))
+    return {k: dataclasses.replace(e, step=jnp.asarray(srcs[k].step), **put[k])
+            for k, e in entries.items()}
 
 
 def _zeroed(buffers, rows):
@@ -422,19 +460,19 @@ class DigcState:
 
     def take_rows(self, rows) -> "DigcState":
         """Gather batch rows from every entry (slot rows -> bucket
-        lanes; repeats allowed for padding lanes)."""
-        return DigcState(entries={
-            k: e.take_rows(rows) for k, e in self.entries.items()
-        })
+        lanes; repeats allowed for padding lanes): one compiled call
+        (``_take_rows``) over the whole state, per entry what
+        ``DigcStateEntry.take_rows`` gives."""
+        return DigcState(entries=_take_entries(self.entries, rows))
 
     def put_rows(self, src: "DigcState", rows) -> "DigcState":
         """Scatter ``src``'s leading rows into every entry at ``rows``
         (bucket lanes -> slot rows; src rows beyond ``len(rows)`` —
-        padding lanes — are dropped)."""
-        return DigcState(entries={
-            k: e.put_rows(src.entries[k], rows)
-            for k, e in self.entries.items()
-        })
+        padding lanes — are dropped): one compiled call (``_put_rows``)
+        over the whole state, per entry what ``DigcStateEntry.put_rows``
+        gives. ``src`` may hold host (numpy) rows, a parked copy."""
+        return DigcState(entries=_put_entries(self.entries, src.entries,
+                                              rows))
 
     def reset_rows(self, rows) -> "DigcState":
         """Cold-reset the given rows in every entry (slots reassigned to

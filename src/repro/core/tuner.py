@@ -515,7 +515,12 @@ class DigcTuner:
         arrays of the stage's true shape (pooled stages tune the real
         (N, M) workload, not a self-graph stand-in). Returns the
         ``VigSchedule`` plus the per-stage results; cached entries are
-        served without re-measurement.
+        served without re-measurement. The measured k and dilation only
+        choose the tiles: each stage's spec keeps ``spec``'s own k and
+        dilation, which stay model-owned (``models.vig.vig_stage_plans``
+        derives every block's k from them, its ``num_knn`` schedule and
+        the serving grid; a workload row's k is already resolved to
+        one block at one grid).
         """
         import jax.numpy as jnp
 
@@ -540,7 +545,7 @@ class DigcTuner:
             )
             tuned, result = self.tune(probe, y_probe, spec=stage_spec,
                                       force=force)
-            stages.append(tuned)
+            stages.append(tuned.replace(k=spec.k, dilation=spec.dilation))
             results.append(result)
         return VigSchedule(stages=tuple(stages)), results
 
@@ -631,7 +636,9 @@ def tune_reuse(
     ``ticks`` is a sequence of ``digc_capture`` lists — one per
     consecutive ``models.vig.vig_forward`` call on the live request
     stream, each holding ``(layer_key, h, cond[, idx])`` per DIGC call
-    (the served ``idx`` is not read: the replay builds its own). The
+    (the served ``idx`` is read only for its width, the block's own k,
+    which a ``num_knn`` ramp varies inside a stage; without it the
+    spec's k applies, and the replay builds its own lists). The
     replay mirrors ``core.digc._reuse_build`` exactly (same drift
     statistic, same strict ``<`` gate, same staleness bound) but runs
     host-side against per-call exact graphs, so every candidate tau's
@@ -666,11 +673,12 @@ def tune_reuse(
     per_key: dict[tuple, list[list[dict]]] = {}
     for tick in ticks:
         seen_this_tick: dict[tuple, int] = {}
-        for layer_key, h, cond, *_ in tick:
+        for layer_key, h, cond, *idx in tick:
             x3 = h if h.ndim == 3 else h[None]
             m = cond.shape[-2] if cond is not None else x3.shape[-2]
             dil = max(base.dilation, 1)
-            k_eff = min(base.k, m // dil) or 1
+            k = idx[0].shape[-1] if idx else base.k
+            k_eff = min(k, m // dil) or 1
             if k_eff * dil > m:
                 dil = 1
             call_spec = base.replace(k=k_eff, dilation=dil)
@@ -700,6 +708,12 @@ def tune_reuse(
                 for ci, call in enumerate(calls):
                     stat, exact = call["stat"], call["exact"]
                     total += stat.shape[0]
+                    if cached is not None and cached.shape != exact.shape:
+                        # a block of another k than the cached graph's
+                        # (a num_knn ramp): the gate cannot engage, and
+                        # the build leaves the cache as it was
+                        recalls.append(1.0)
+                        continue
                     if cached is None:
                         reuse_row = np.zeros(stat.shape, bool)
                     elif policy == "overlap":

@@ -56,6 +56,17 @@ class VigConfig:
     num_classes: int = 1000
     digc_impl: str = "blocked"
     ffn_ratio: int = 4
+    # Per-block neighbour counts over the whole network (the official
+    # isotropic ViGs ramp k, ``linspace(k, 2k, depth)``); None serves
+    # ``k`` in every block.
+    num_knn: Optional[tuple[int, ...]] = None
+
+    def __post_init__(self):
+        if self.num_knn is not None and len(self.num_knn) != sum(self.depths):
+            raise ValueError(
+                f"{self.name}: num_knn has {len(self.num_knn)} entries for "
+                f"{sum(self.depths)} blocks"
+            )
 
     @property
     def base_grid(self) -> int:
@@ -68,11 +79,20 @@ class VigConfig:
         return dataclasses.replace(self, **kw)
 
 
+# The official code's k schedule of ViG-S and ViG-B (vig_pytorch/vig.py):
+# ``[int(x) for x in torch.linspace(9, 18, 16)]``, with max_dilation
+# 196 // 18 = 10.
+_KNN_RAMP_16 = (9, 9, 10, 10, 11, 12, 12, 13, 13, 14, 15, 15, 16, 16, 17, 18)
+
 # ViG paper variants.
 VIG_VARIANTS = {
     "vig_ti_iso": VigConfig("vig_ti_iso", "isotropic", embed_dims=(192,), depths=(12,)),
-    "vig_s_iso": VigConfig("vig_s_iso", "isotropic", embed_dims=(320,), depths=(16,)),
-    "vig_b_iso": VigConfig("vig_b_iso", "isotropic", embed_dims=(640,), depths=(16,)),
+    "vig_s_iso": VigConfig("vig_s_iso", "isotropic", embed_dims=(320,),
+                           depths=(16,), num_knn=_KNN_RAMP_16,
+                           max_dilation=10),
+    "vig_b_iso": VigConfig("vig_b_iso", "isotropic", embed_dims=(640,),
+                           depths=(16,), num_knn=_KNN_RAMP_16,
+                           max_dilation=10),
     "vig_ti_pyr": VigConfig(
         "vig_ti_pyr", "pyramid", patch=4, embed_dims=(48, 96, 240, 384),
         depths=(2, 2, 6, 2), reduce_ratios=(4, 2, 1, 1),
@@ -271,9 +291,10 @@ class StagePlan:
     grid: int
     r: int
     m: int  # co-nodes per image (grid/r)^2
-    spec: DigcSpec  # stage spec, k/dilation still model-owned
+    spec: DigcSpec  # stage spec, k/dilation still model-owned (k = ks[0])
     dilations: tuple[int, ...]  # per block, after the m-feasibility clamp
     k_effs: tuple[int, ...]  # per block effective neighbor count
+    ks: tuple[int, ...]  # per block resolution-scaled k, before the clamps
 
     @property
     def key(self) -> str:
@@ -310,6 +331,11 @@ def vig_stage_plans(cfg: VigConfig,
                     ) -> tuple[StagePlan, ...]:
     """Materialize the stage pipeline for a model + DIGC choice.
 
+    Each block's k is the config's ``num_knn`` entry for it (a per-block
+    schedule wins over the spec's k), else the spec's k; the resolution
+    ramp and the clamps then apply to that block's own k (``ks``,
+    ``k_effs``).
+
     ``grid`` is the serving patch grid (default: the config's native
     ``base_grid``) — the resolution-parametric hook: stage grids, m,
     the per-block (dilation, k_eff) clamps and the resolution-scaled
@@ -344,18 +370,23 @@ def vig_stage_plans(cfg: VigConfig,
                 f"needs the 2x2 downsample (model {cfg.name!r}); "
                 f"serve a resolution divisible through every stage"
             )
-        k_s = _resolution_k(spec.k, grid, cfg.grid_at_stage(si))
-        spec = spec.replace(k=k_s)
+        native = cfg.grid_at_stage(si)
+        ks = tuple(
+            _resolution_k(spec.k if cfg.num_knn is None
+                          else cfg.num_knn[gb + bi], grid, native)
+            for bi in range(depth)
+        )
+        spec = spec.replace(k=ks[0])
         m = (grid // max(r, 1)) ** 2
         geo = tuple(
-            _block_geometry(cfg, gb + bi, m, k_s, grid=grid,
-                            base_grid=cfg.grid_at_stage(si))
+            _block_geometry(cfg, gb + bi, m, ks[bi], grid=grid,
+                            base_grid=native)
             for bi in range(depth)
         )
         plans.append(StagePlan(
             index=si, depth=depth, grid=grid, r=r, m=m, spec=spec,
             dilations=tuple(g[0] for g in geo),
-            k_effs=tuple(g[1] for g in geo),
+            k_effs=tuple(g[1] for g in geo), ks=ks,
         ))
         gb += depth
         if si + 1 < len(cfg.depths):
@@ -467,13 +498,15 @@ def run_stage(stage_params, x, cfg: VigConfig, plan: StagePlan, *,
               digc_capture: Optional[list] = None,
               m_valid: Optional[jax.Array] = None):
     """Run one pipeline stage: ``plan.depth`` Grapher+FFN blocks over a
-    fixed grid, sharing the stage's state key (layer l+1 warm-starts —
-    or, under a reuse policy, serves — layer l's graph artifact)."""
+    fixed grid, each with its own k (``plan.ks``), sharing the stage's
+    state key (layer l+1 warm-starts — or, under a reuse policy, serves
+    — layer l's graph artifact)."""
     for bi in range(plan.depth):
         with jax.named_scope(f"block{bi}"):
             x, state = grapher_block(
                 stage_params[f"block{bi}"], x, cfg, plan.grid, plan.r,
-                plan.dilations[bi], digc_spec=plan.spec, cache=cache,
+                plan.dilations[bi],
+                digc_spec=plan.spec.replace(k=plan.ks[bi]), cache=cache,
                 layer_key=plan.key, state=state, reuse_first=(bi == 0),
                 digc_capture=digc_capture, m_valid=m_valid,
             )
@@ -632,8 +665,10 @@ def init_vig_state(cfg: VigConfig, batch: int,
             # Cached-graph buffers (DESIGN.md §12), sized by the
             # stage's FIRST block — the same derivation grapher_block
             # applies, so the shapes line up; a later block whose
-            # clamped k_eff differs (tiny co-node counts) simply never
-            # engages the cache (static shape check in the gate).
+            # k_eff differs (a num_knn ramp, or a clamp at tiny co-node
+            # counts) simply never engages the cache (static shape
+            # check in the gate): under a ramp only the blocks of the
+            # first block's k share the cached graph.
             alloc["graph_shape"] = (batch, plan.n, plan.k_effs[0])
         entries[plan.key] = state_entry(**alloc)
     return DigcState.init(entries)
@@ -650,14 +685,15 @@ def count_digc_work(cfg: VigConfig, *, grid: Optional[int] = None):
     """Per-image DIGC workload (N, M, D, k, dilation) per block — feeds
     the paper-table benchmarks. Reads the same ``vig_stage_plans`` the
     forward executes (including, with ``grid=``, an off-native serving
-    resolution and its scaled k), so the accounting can never drift
-    from the model."""
+    resolution and its scaled k, and a per-block ``num_knn`` schedule:
+    ``k`` is the block's own, before the co-node clamps), so the
+    accounting can never drift from the model."""
     out = []
     for plan in vig_stage_plans(cfg, grid=grid):
         d = cfg.embed_dims[plan.index]
         for bi in range(plan.depth):
             out.append({
                 "stage": plan.index, "N": plan.n, "M": plan.m, "D": d,
-                "k": plan.spec.k, "dilation": plan.dilations[bi],
+                "k": plan.ks[bi], "dilation": plan.dilations[bi],
             })
     return out

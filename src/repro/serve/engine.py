@@ -599,6 +599,8 @@ class VigServeEngine:
         # Spans and counters of the tick (serve/tracer.py): off until a
         # caller that measures the engine switches it on.
         self.tracer = EngineTracer()
+        # (size, bucket) -> per-lane (lists, candidates), for the tracer
+        self._digc_work: dict[tuple, tuple[int, int]] = {}
 
     # -- multi-resolution lattice plumbing (DESIGN.md §13) --------------
 
@@ -664,6 +666,28 @@ class VigServeEngine:
         self.tracer.count("reset_calls")
         self.tracer.count("reset_rows", len(slots))
         return state.reset_rows(slots)
+
+    def _count_digc(self, bucket: int, size: int, lanes: int) -> None:
+        """Count the tick's DIGC work on the tracer, from the cell's
+        stage plans (no pull): ``digc_lists``, the neighbour entries its
+        graphs hold (live lanes x N x k per block), and
+        ``digc_candidates``, what the top-(k*d) merge keeps before the
+        dilation stride (live lanes x N x k*d per block)."""
+        if not self.tracer.recording:
+            return
+        key = (size, bucket)
+        work = self._digc_work.get(key)
+        if work is None:
+            from repro.models.vig import vig_stage_plans
+
+            plans = vig_stage_plans(self.cfg, self._choice_for(bucket, size),
+                                    grid=size // self.cfg.patch)
+            work = self._digc_work[key] = (
+                sum(p.n * k for p in plans for k in p.k_effs),
+                sum(p.n * k * d for p in plans
+                    for k, d in zip(p.k_effs, p.dilations)))
+        self.tracer.count("digc_lists", lanes * work[0])
+        self.tracer.count("digc_candidates", lanes * work[1])
 
     # -- SLO-bounded admission scheduling (DESIGN.md §14) ---------------
 
@@ -864,14 +888,18 @@ class VigServeEngine:
         (N, M) pair, later pyramid stages get their own entries.
         ``size`` selects the N-bucket (default: the native pyramid) —
         the rows carry that bucket's (N, M, k), so the tuner's workload
-        key covers both lattice dimensions."""
+        key covers both lattice dimensions. A stage whose blocks differ
+        in k (a ``num_knn`` ramp) or dilation tunes at its widest
+        block, the largest k*d: the tile chosen there holds the widest
+        merge, and every block of the stage runs it."""
         from repro.models.vig import count_digc_work
 
         grid = None if size is None else size // self.cfg.patch
-        rows: dict[int, dict] = {}
+        stages: dict[int, list[dict]] = {}
         for row in count_digc_work(self.cfg, grid=grid):
-            rows.setdefault(row["stage"], row)
-        return [rows[si] for si in sorted(rows)]
+            stages.setdefault(row["stage"], []).append(row)
+        return [max(stages[si], key=lambda r: r["k"] * r["dilation"])
+                for si in sorted(stages)]
 
     def warmup(self, rng_seed: int = 0):
         """Autotune a per-stage engine schedule (blocked tier only).
@@ -1790,6 +1818,7 @@ class VigServeEngine:
             # sum over ticks of (width - live), exactly.
             self.live_lanes += a
             self.padded_lanes += width - a
+            self._count_digc(bucket, size, a)
             self.lane_hist[(size, a)] = self.lane_hist.get((size, a), 0) + 1
             self._prefetch_parked()
         return a

@@ -9,7 +9,10 @@ engine switches it on:
   nanoseconds (``time.perf_counter_ns``), and the counters: ``host_pulls``
   (device-to-host transfers made through ``to_host``) and what the engine
   adds through ``count`` (``reset_calls`` and ``reset_rows``: compiled
-  state-row resets and the slots they reset). A span's self time is its
+  state-row resets and the slots they reset; ``digc_lists`` and
+  ``digc_candidates``: the neighbour entries a tick's graphs hold and
+  the top-(k*d) candidates DIGC keeps for them, summed over blocks and
+  live lanes from the stage plans). A span's self time is its
   duration less the time its child spans cover, so the self times of a
   root and everything under it add up to the root's duration.
 * ``annotating`` (with ``recording``, while a profiler trace runs) also

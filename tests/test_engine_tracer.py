@@ -125,7 +125,8 @@ def test_host_pulls_count_every_copy_to_the_host(maker, impl, guards):
     guard_pulls = (1 + 3) * buffers if guards else 0
     want = guard_pulls + _graph_pulls(state) + 1  # + the logits
     assert eng.tracer.totals()["counters"] == {
-        "host_pulls": want, "reset_calls": 1, "reset_rows": n}
+        "host_pulls": want, "reset_calls": 1, "reset_rows": n,
+        **_digc_work(cfg, n)}
 
 
 def _lowered(cfg, impl):
@@ -174,3 +175,41 @@ def test_pallas_kernels_carry_stable_names():
                     yield from names(sub)
 
     assert set(names(jaxpr.jaxpr)) == {"digc_topk", "mrconv"}
+
+
+def _digc_work(cfg, lanes):
+    """The tick's DIGC counters from the plans: per block, live lanes x
+    N x k (``digc_lists``) and x k*d (``digc_candidates``)."""
+    plans = vig.vig_stage_plans(cfg)
+    return {"digc_lists": lanes * sum(p.n * k for p in plans
+                                      for k in p.k_effs),
+            "digc_candidates": lanes * sum(
+                p.n * k * d for p in plans
+                for k, d in zip(p.k_effs, p.dilations))}
+
+
+def _ramp():
+    return _iso().replace(depths=(4,), num_knn=(3, 3, 4, 5))
+
+
+@pytest.mark.parametrize("maker", [_iso, _pyr, _ramp],
+                         ids=["iso", "pyr", "ramp"])
+def test_digc_work_counters_follow_the_plans(maker):
+    cfg = maker()
+    eng = _engine(cfg)
+    _serve(eng, _images(cfg, 4, 0))
+    assert "digc_lists" not in eng.tracer.totals()["counters"]
+    eng.tracer.recording = True
+    lanes = (3, 2)
+    for t, n in enumerate(lanes):
+        _serve(eng, _images(cfg, n, t + 1), uid0=10 * (t + 1))
+    if cfg.num_knn is not None:
+        assert [k for p in vig.vig_stage_plans(cfg)
+                for k in p.k_effs] == [3, 3, 4, 5]
+    want = _digc_work(cfg, sum(lanes))
+    counters = eng.tracer.totals()["counters"]
+    assert {k: counters[k] for k in want} == want
+    eng.tracer.recording = False
+    _serve(eng, _images(cfg, 2, 9), uid0=90)
+    counters = eng.tracer.totals()["counters"]
+    assert {k: counters[k] for k in want} == want

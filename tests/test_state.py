@@ -2,6 +2,8 @@
 jitted forwards, runtime-gated warm starts, donation, and parity with
 the legacy eager DigcCache shim."""
 
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -106,6 +108,52 @@ def test_state_row_lifecycle_take_put_reset():
     np.testing.assert_allclose(np.asarray(r.centroids[2]),
                                np.asarray(a.centroids[2]))
     assert back.row_steps() == {"s": [4, 0, 3, 1]}
+
+
+def test_state_take_put_rows_are_one_compiled_call_each():
+    """``DigcState.take_rows`` / ``put_rows`` over a state of several
+    entries gather and scatter every entry's rows (padding lanes
+    dropped on the way back) in one compiled call each, compiled once
+    per number of rows: a new set of rows of the same count reuses the
+    program."""
+    from repro.core import state as state_mod
+
+    rng = np.random.default_rng(3)
+    entries = {
+        "a": state_entry(centroids_shape=(6, 2, 3), sq_y_shape=(6, 5),
+                         rows=6),
+        "b": state_entry(sq_y_shape=(6, 4), rows=6),
+    }
+    entries = {k: dataclasses.replace(
+        e, step=jnp.int32(5),
+        **{f: _rand(rng, *v.shape).astype(v.dtype)
+           for f, v in e.row_buffers().items()})
+        for k, e in entries.items()}
+    st = DigcState.init(entries)
+    takes0 = state_mod._take_rows._cache_size()
+    puts0 = state_mod._put_rows._cache_size()
+    for rows, lanes in (([4, 1, 4, 4], [4, 1]), ([0, 5, 0, 0], [0, 5])):
+        bucket = st.take_rows(rows)
+        served = DigcState.init({
+            k: e.bump(**{f: v + 1 for f, v in e.row_buffers().items()
+                         if f != "row_step"})
+            for k, e in bucket.entries.items()})
+        back = st.put_rows(served, lanes)
+        for k, e in st.entries.items():
+            got_take = bucket.entries[k].row_buffers()
+            got_put = back.entries[k].row_buffers()
+            assert int(bucket.entries[k].step) == 5
+            assert int(back.entries[k].step) == 6
+            for f, v in e.row_buffers().items():
+                v = np.asarray(v)
+                np.testing.assert_array_equal(np.asarray(got_take[f]),
+                                              v[rows])
+                want = v.copy()
+                want[lanes] = np.asarray(
+                    served.entries[k].row_buffers()[f])[:len(lanes)]
+                np.testing.assert_array_equal(np.asarray(got_put[f]), want)
+    assert state_mod._take_rows._cache_size() == takes0 + 1
+    assert state_mod._put_rows._cache_size() == puts0 + 1
 
 
 # ---------------------------------------------------------------------------

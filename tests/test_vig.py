@@ -128,6 +128,131 @@ def test_resolution_dilation_scales_above_native():
     assert set(flat.dilations) == {1}
 
 
+# The official code's ViG-B schedule: [int(x) for x in
+# torch.linspace(9, 18, 16)], block i at dilation min(i // 4 + 1, 10).
+B_KNN = (9, 9, 10, 10, 11, 12, 12, 13, 13, 14, 15, 15, 16, 16, 17, 18)
+B_DIL = (1,) * 4 + (2,) * 4 + (3,) * 4 + (4,) * 4
+
+
+def test_vig_b_iso_plans_the_published_schedule():
+    cfg = vig.VIG_VARIANTS["vig_b_iso"]
+    assert cfg.embed_dims == (640,) and cfg.depths == (16,)
+    assert cfg.num_knn == B_KNN and cfg.max_dilation == 10
+    (plan,) = vig.vig_stage_plans(cfg)
+    assert plan.ks == plan.k_effs == B_KNN and plan.dilations == B_DIL
+    assert plan.spec.k == 9 and plan.n == plan.m == 196
+    assert sum(k * d for k, d in zip(plan.k_effs, plan.dilations)) == 573
+    assert vig.VIG_VARIANTS["vig_s_iso"].num_knn == B_KNN
+    with pytest.raises(ValueError, match="num_knn"):
+        cfg.replace(depths=(12,))
+
+
+def test_resolution_k_ramps_each_blocks_own_k():
+    cfg = vig.VIG_VARIANTS["vig_b_iso"]
+    (doubled,) = vig.vig_stage_plans(cfg, grid=28)
+    assert doubled.ks == tuple(2 * k for k in B_KNN)
+    assert doubled.dilations == tuple(2 * d for d in B_DIL)
+    (half_up,) = vig.vig_stage_plans(cfg, grid=21)
+    assert half_up.ks == tuple(vig._resolution_k(k, 21, 14) for k in B_KNN)
+    assert half_up.ks == tuple(int(round(1.5 * k)) for k in B_KNN)
+    for plan in (doubled, half_up):
+        assert all(k * d <= plan.m
+                   for k, d in zip(plan.k_effs, plan.dilations))
+
+
+def _lowered_text(cfg, size):
+    from repro.models.module import abstract_params
+
+    params = abstract_params(vig.vig_param_spec(cfg))
+    images = jax.ShapeDtypeStruct((2, size, size, 3), jnp.float32)
+    st = vig.init_vig_state(cfg, 2, None, per_slot=True,
+                            grid=size // cfg.patch)
+    f = jax.jit(lambda p, im, st: vig.vig_forward(p, im, cfg, state=st))
+    return f.lower(params, images, st).as_text()
+
+
+@pytest.mark.parametrize("name,size", [("vig_ti_iso", 64), ("vig_ti_iso", 128),
+                                       ("vig_s_pyr", 64)])
+def test_uniform_schedule_is_the_scheduleless_program(name, size):
+    """``num_knn`` of one k in every block plans and lowers exactly as
+    ``num_knn=None``: the configurations without a ramp serve the
+    program they served before the schedule existed."""
+    cfg = vig.VIG_VARIANTS[name].replace(image_size=size)
+    assert cfg.num_knn is None
+    flat = cfg.replace(num_knn=(cfg.k,) * sum(cfg.depths))
+    for grid in (None, 2 * cfg.base_grid):
+        assert vig.vig_stage_plans(cfg, grid=grid) == vig.vig_stage_plans(
+            flat, grid=grid)
+    assert vig.count_digc_work(cfg) == vig.count_digc_work(flat)
+    assert _lowered_text(cfg, size) == _lowered_text(flat, size)
+
+
+def _ramp_iso(knn=(3, 3, 4, 5)):
+    return vig.VIG_VARIANTS["vig_ti_iso"].replace(
+        image_size=32, patch=8, embed_dims=(16,), depths=(len(knn),),
+        num_classes=5, k=knn[0], num_knn=knn)
+
+
+def test_per_block_k_reaches_the_accounting_and_the_reuse_replay():
+    """``count_digc_work`` reports each block's own k, and the
+    stale-graph replay builds each call's lists at the width its block
+    served: only the blocks of the cached graph's k can reuse it."""
+    from repro.core import DigcSpec
+    from repro.core.tuner import tune_reuse
+
+    cfg = _ramp_iso()
+    assert [w["k"] for w in vig.count_digc_work(cfg)] == [3, 3, 4, 5]
+    assert [w["k"] for w in vig.count_digc_work(cfg, grid=8)] == [6, 6, 8, 10]
+    params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(0))
+    imgs = jax.random.normal(jax.random.PRNGKey(1), (3, 1, 32, 32, 3))
+    spec = DigcSpec(impl="blocked", k=3)
+
+    def frac(c):
+        ticks = []
+        for im in imgs:
+            cap = []
+            vig.vig_forward(params, im, c, digc_impl=spec, digc_capture=cap)
+            assert [i.shape[-1] for *_, i in cap] == list(
+                c.num_knn or (c.k,) * 4)
+            ticks.append(cap)
+        _, results = tune_reuse(ticks, spec=spec, policy="tick", taus=(1e9,),
+                                max_stale=100)
+        return results[0].reuse_frac
+
+    # "tick" reuses every call after a stage's first: blocks 1-3 of each
+    # tick and, after the first tick, block 0 too; under the ramp only
+    # block 1 shares block 0's k
+    assert frac(cfg.replace(num_knn=None)) == pytest.approx(11 / 12)
+    assert frac(cfg) == pytest.approx(5 / 12)
+
+
+def test_reuse_state_under_a_ramp_caches_the_first_blocks_k():
+    """The stale-graph buffers are sized by a stage's first block; under
+    a ramp a block of another k builds its own lists and leaves them."""
+    from repro.core import DigcSpec
+
+    cfg = _ramp_iso()
+    spec = DigcSpec(impl="blocked", k=3, reuse="tick", drift_tau=1e9,
+                    max_stale=100)
+    st = vig.init_vig_state(cfg, 2, spec, per_slot=True)
+    assert st.entries["stage0"].graph_idx.shape == (2, 16, 3)
+    params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(0))
+    imgs = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32, 3))
+    answers = []
+    for _ in range(2):
+        cap = []
+        logits, st = vig.vig_forward(params, imgs, cfg, digc_impl=spec,
+                                     state=st, digc_capture=cap)
+        assert [i.shape for *_, i in cap] == [(2, 16, k) for k in (3, 3, 4, 5)]
+        assert st.entries["stage0"].graph_idx.shape == (2, 16, 3)
+        answers.append(np.asarray(logits))
+    # block 1 serves block 0's graph (the "tick" policy), so the answer
+    # departs from the plain forward's; blocks 2 and 3 build their own
+    assert all(np.isfinite(a).all() for a in answers)
+    assert not np.array_equal(answers[0], np.asarray(
+        vig.vig_forward(params, imgs, cfg)))
+
+
 @pytest.mark.slow
 def test_vig_training_reduces_loss():
     from repro.data.pipeline import DataConfig, synth_image_batch
