@@ -180,8 +180,9 @@ def digc_blocked(
     Two-level tiling: the whole batch advances through each
     (block_n x block_m) tile together, so live memory is
     O(B * block_n * block_m) — never O(B * N * M). ``merge`` selects
-    the LSM/GMM realization ("select" exact grouped extraction,
-    "topk" concat+top_k, "packed" tie-tolerant packed keys);
+    the per-tile LSM: "topk" (the default) one exact ``lax.top_k`` per
+    tile, "select" exact grouped extraction in kd rounds, "packed"
+    tie-tolerant packed keys;
     ``fuse_norms`` folds the norm terms into the distance matmul
     (tie-tolerant), ``mxu_bf16`` runs the contraction in bf16.
     """

@@ -2,9 +2,9 @@
 
 Every exact XLA tier routes through ``stream_topk``, the engine's one
 entry point. It reproduces the paper's module split at the XLA level —
-DCM (a distance tile per grid step), LSM (``select_topkd``, a grouped
-local selection), GMM (a global merge of per-tile survivors) — with
-two structural upgrades over the PR-1 ``digc_blocked``:
+DCM (a distance tile per grid step), LSM (each tile's own top-kd), GMM
+(a global merge of per-tile survivors) — with two structural upgrades
+over the PR-1 ``digc_blocked``:
 
 * **Two-level tiling.** The query dimension N tiles as well as the
   co-node dimension M (``block_n`` x ``block_m`` grid, outer scan over
@@ -13,20 +13,20 @@ two structural upgrades over the PR-1 ``digc_blocked``:
   resolution ViG stages (N = 12544+) stream through a cache-sized
   working set instead of materializing 100+ MB of distance rows.
 * **Merge strategies.** The LSM/GMM realization is a knob
-  (``DigcSpec.merge``), because the best selection algorithm is
-  backend-dependent (measured, see ``core/tuner.py``):
+  (``DigcSpec.merge``). ``"topk"`` and ``"select"`` share one skeleton —
+  each co-node tile keeps its own top-kd (LSM), then one ``lax.top_k``
+  over the ``nb_m * kd`` survivors (GMM) — and differ only in the LSM:
 
-    - ``"select"`` (default) — grouped two-level extraction: each
-      distance tile is reshaped to (groups, width<=32) lanes, a
-      per-group running min is maintained, and each of the kd rounds
-      touches only the winning group (one gather + O(G + w) lane ops)
-      instead of the full tile. Exact, ties to the lowest index —
-      bit-identical indices to ``lax.top_k``. This replaces the
-      concat + ``lax.top_k`` merge whose cost is a scalar selection
-      sweep over every candidate (~kd * M per query row, independent
-      of block size — why PR-1's block_m sweep was flat).
-    - ``"topk"`` — the PR-1 merge (concatenate + ``lax.top_k``), kept
-      as the oracle merge and for backends where fused top_k wins.
+    - ``"topk"`` (default, ``merge=None``) — one exact ``lax.top_k``
+      over the tile's W columns: a data-independent op over full rows
+      whose cost barely moves with kd. Ties go to the lowest column.
+      On a TPU v5e it beat ``"select"`` at every shape the ViG cells
+      serve (PERF.md §6); on the CPU too (``BENCH_digc.json``).
+    - ``"select"`` — grouped two-level extraction (``select_topkd``):
+      each tile is reshaped to (groups, width<=32) lanes, a per-group
+      running min is kept, and each of kd dependent rounds touches only
+      the winning group. Exact, bit-identical to ``"topk"``; its cost is
+      linear in kd. Opt-in, and a tuner candidate.
     - ``"packed"`` — single-int32 packed-key min/mask merge
       (``core/packedkey.py``), the XLA mirror of the Pallas kernel's
       packed path. Tie-tolerant (truncated distances), halves merge
@@ -50,6 +50,7 @@ tracing, where a cached value would be baked in as a stale constant).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -170,12 +171,12 @@ def select_topkd(d_blk: jax.Array, kd: int, group_w: int = _SELECT_GROUP_W):
 # GMM merge bodies
 
 
-def merge_topk_xla(run_d, run_i, blk_d, blk_i, kd: int):
-    """Concat + ``lax.top_k`` merge (the PR-1 GMM analogue)."""
-    cand_d = jnp.concatenate([run_d, blk_d], axis=-1)
-    cand_i = jnp.concatenate([run_i, blk_i], axis=-1)
-    neg_top, sel = lax.top_k(-cand_d, kd)
-    return -neg_top, jnp.take_along_axis(cand_i, sel, axis=-1)
+def topk_lsm(d: jax.Array, kd: int):
+    """Exact top-kd of each row of ``d`` (..., N, W), ascending: one
+    ``lax.top_k`` over the row, ties to the lowest column. Returns
+    (dist (..., N, kd), col (..., N, kd)), ``col`` indexing into W."""
+    neg, col = lax.top_k(-d, kd)
+    return -neg, col
 
 
 def merge_packed_xla(run_k, blk_k, kd: int):
@@ -239,7 +240,7 @@ def stream_topk(
     static N-bucket with exact results on the live rows (DESIGN.md §13).
     """
     if merge is None:
-        merge = "select"
+        merge = "topk"
     if merge not in MERGE_STRATEGIES:
         raise ValueError(
             f"unknown merge strategy {merge!r}; one of {MERGE_STRATEGIES}"
@@ -337,25 +338,6 @@ def stream_topk(
                 return None
             return lax.dynamic_slice_in_dim(p_q, step * block_m, block_m, 2)
 
-        if merge == "select":
-            def step(carry, sm):
-                y_blk, sqy_blk, off, step_i = sm
-                d_blk, _ = tile_dists(y_blk, sqy_blk, off, p_blk_for(step_i))
-                vals, col = select_topkd(d_blk, kd, group_w=group_w)
-                return carry, (vals, off + col)
-
-            _, (vals, idxs) = lax.scan(
-                step, None,
-                (y_blocks, sqy_blocks, offsets,
-                 jnp.arange(nb_m, dtype=jnp.int32)),
-            )
-            if nb_m == 1:
-                return vals[0], idxs[0]
-            cd = vals.transpose(1, 2, 0, 3).reshape(b, bn, nb_m * kd)
-            ci = idxs.transpose(1, 2, 0, 3).reshape(b, bn, nb_m * kd)
-            neg, sel = lax.top_k(-cd, kd)
-            return -neg, jnp.take_along_axis(ci, sel, axis=-1)
-
         if merge == "packed":
             def step(run_k, sm):
                 y_blk, sqy_blk, off, step_i = sm
@@ -371,22 +353,31 @@ def stream_topk(
             )
             return unpack_keys(run_k, idx_bits)
 
-        def step(carry, sm):  # merge == "topk"
-            run_d, run_i = carry
-            y_blk, sqy_blk, off, step_i = sm
-            d_blk, cols = tile_dists(y_blk, sqy_blk, off, p_blk_for(step_i))
-            run_d, run_i = merge_topk_xla(run_d, run_i, d_blk, cols, kd)
-            return (run_d, run_i), None
+        # "topk" / "select": each tile keeps its own top-kw (LSM), then
+        # one lax.top_k over the nb_m * kw survivors (GMM). A tile
+        # narrower than kd keeps all of its columns: nb_m * W >= M >= kd.
+        kw = min(kd, block_m)
+        if merge == "select":
+            lsm = functools.partial(select_topkd, group_w=group_w)
+        else:
+            lsm = topk_lsm
 
-        init = (
-            jnp.full((b, bn, kd), BIG, jnp.float32),
-            jnp.zeros((b, bn, kd), jnp.int32),
-        )
-        (run_d, run_i), _ = lax.scan(
-            step, init,
+        def step(carry, sm):
+            y_blk, sqy_blk, off, step_i = sm
+            d_blk, _ = tile_dists(y_blk, sqy_blk, off, p_blk_for(step_i))
+            vals, col = lsm(d_blk, kw)
+            return carry, (vals, off + col)
+
+        _, (vals, idxs) = lax.scan(
+            step, None,
             (y_blocks, sqy_blocks, offsets, jnp.arange(nb_m, dtype=jnp.int32)),
         )
-        return run_d, run_i
+        if nb_m == 1:
+            return vals[0], idxs[0]
+        cd = vals.transpose(1, 2, 0, 3).reshape(b, bn, nb_m * kw)
+        ci = idxs.transpose(1, 2, 0, 3).reshape(b, bn, nb_m * kw)
+        dist, sel = topk_lsm(cd, kd)
+        return dist, jnp.take_along_axis(ci, sel, axis=-1)
 
     if block_n is None or block_n >= n:
         return run_queries(x_op, sq_x, pos_bias, jnp.int32(0))

@@ -232,14 +232,17 @@ def kernel_tile_defaults(
 
 # Per-backend throughput constants (seconds per unit). These are only
 # used to *rank* tile configurations before measurement refines them
-# (core/tuner.py), so rough magnitudes suffice; they were fitted to the
+# (core/tuner.py), so rough magnitudes suffice. CPU: fitted to the
 # measured CPU decomposition (gemm ~40 GFLOP/s, lax.top_k ~9 ns per
 # candidate row-element, fused elementwise lane ~1 ns, tile
-# materialization ~0.15 ns/byte).
+# materialization ~0.15 ns/byte). TPU: fitted to jitted ``stream_topk``
+# calls on a v5e (PERF.md §6): a ``select_topkd`` round costs 19-23 ns
+# per row over G + 2w = 71-72 lanes; the one-``lax.top_k``-per-tile
+# path 0.3 ns per candidate row-element at W = 196 and 1.2-1.9 ns at
+# M = 784 in four tiles of 256, final merge included.
 _ENGINE_CONSTANTS = {
     "cpu": dict(gemm=1 / 40e9, topk=9e-9, lane=1e-9, byte=1.5e-10),
-    # TPU: MXU gemm, VPU lanes; top_k lowers to sort — heavily penalized.
-    "tpu": dict(gemm=1 / 49e12, topk=2e-9, lane=1e-12, byte=1.2e-12),
+    "tpu": dict(gemm=1 / 49e12, topk=6e-10, lane=2.7e-10, byte=1.2e-12),
 }
 
 
@@ -252,7 +255,7 @@ def engine_cost_estimate(
     b: int = 1,
     block_n: int | None = None,
     block_m: int | None = None,
-    merge: str = "select",
+    merge: str = "topk",
     fuse_norms: bool = False,
     mxu_bf16: bool = False,
     backend: str = "cpu",
@@ -262,9 +265,10 @@ def engine_cost_estimate(
 
     Mirrors the engine's actual dataflow: a (block_n x block_m) tile
     grid, a DCM contraction + tile assembly per tile, and the selected
-    LSM/GMM merge. ``select`` costs one build pass over each tile plus
-    kd O(G + w) rounds; ``topk`` costs a kd-deep selection sweep over
-    every candidate (the term that made PR-1's block_m sweep flat);
+    LSM/GMM merge. ``topk`` (the engine's default) costs one
+    ``lax.top_k`` over every tile's columns; ``select`` one build pass
+    over each tile plus kd O(G + w) rounds; both then one ``lax.top_k``
+    over the tiles' survivors when there is more than one co-node tile.
     ``packed`` costs a pack pass plus kd min/mask passes.
     """
     c = _ENGINE_CONSTANTS.get(backend, _ENGINE_CONSTANTS["cpu"])
@@ -282,12 +286,13 @@ def engine_cost_estimate(
     # norms were folded into the contraction.
     assembly_s = tile_elems * 4 * c["byte"] * (1 if fuse_norms else 3)
 
+    kw = min(kd, bm)  # survivors per tile
+    final = 0.0 if nb_m == 1 else rows * nb_m * kw * c["topk"]
     if merge == "select":
         w = min(select_group_w, bm)
         groups = ceil_div(bm, w)
         build = tile_elems * c["lane"]
-        rounds = rows * nb_m * kd * (groups + 2 * w) * c["lane"]
-        final = 0.0 if nb_m == 1 else rows * nb_m * kd * c["topk"]
+        rounds = rows * nb_m * kw * (groups + 2 * w) * c["lane"]
         merge_s = build + rounds + final
     elif merge == "packed":
         # Bitonic two-level merge (core/packedkey networks): group sort
@@ -300,7 +305,7 @@ def engine_cost_estimate(
         ) * 1.5 * c["lane"]
         merge_s = pack + passes
     else:  # "topk"
-        merge_s = rows * nb_m * (kd + bm) * c["topk"]
+        merge_s = tile_elems * c["topk"] + final
 
     # Per-tile dispatch overhead (scan step launch, slices, transposes).
     overhead_s = nb_n * nb_m * 50e-6 if backend == "cpu" else 0.0

@@ -189,7 +189,7 @@ def test_property_select_merge_equals_reference(n, m, seed):
 # Full engine paths: merge strategies x tiling, ragged edges
 
 
-@pytest.mark.parametrize("merge", ["select", "topk"])
+@pytest.mark.parametrize("merge", [None, "select", "topk"])
 @pytest.mark.parametrize("block_n,block_m", [(None, 16), (16, 32), (13, 17)])
 def test_engine_exact_merges_match_reference(merge, block_n, block_m):
     rng = np.random.default_rng(hash((merge, block_n, block_m)) % 2**31)
@@ -202,7 +202,7 @@ def test_engine_exact_merges_match_reference(merge, block_n, block_m):
                                rtol=1e-5, atol=1e-4)
 
 
-@pytest.mark.parametrize("merge", ["select", "topk", "packed"])
+@pytest.mark.parametrize("merge", [None, "select", "topk", "packed"])
 def test_engine_query_tiled_causal_ragged(merge):
     """causal masking with N % block_n != 0: global row offsets must
     stay correct across query tiles."""
@@ -225,7 +225,7 @@ def test_engine_query_tiled_causal_ragged(merge):
         )
 
 
-@pytest.mark.parametrize("merge", ["select", "topk"])
+@pytest.mark.parametrize("merge", [None, "select", "topk"])
 def test_engine_query_tiled_pos_bias_ragged(merge):
     rng = np.random.default_rng(22)
     x, y = _rand(rng, 2, 37, 8), _rand(rng, 2, 53, 8)
@@ -234,6 +234,40 @@ def test_engine_query_tiled_pos_bias_ragged(merge):
     i_e = digc(x, y, k=4, impl="blocked", merge=merge, pos_bias=p,
                block_n=16, block_m=16)
     np.testing.assert_array_equal(np.asarray(i_r), np.asarray(i_e))
+
+
+def _edge_case(case):
+    """(x, y, kd, block_m, m_valid) of one edge of the per-tile LSM."""
+    rng = np.random.default_rng(31)
+    if case == "kd_over_block_m":  # tiles narrower than kd, nb_m > 1
+        return _rand(rng, 2, 40, 8), _rand(rng, 2, 70, 8), 20, 16, None
+    if case == "kd_eq_m":
+        return _rand(rng, 2, 30, 8), _rand(rng, 2, 24, 8), 24, 16, None
+    if case == "duplicate_conodes":  # exact ties: lowest column first
+        y = _rand(rng, 2, 12, 8)
+        return _rand(rng, 2, 30, 8), jnp.concatenate([y] * 5, 1), 9, 16, None
+    valid = np.arange(70) < 53  # pad co-node columns
+    return _rand(rng, 2, 40, 8), _rand(rng, 2, 70, 8), 6, 32, jnp.asarray(valid)
+
+
+@pytest.mark.parametrize(
+    "case", ["kd_over_block_m", "kd_eq_m", "duplicate_conodes", "m_valid"])
+def test_engine_default_select_and_reference_agree_bitwise(case):
+    """At the LSM's edges merge=None (one lax.top_k per tile) and
+    merge="select" give bit-identical indices and distances, and the
+    reference's indices. (The reference sums the distance's terms in
+    another order, so its distances agree to rounding.)"""
+    x, y, kd, block_m, m_valid = _edge_case(case)
+    i_r, d_r = digc_reference(x, y, k=kd, return_dists=True, m_valid=m_valid)
+    i_t, d_t = digc(x, y, k=kd, impl="blocked", block_m=block_m,
+                    m_valid=m_valid, return_dists=True)
+    i_s, d_s = digc(x, y, k=kd, impl="blocked", merge="select",
+                    block_m=block_m, m_valid=m_valid, return_dists=True)
+    np.testing.assert_array_equal(np.asarray(i_t), np.asarray(i_s))
+    np.testing.assert_array_equal(np.asarray(d_t), np.asarray(d_s))
+    np.testing.assert_array_equal(np.asarray(i_t), np.asarray(i_r))
+    np.testing.assert_allclose(np.asarray(d_t), np.asarray(d_r),
+                               rtol=1e-5, atol=1e-4)
 
 
 def test_engine_packed_full_path_tie_tolerant():

@@ -148,13 +148,21 @@ def test_named_scopes_land_in_the_program(maker):
         assert scope in text, scope
     if len(cfg.depths) > 1:
         assert "downsample0/" in text and "stage1/block0/digc/" in text
-    # the blocked tier's merge loops sit under the blocks' digc scopes
-    whiles = [re.search(r'op_name="([^"]*)"', ln)
-              for ln in lowered.compile().as_text().splitlines()
-              if re.search(r"^\s*%while\S* = .* while\(", ln)]
-    assert whiles
-    assert all(m and re.search(r"/stage\d+/block\d+/digc/", m.group(1))
-               for m in whiles)
+    # the blocked tier's top-k selection (a TopK call or a sort) and any
+    # loop left around its tiles sit under the blocks' digc scopes
+    lines = lowered.compile().as_text().splitlines()
+    digc = r"/stage\d+/block\d+/digc/"
+
+    def op_names(pattern):
+        return [re.search(r'op_name="([^"]*)"', ln)
+                for ln in lines if re.search(pattern, ln)]
+
+    selects = op_names(r'^\s*%\S+ = .* (sort\(|custom-call\(.*'
+                       r'custom_call_target="TopK")')
+    assert selects
+    assert all(m and re.search(digc, m.group(1)) for m in selects)
+    assert all(m and re.search(digc, m.group(1))
+               for m in op_names(r"^\s*%while\S* = .* while\("))
 
 
 def test_pallas_kernels_carry_stable_names():

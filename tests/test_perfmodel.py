@@ -1,5 +1,7 @@
 """The paper's Table I cycle model must reproduce exactly."""
 
+import inspect
+
 from repro.core import (
     FPGAConfig,
     digc_hbm_bytes,
@@ -8,6 +10,7 @@ from repro.core import (
     tpu_digc_estimate,
     vig_resolution_to_nodes,
 )
+from repro.core.perfmodel import engine_cost_estimate
 
 
 def test_table1_vig_tiny():
@@ -43,3 +46,16 @@ def test_tpu_estimate_fields():
     assert est["bound"] in ("compute", "memory", "merge")
     assert est["traffic_saving"] > 1
     assert est["latency_s"] > 0
+
+
+def test_tpu_prior_ranks_topk_lsm_over_select_at_vig_b_width():
+    """On a v5e one lax.top_k per tile took 0.106 ms against select's
+    2.132 ms at ViG-B's widest block (8 x 196 rows, M = 196, D = 640,
+    k*d = 72): the TPU prior must rank them so, and default to the
+    engine's own merge."""
+    kw = dict(b=8, block_m=256, backend="tpu")
+    topk = engine_cost_estimate(196, 196, 640, 72, merge="topk", **kw)
+    select = engine_cost_estimate(196, 196, 640, 72, merge="select", **kw)
+    assert 5 * topk["merge_s"] < select["merge_s"]
+    default = inspect.signature(engine_cost_estimate).parameters["merge"]
+    assert default.default == "topk"
