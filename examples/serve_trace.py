@@ -1,9 +1,9 @@
 """Trace-replay driver for the SLO-bounded admission scheduler
 (DESIGN.md §14).
 
-Replays the seeded Poisson+burst arrival trace (the same generator
-``benchmarks/bench_serve.py`` measures) through a ``VigServeEngine``
-under a ``VirtualClock``, twice:
+Replays the seeded Poisson+burst arrival trace (``serve.sched``'s
+``arrival_trace``) through a ``VigServeEngine`` under a
+``VirtualClock``, twice:
 
 * the exact-size baseline (``buckets=None``, ``slo_ms=0``): every
   arrival wave dispatches immediately at its own batch size;

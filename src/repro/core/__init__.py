@@ -34,7 +34,6 @@ from repro.core.digc import (
 )
 from repro.core.engine import (
     MERGE_STRATEGIES,
-    DigcCache,
     select_topkd,
     stream_topk,
 )

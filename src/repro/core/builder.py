@@ -182,9 +182,6 @@ class GraphBuilder:
     supports_pos_bias: bool = False
     supports_causal: bool = False
     distributed: bool = False
-    # Builders that can reuse DigcCache state (co-node norms, cluster
-    # centroids) accept build(..., cache=, cache_key=) keywords.
-    supports_cache: bool = False
     # Builders that thread functional DigcState (core/state.py) accept
     # build(..., state_entry=) and return (idx, dist, new_entry); for
     # everyone else digc() passes the state through unchanged.

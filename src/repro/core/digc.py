@@ -383,8 +383,6 @@ def digc(
     pos_bias: Optional[Array] = None,
     return_dists: bool = False,
     causal: Optional[bool] = None,
-    cache=None,
-    cache_key=None,
     state=None,
     state_key=None,
     reuse_first: bool = True,
@@ -415,17 +413,11 @@ def digc(
     the same forward pass (the ``"tick"`` policy reuses those
     unconditionally instead of re-gating).
 
-    ``cache``/``cache_key`` (a ``repro.core.engine.DigcCache`` plus a
-    caller-chosen identity for the reusable state, e.g. a model layer
-    name or a gallery version) are the legacy **eager shim** for the
-    same reuse: host-side, bypassed entirely under tracing. Mutually
-    exclusive with ``state``.
-
     ``fault_plan`` (a ``repro.core.faults.FaultPlan``) is the
     fault-injection hook (DESIGN.md §11): when set, the node features
     pass through the plan's ``digc.x`` site before construction —
     zero-overhead and a no-op when ``None``, and host-side only
-    (bypassed under tracing, like the eager cache).
+    (bypassed under tracing).
 
     ``m_valid`` ((M,) or (B, M) bool) marks live co-nodes; pad columns
     are BIG-norm-masked so they can never enter a live row's top-k
@@ -448,11 +440,6 @@ def digc(
     x3, y3, p3, squeeze = promote_batch(x, y, pos_bias)
     y_arg = None if y is None else y3
     if state is not None:
-        if cache is not None:
-            raise ValueError(
-                "digc() takes either functional state= or the legacy "
-                "eager cache=, not both"
-            )
         entry = state.get(state_key)
         if builder.supports_state and entry is not None:
             # The stale-graph reuse gate (DESIGN.md §12) wraps every
@@ -471,13 +458,7 @@ def digc(
         if return_dists:
             return idx, dist, state
         return idx, state
-    if cache is not None and builder.supports_cache:
-        idx, dist = builder.build(
-            x3, y_arg, p3, spec, cache=cache, cache_key=cache_key,
-            **_pad_kw(m_valid),
-        )
-    else:
-        idx, dist = builder.build(x3, y_arg, p3, spec, **_pad_kw(m_valid))
+    idx, dist = builder.build(x3, y_arg, p3, spec, **_pad_kw(m_valid))
     if squeeze:
         idx, dist = idx[0], dist[0]
     if return_dists:
@@ -527,11 +508,10 @@ def _build_blocked(x, y, pos_bias, spec: DigcSpec, state_entry=None,
     # Exact tier: no implicit cache reads. Per-call norm reuse
     # (self-graph ||x||^2 == ||y||^2) happens inside the engine; a
     # caller serving a *fixed* co-node gallery passes precomputed norms
-    # explicitly via digc_blocked(sq_y=cache.norms(gallery_key, y)) or
-    # through a functional state entry carrying sq_y — an implicit
-    # cache keyed by call-site would silently serve stale norms once
-    # the co-node contents change (e.g. per-layer pooled features),
-    # corrupting an exact tier.
+    # explicitly via digc_blocked(sq_y=...) or through a functional
+    # state entry carrying sq_y — an implicit cache keyed by call-site
+    # would silently serve stale norms once the co-node contents change
+    # (e.g. per-layer pooled features), corrupting an exact tier.
     sq_y = None
     new_entry = None
     if state_entry is not None:

@@ -21,7 +21,7 @@ over the PR-1 ``digc_blocked``:
       over the tile's W columns: a data-independent op over full rows
       whose cost barely moves with kd. Ties go to the lowest column.
       On a TPU v5e it beat ``"select"`` at every shape the ViG cells
-      serve (PERF.md §6); on the CPU too (``BENCH_digc.json``).
+      serve (PERF.md §6).
     - ``"select"`` — grouped two-level extraction (``select_topkd``):
       each tile is reshaped to (groups, width<=32) lanes, a per-group
       running min is kept, and each of kd dependent rounds touches only
@@ -34,24 +34,19 @@ over the PR-1 ``digc_blocked``:
 
 * **Norm reuse.** ``||y||^2`` is computed once per call, shared with
   the self-graph ``||x||^2`` when y is None, accepted precomputed via
-  ``sq_y=`` (the ``DigcCache`` serving hook), and optionally folded
+  ``sq_y=`` (a frozen gallery's norms, carried by a ``DigcState``
+  entry), and optionally folded
   into the distance matmul itself (``fuse_norms``: operands augmented
   to [-2x, 1, ||x||^2] / [y, ||y||^2, 1] so the whole distance tile is
   one contraction — no separate broadcast-add passes over the tile).
   ``fuse_norms`` changes fp32 summation order, so it is tie-tolerant
   rather than bit-exact; it is off unless the tuner measures it a win.
-
-``DigcCache`` carries reusable graph-construction state across layers
-and requests (co-node norms, cluster centroids/assignments). It is a
-host-side cache: it only engages on concrete arrays (never under
-tracing, where a cached value would be baked in as a stale constant).
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
-from typing import Any, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -77,7 +72,7 @@ MERGE_STRATEGIES = ("select", "topk", "packed")
 # 64 are supported with a two-word mask (``DigcSpec.group_w``): fewer
 # groups to reduce over per round, at the price of a second mask word
 # and a wider per-round gather — whether that wins is workload- and
-# backend-dependent (measured in benchmarks/bench_kernel.py).
+# backend-dependent.
 _SELECT_GROUP_W = 32
 _SELECT_GROUP_W_MAX = 64
 
@@ -229,8 +224,8 @@ def stream_topk(
 
     ``block_m=None`` streams the whole co-node set in one tile;
     ``block_n=None`` disables query tiling (PR-1 behavior). ``sq_y``
-    accepts precomputed co-node squared norms (B, M) — the
-    ``DigcCache`` hook for serving a fixed co-node gallery.
+    accepts precomputed co-node squared norms (B, M) — the hook a
+    ``DigcState`` entry uses to serve a fixed co-node gallery.
 
     ``m_valid`` is an (M,) or (B, M) bool mask of *live* co-nodes: pad
     co-nodes take the same BIG-norm masking the internal tile padding
@@ -407,74 +402,3 @@ def stream_topk(
     dist = dist_q.transpose(1, 0, 2, 3).reshape(b, n_pad, kd)[:, :n]
     idx = idx_q.transpose(1, 0, 2, 3).reshape(b, n_pad, kd)[:, :n]
     return dist, idx
-
-
-# ---------------------------------------------------------------------------
-# Cross-layer / cross-request cache
-
-
-@dataclasses.dataclass
-class DigcCache:
-    """Host-side cache for reusable graph-construction state — the
-    **legacy eager shim**; new code should thread the functional
-    ``repro.core.state.DigcState`` pytree instead, which carries the
-    same state *through* ``jit`` (DESIGN.md §7).
-
-    Holds co-node squared norms (serving a fixed gallery), cluster
-    centroids (layer-to-layer / request-to-request k-means warm
-    starts) and any other builder state, keyed by (kind, caller key).
-    Strictly eager: entries are only read or written for concrete
-    arrays — under ``jit`` tracing the cache is bypassed entirely,
-    because a cached value captured by a trace would be baked into the
-    compiled program as a stale constant.
-    """
-
-    max_entries: int = 256
-    _store: dict = dataclasses.field(default_factory=dict)
-    hits: int = 0
-    misses: int = 0
-
-    @staticmethod
-    def usable(*arrays) -> bool:
-        """Cache only engages outside tracing (concrete values)."""
-        return not any(isinstance(a, jax.core.Tracer) for a in arrays)
-
-    def get(self, kind: str, key: Any):
-        entry = self._store.get((kind, key))
-        if entry is None:
-            self.misses += 1
-        else:
-            self.hits += 1
-        return entry
-
-    def put(self, kind: str, key: Any, value) -> None:
-        if not self.usable(*jax.tree_util.tree_leaves(value)):
-            return
-        if len(self._store) >= self.max_entries:
-            self._store.pop(next(iter(self._store)))
-        self._store[(kind, key)] = value
-
-    def norms(self, key: Any, y: jax.Array) -> jax.Array:
-        """||y||^2 for a co-node set identified by ``key``.
-
-        The key must identify the co-node *contents* (e.g. a gallery
-        version tag) — shapes alone are not enough.
-        """
-        if not self.usable(y):
-            return jnp.sum(y.astype(jnp.float32) ** 2, axis=-1)
-        cached = self.get("sq_y", key)
-        if cached is not None and cached.shape == y.shape[:-1]:
-            return cached
-        sq = jnp.sum(y.astype(jnp.float32) ** 2, axis=-1)
-        self.put("sq_y", key, sq)
-        return sq
-
-    def stats(self) -> dict:
-        return {
-            "entries": len(self._store),
-            "hits": self.hits,
-            "misses": self.misses,
-        }
-
-    def clear(self) -> None:
-        self._store.clear()

@@ -1,17 +1,11 @@
-"""Functional DIGC state (DESIGN.md §7): the jit-native successor to
-the host-side ``DigcCache``.
-
-The paper's FPGA accelerator keeps its construction state (stream
-buffers, heap contents) resident on-chip across layers. Our analogue —
-cluster centroids for k-means warm starts, co-node norms for a frozen
-gallery — used to live in a mutable host-side ``DigcCache``, which by
-design never engages under tracing; serving the cache-aware tiers
-therefore meant running them *eager*. ``DigcState`` makes that state an
-explicit pytree value instead: it is threaded in-and-out of ``digc()``
+"""Functional DIGC state (DESIGN.md §7): the graph-construction state
+that persists across layers and requests — cluster centroids for
+k-means warm starts, co-node norms for a frozen gallery, cached graphs
+— held as an explicit pytree that is threaded in-and-out of ``digc()``
 (``digc(..., state=, state_key=) -> (idx, new_state)``), through
 ``vig_forward``, and through a single donated ``jax.jit`` in
-``serve.VigServeEngine`` — warm starts now work *inside* compiled
-serving, and the buffers are donated so the state updates in place.
+``serve.VigServeEngine``, so warm starts work *inside* compiled serving
+and the donated buffers update in place.
 
 Layout: ``DigcState.entries`` maps a caller-chosen key (e.g. the model
 stage name) to a ``DigcStateEntry``:

@@ -14,7 +14,7 @@ static shapes (TPU-compilable), and are batched-first — (B, N, D) in,
   * ``axial_digc``   — GreedyViG-family axial construction: candidates
     restricted to the query's grid row + column. O(N·(H+W)·D).
 
-Approximate by design; recall measured in tests/benchmarks. Both are
+Approximate by design; recall measured in the tests. Both are
 registered GraphBuilders (DESIGN.md §4), peers of the exact tiers.
 """
 
@@ -37,7 +37,7 @@ def kmeans(y: jax.Array, n_clusters: int, iters: int = 5,
            seed: int = 0, init: Optional[jax.Array] = None) -> jax.Array:
     """Lightweight Lloyd's iterations. y (M, D) -> centroids (C, D).
 
-    ``init`` warm-starts from previous centroids (a DigcCache carry:
+    ``init`` warm-starts from previous centroids (a DigcState carry:
     consecutive ViG layers / serving requests drift slowly, so a warm
     start converges in 1-2 iterations instead of 5 from random init).
     """
@@ -99,7 +99,7 @@ def _cluster_index(y, *, n_clusters, cap, seed, iters=5, init_centroids=None):
     (centroids (C, D), members (C, cap) with pad id M).
 
     Hoisted out of the per-image search so it runs once when co-nodes
-    are shared across the batch, and so DigcCache can warm-start the
+    are shared across the batch, and so DigcState can warm-start the
     k-means from a previous layer's / request's centroids.
     """
     m = y.shape[0]
@@ -222,8 +222,8 @@ def cluster_digc(
     """Two-stage ANN graph construction (ClusterViG family).
 
     1. cluster co-nodes (k-means, static iters; ``init_centroids``
-       warm-starts from a previous layer/request via ``DigcCache`` or a
-       functional ``DigcState`` entry);
+       warm-starts from a previous layer/request via a functional
+       ``DigcState`` entry);
     2. bucket members into fixed-capacity cluster lists (overflow
        drops, like the MoE dispatch);
     3. per query: top-n_probe centroids, then top-k·d over the probed
@@ -246,9 +246,8 @@ def cluster_digc(
     bool vector makes validity **per batch row** (multi-tenant
     serving, DESIGN.md §9): each row gets the build its own validity
     selects — all-warm batches pay one build, mixed batches stage both
-    and select per row. With ``init_valid=None`` (the legacy eager
-    path), warm/cold is a trace-time choice: ``init_centroids``
-    present means warm.
+    and select per row. With ``init_valid=None`` warm/cold is a
+    trace-time choice: ``init_centroids`` present means warm.
 
     ``return_state=True`` additionally returns {"centroids": (B, C, D)}
     for warm-starting the next call.
@@ -269,7 +268,7 @@ def cluster_digc(
     if init3 is not None and init3.ndim == 2:
         init3 = jnp.broadcast_to(init3[None], (b,) + init3.shape)
     if init3 is not None and init3.shape[1] != n_clusters:
-        init3 = None  # stale cache shape (workload changed): cold start
+        init3 = None  # stale centroid shape (workload changed): cold start
 
     def build_index(iters: int, init_b3, shared: bool = shared_y):
         def index_one(yb, init_b=None):
@@ -417,44 +416,19 @@ def recall_vs_exact(x, y, idx_approx, k: int) -> float:
 # Registry entries (DESIGN.md §4).
 
 
-def _build_cluster(x, y, pos_bias, spec: DigcSpec, cache=None, cache_key=None,
-                   state_entry=None):
+def _build_cluster(x, y, pos_bias, spec: DigcSpec, state_entry=None):
     del pos_bias  # validated unsupported upstream
     if state_entry is not None:
         return _build_cluster_stateful(x, y, spec, state_entry)
-    init = None
-    ckey = None
-    if cache is not None and cache_key is not None:
-        # An explicit key is required: two unrelated callers sharing a
-        # cache with matching shapes must not warm-start from each
-        # other's centroids.
-        from repro.core.engine import DigcCache
-
-        concrete = DigcCache.usable(x) and (y is None or DigcCache.usable(y))
-        if concrete:
-            m = y.shape[1] if y is not None else x.shape[1]
-            ckey = (cache_key, x.shape[0], m, x.shape[-1])
-            init = cache.get("cluster_centroids", ckey)
-    warm = init is not None
-    out = cluster_digc(
+    return cluster_digc(
         x, y, k=spec.k, dilation=spec.dilation,
         n_clusters=spec.n_clusters, n_probe=spec.n_probe,
         capacity_factor=(
             spec.capacity_factor if spec.capacity_factor is not None else 2.0
         ),
         seed=spec.seed if spec.seed is not None else 0,
-        # warm starts converge in 2 Lloyd iterations (features drift
-        # slowly layer-to-layer / request-to-request)
-        kmeans_iters=2 if warm else 5,
-        init_centroids=init,
         return_dists=True,
-        return_state=ckey is not None,
     )
-    if ckey is not None:
-        idx, dist, state = out
-        cache.put("cluster_centroids", ckey, state["centroids"])
-        return idx, dist
-    return out
 
 
 def _build_cluster_stateful(x, y, spec: DigcSpec, entry):
@@ -533,10 +507,9 @@ register(GraphBuilder(
     knobs=frozenset({"n_clusters", "n_probe", "capacity_factor", "seed"})
     | REUSE_KNOBS,
     exact=False,
-    supports_cache=True,
     supports_state=True,  # jit-native centroid warm starts via DigcState
     doc="ClusterViG-family IVF search: k-means index (shared co-nodes "
-        "indexed once, DigcState/DigcCache warm starts) + dispatch-form "
+        "indexed once, DigcState warm starts) + dispatch-form "
         "probe",
 ))
 

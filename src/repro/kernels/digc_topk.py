@@ -85,7 +85,7 @@ def _bucket_reduce(blk_k, kd: int, rounds: int):
     O(rounds) passes instead of O(kd) — the LSM local-sort stage taken
     to its cheapest useful form. Per-tile approximate, but the global
     top-kd is spread across tiles, so end-to-end recall stays high
-    (measured in tests/benchmarks; rounds trades recall vs speed)."""
+    (measured in the tests; rounds trades recall vs speed)."""
     bn, bm = blk_k.shape
     g = kd
     w = bm // g

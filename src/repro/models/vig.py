@@ -415,7 +415,7 @@ def resolve_digc_spec(cfg: VigConfig,
 
 def grapher_block(bp, x, cfg: VigConfig, grid: int, r: int, dilation: int,
                   digc_spec: Optional[DigcSpec] = None,
-                  cache=None, layer_key: Optional[str] = None,
+                  layer_key: Optional[str] = None,
                   state: Optional[DigcState] = None,
                   reuse_first: bool = True,
                   digc_capture: Optional[list] = None,
@@ -426,17 +426,13 @@ def grapher_block(bp, x, cfg: VigConfig, grid: int, r: int, dilation: int,
 
     Graph construction runs batched through the registry — no per-sample
     closure, no strategy branching; the builder supplies its fused
-    aggregation (e.g. the MRConv Pallas kernel) when it has one. Two
-    ways to carry construction state across layers and requests:
-
-    * ``state`` (a functional ``DigcState`` pytree, keyed by
-      ``layer_key``) — the jit-native path: stateful builders read and
-      return their entry *through* the trace, so warm starts work in
-      compiled serving. ``reuse_first`` marks the first block of a
-      stage within a forward pass — the gate point of the ``"tick"``
-      stale-graph policy (DESIGN.md §12).
-    * ``cache`` (a ``DigcCache``) — the legacy eager shim: host-side,
-      bypassed under jit.
+    aggregation (e.g. the MRConv Pallas kernel) when it has one.
+    Construction state across layers and requests is ``state`` (a
+    functional ``DigcState`` pytree, keyed by ``layer_key``): stateful
+    builders read and return their entry *through* the trace, so warm
+    starts work in compiled serving. ``reuse_first`` marks the first
+    block of a stage within a forward pass — the gate point of the
+    ``"tick"`` stale-graph policy (DESIGN.md §12).
 
     ``digc_capture`` (a list) collects ``(layer_key, h, cond, idx)``
     per DIGC call — the probe hook the tuner's recall-floor
@@ -477,8 +473,7 @@ def grapher_block(bp, x, cfg: VigConfig, grid: int, r: int, dilation: int,
                               reuse_first=reuse_first,
                               m_valid=m_valid)  # (B, N, k)
         else:
-            idx = digc(h, cond, spec=dspec, cache=cache,
-                       cache_key=layer_key, m_valid=m_valid)  # (B, N, k)
+            idx = digc(h, cond, spec=dspec, m_valid=m_valid)  # (B, N, k)
     if digc_capture is not None:
         digc_capture.append((layer_key, h, cond, idx))
     aggregate = builder.aggregate if builder.aggregate is not None else mr_aggregate
@@ -494,7 +489,7 @@ def grapher_block(bp, x, cfg: VigConfig, grid: int, r: int, dilation: int,
 
 
 def run_stage(stage_params, x, cfg: VigConfig, plan: StagePlan, *,
-              cache=None, state: Optional[DigcState] = None,
+              state: Optional[DigcState] = None,
               digc_capture: Optional[list] = None,
               m_valid: Optional[jax.Array] = None):
     """Run one pipeline stage: ``plan.depth`` Grapher+FFN blocks over a
@@ -506,7 +501,7 @@ def run_stage(stage_params, x, cfg: VigConfig, plan: StagePlan, *,
             x, state = grapher_block(
                 stage_params[f"block{bi}"], x, cfg, plan.grid, plan.r,
                 plan.dilations[bi],
-                digc_spec=plan.spec.replace(k=plan.ks[bi]), cache=cache,
+                digc_spec=plan.spec.replace(k=plan.ks[bi]),
                 layer_key=plan.key, state=state, reuse_first=(bi == 0),
                 digc_capture=digc_capture, m_valid=m_valid,
             )
@@ -515,7 +510,6 @@ def run_stage(stage_params, x, cfg: VigConfig, plan: StagePlan, *,
 
 def vig_forward(params, images, cfg: VigConfig, *,
                 digc_impl: Union[str, DigcSpec, "VigSchedule", None] = None,
-                cache=None,
                 state: Optional[DigcState] = None,
                 digc_capture: Optional[list] = None,
                 valid_mask: Optional[jax.Array] = None):
@@ -527,16 +521,13 @@ def vig_forward(params, images, cfg: VigConfig, *,
     DESIGN.md §12): patchify → stem → per-stage Grapher blocks (with
     the graph index treated as a cached, versioned state artifact when
     the spec carries a ``reuse`` policy) → downsample → head.
-    Construction state across blocks and requests comes in two forms:
-
-    * ``state`` — a functional ``DigcState`` (see ``init_vig_state``):
-      the call returns ``(logits, new_state)`` and is fully
-      jit-compatible; blocks in a stage share a state key, so layer
-      l+1 warm-starts from layer l, and feeding the returned state into
-      the next call warm-starts request-to-request *inside* the
-      compiled program.
-    * ``cache`` — the legacy eager ``DigcCache`` shim (host-side,
-      bypassed under jit); returns logits only.
+    Construction state across blocks and requests is ``state``, a
+    functional ``DigcState`` (see ``init_vig_state``): the call then
+    returns ``(logits, new_state)`` and is fully jit-compatible; blocks
+    in a stage share a state key, so layer l+1 warm-starts from layer
+    l, and feeding the returned state into the next call warm-starts
+    request-to-request *inside* the compiled program. Without it the
+    call returns logits only.
 
     ``digc_capture`` (a list) collects every DIGC call's
     ``(layer_key, nodes, co_nodes, idx)`` — the recall-verification
@@ -584,7 +575,7 @@ def vig_forward(params, images, cfg: VigConfig, *,
     for plan in plans:
         with jax.named_scope(f"stage{plan.index}"):
             x, state = run_stage(
-                params[plan.key], x, cfg, plan, cache=cache, state=state,
+                params[plan.key], x, cfg, plan, state=state,
                 digc_capture=digc_capture, m_valid=valid_mask,
             )
         if plan.index + 1 < len(cfg.depths):
@@ -683,11 +674,11 @@ def vig_loss_fn(params, batch, cfg: VigConfig):
 
 def count_digc_work(cfg: VigConfig, *, grid: Optional[int] = None):
     """Per-image DIGC workload (N, M, D, k, dilation) per block — feeds
-    the paper-table benchmarks. Reads the same ``vig_stage_plans`` the
-    forward executes (including, with ``grid=``, an off-native serving
-    resolution and its scaled k, and a per-block ``num_knn`` schedule:
-    ``k`` is the block's own, before the co-node clamps), so the
-    accounting can never drift from the model."""
+    the tuner's per-stage rows (``VigServeEngine._stage_rows``). Reads
+    the same ``vig_stage_plans`` the forward executes (including, with
+    ``grid=``, an off-native serving resolution and its scaled k, and a
+    per-block ``num_knn`` schedule: ``k`` is the block's own, before the
+    co-node clamps), so the accounting can never drift from the model."""
     out = []
     for plan in vig_stage_plans(cfg, grid=grid):
         d = cfg.embed_dims[plan.index]

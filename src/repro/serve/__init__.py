@@ -2,5 +2,4 @@
 # LM path (per-slot cache commit masks), and the multi-tenant bucketed
 # ViG image engine (DESIGN.md §9): fixed slots, request batches padded
 # to a static bucket set, one donated jax.jit + per-slot functional
-# DigcState rows per bucket (per-bucket VigSchedule autotuning; the
-# eager DigcCache path survives as the mode="eager" compatibility shim).
+# DigcState rows per bucket (per-bucket VigSchedule autotuning).

@@ -344,16 +344,10 @@ class VigServeEngine:
       includes the batch size — a B=8 tile is not a B=1 tile). Later
       engine instances with the same tuner path skip the measurement
       (host-keyed JSON cache).
-
-    ``mode="eager"`` is the legacy compatibility shim: cache-aware
-    tiers run eager with the host-side ``DigcCache`` (the PR-2
-    behavior), everything else jits statelessly. It exists for parity
-    testing and as an escape hatch; the jit path is the serving path
-    and the only one the multi-tenant request API supports.
     """
 
     def __init__(self, cfg, params, *, digc_impl=None, batch: int = 8,
-                 autotune: bool = True, tuner_path=None, mode: str = "jit",
+                 autotune: bool = True, tuner_path=None,
                  buckets: Optional[tuple] = DEFAULT_BUCKETS,
                  image_sizes: Optional[tuple] = None,
                  on_compile: Optional[Callable[[int], None]] = None,
@@ -367,13 +361,10 @@ class VigServeEngine:
                  slo_ms=0.0, clock: Optional[Callable[[], float]] = None,
                  prefetch: bool = True, bucket_cap: int = 4):
         from repro.core.builder import get_builder
-        from repro.core.engine import DigcCache
         from repro.models.vig import resolve_digc_spec, vig_stage_plans
 
         from repro.core.tuner import VigSchedule
 
-        if mode not in ("jit", "eager"):
-            raise ValueError(f"mode must be 'jit' or 'eager', got {mode!r}")
         # buckets="auto" defers the choice to the host tuner cache (the
         # arrival-histogram bucket-set optimizer, DESIGN.md §14); it is
         # materialized below, after image_sizes resolve, so the lookup
@@ -390,7 +381,6 @@ class VigServeEngine:
         self.params = params
         self.batch = batch
         self.spec = resolve_digc_spec(cfg, digc_impl)
-        self.mode = mode
         # -- multi-resolution lattice (DESIGN.md §13): the bucket grid
         # gains an N dimension. Each configured image size is an
         # N-bucket (N = (size/patch)^2 patch nodes); admission resolves
@@ -474,7 +464,6 @@ class VigServeEngine:
             self.spec = self.spec.replace(
                 mesh=mesh, axis_name=mesh_axis, batch_axis=mesh_batch_axis
             )
-        self.cache = DigcCache()  # engaged by the eager shim only
         self.autotune = autotune
         self.tuner_path = tuner_path
         # A pre-tuned VigSchedule may be passed directly as digc_impl
@@ -486,11 +475,10 @@ class VigServeEngine:
         self.schedule = digc_impl if self._user_schedule else None
         self.tuned = None  # per-stage TuneResults once warmed up
         self.requests_served = 0
-        self._jit_fwd = None  # eager shim's stateless fallback
-        # jit mode, direct path: batch size -> [compiled forward, DigcState]
+        # direct path: batch size -> [compiled forward, DigcState]
         self._compiled: dict[int, list] = {}
 
-        # -- multi-tenant request path (jit mode) -----------------------
+        # -- multi-tenant request path ----------------------------------
         self.buckets = buckets
         self.slots = max(buckets) if buckets is not None else batch
         self.on_compile = on_compile  # compile-counter hook (tests/ops)
@@ -922,7 +910,6 @@ class VigServeEngine:
         # Forwards compiled before the schedule existed bake the old
         # spec: drop them so the next request recompiles with it.
         self._compiled.clear()
-        self._jit_fwd = None
         return self.tuned
 
     def _impl_choice(self):
@@ -989,26 +976,6 @@ class VigServeEngine:
         self._compiled[b][1] = new_state
         return logits
 
-    def _infer_eager_shim(self, images) -> jax.Array:
-        from repro.core.builder import get_builder
-        from repro.models.vig import vig_forward
-
-        if get_builder(self.spec.impl).supports_cache:
-            # Eager so the host-side DigcCache engages across requests.
-            return vig_forward(
-                self.params, images, self.cfg,
-                digc_impl=self.spec, cache=self.cache,
-            )
-        # No reusable construction state: serve jitted, stateless —
-        # still through the tuned per-stage schedule when one exists,
-        # so eager vs jit mode differ only in the state threading.
-        choice = self._impl_choice()
-        if self._jit_fwd is None or self._jit_fwd[0] is not choice:
-            self._jit_fwd = (choice, jax.jit(
-                lambda p, im: vig_forward(p, im, self.cfg, digc_impl=choice)
-            ))
-        return self._jit_fwd[1](self.params, images)
-
     def infer(self, images) -> jax.Array:
         """images (B, H, W, C) -> logits (B, num_classes).
 
@@ -1019,10 +986,7 @@ class VigServeEngine:
         if (self.autotune and self.tuned is None and self.schedule is None
                 and self.spec.impl == "blocked"):
             self.warmup()
-        if self.mode == "eager":
-            logits = self._infer_eager_shim(images)
-        else:
-            logits = self._infer_jit(images)
+        logits = self._infer_jit(images)
         self.requests_served += int(images.shape[0])
         return logits
 
@@ -1560,11 +1524,6 @@ class VigServeEngine:
         mixing cells in a tick would need a second program anyway."""
         if not self.queue:
             return 0
-        if self.mode != "jit":
-            raise RuntimeError(
-                "the multi-tenant request path serves through the jitted "
-                "functional-state forward; construct with mode='jit'"
-            )
         cell, eligible = self._select_cell()
         if cell is None:
             # Scheduler deferral (slo_ms > 0): no cell is ripe — wait
@@ -1905,8 +1864,7 @@ class VigServeEngine:
             (c[0], *(live(v) for v in arr)) for c, arr in zip(cap, arrays)]
 
     def stats(self) -> dict:
-        out = {"requests_served": self.requests_served, "mode": self.mode,
-               "digc_cache": self.cache.stats(),
+        out = {"requests_served": self.requests_served,
                "digc_state": self.state_steps(),
                "buckets": self.buckets,
                "image_sizes": self.image_sizes,

@@ -5,7 +5,7 @@ and the arrival-trace tooling behind the SLO-bounded admission queue.
 ``clock=`` knob): time only moves when the harness advances it, so a
 replayed trace dispatches identically run over run — deadlines become
 exact comparisons instead of races, which is what makes the scheduler
-property tests and the ``serve/sched_*`` bench rows reproducible.
+property tests and ``examples/serve_trace.py`` reproducible.
 
 ``arrival_trace`` draws the seeded Poisson + bursty request stream the
 ROADMAP acceptance bar names: a memoryless trickle of mixed-size
@@ -13,9 +13,7 @@ singletons punctuated by synchronized flash crowds — the workload
 shape where exact-size programs burn their time on per-tick overhead
 and bucketed programs burn theirs on padding, i.e. exactly the regime
 the admission queue and the bucket-set optimizer are built for.
-``benchmarks/bench_serve.py`` and ``examples/serve_trace.py`` share
-this generator so the committed rows and the example replay the same
-workload.
+``examples/serve_trace.py`` replays it.
 """
 
 from __future__ import annotations

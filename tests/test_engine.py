@@ -1,5 +1,5 @@
 """Streaming-engine correctness: merge strategies, two-level tiling,
-packed keys, and the DigcCache.
+packed keys, and DIGC state threaded through the served forward.
 
 The exact merges ("select", "topk") must match the reference oracle
 bit-for-bit on indices; the packed merge is tie-tolerant (distances
@@ -18,7 +18,6 @@ from _hypothesis_compat import given, settings, st
 from repro.core import BIG, DigcSpec, digc, digc_reference, pairwise_sq_dists
 from repro.core.digc import merge_topk
 from repro.core.engine import (
-    DigcCache,
     merge_packed_xla,
     select_topkd,
     stream_topk,
@@ -331,71 +330,32 @@ def test_engine_unknown_merge_raises():
 
 
 # ---------------------------------------------------------------------------
-# DigcCache
-
-
-def test_cache_norms_roundtrip_and_stats():
-    rng = np.random.default_rng(28)
-    y = _rand(rng, 2, 20, 6)
-    cache = DigcCache()
-    sq1 = cache.norms("gallery-v1", y)
-    sq2 = cache.norms("gallery-v1", y)
-    assert cache.stats()["hits"] == 1
-    np.testing.assert_array_equal(np.asarray(sq1), np.asarray(sq2))
-    np.testing.assert_allclose(
-        np.asarray(sq1), np.asarray(jnp.sum(y * y, -1)), rtol=1e-6
-    )
-
-
-def test_cache_bypassed_under_jit():
-    """Tracing must never read or write the cache (stale constants)."""
-    cache = DigcCache()
-
-    @jax.jit
-    def f(y):
-        return cache.norms("k", y)
-
-    rng = np.random.default_rng(29)
-    y1, y2 = _rand(rng, 4, 3), _rand(rng, 4, 3)
-    np.testing.assert_allclose(
-        np.asarray(f(y1)), np.asarray(jnp.sum(y1 * y1, -1)), rtol=1e-6
-    )
-    # second call with different data: a cached constant would be wrong
-    np.testing.assert_allclose(
-        np.asarray(f(y2)), np.asarray(jnp.sum(y2 * y2, -1)), rtol=1e-6
-    )
-    assert cache.stats()["entries"] == 0
+# DIGC state across calls and requests
 
 
 def test_cache_cluster_warm_start_recall():
-    """Warm-started cluster construction stays at cold-start recall."""
+    """Warm-started cluster construction (a DigcState entry carrying the
+    previous call's centroids) stays at cold-start recall."""
+    from repro.core import DigcState, state_entry
     from repro.core.strategies import recall_vs_exact
 
     rng = np.random.default_rng(30)
     x = _rand(rng, 2, 128, 16)
-    cache = DigcCache()
     spec = DigcSpec(impl="cluster", k=4, n_clusters=8, n_probe=8,
                     capacity_factor=8.0)
-    i_cold = digc(x, spec=spec, cache=cache, cache_key="layer0")
-    assert cache.stats()["entries"] == 1
-    i_warm = digc(x, spec=spec, cache=cache, cache_key="layer0")
-    assert cache.stats()["hits"] >= 1
+    st = DigcState.init({"layer0": state_entry(centroids_shape=(2, 8, 16))})
+    i_cold, st = digc(x, spec=spec, state=st, state_key="layer0")
+    assert st.steps() == {"layer0": 1}
+    i_warm, st = digc(x, spec=spec, state=st, state_key="layer0")
+    assert st.steps() == {"layer0": 2}
     # full probe + ample capacity: both must be exact
     assert recall_vs_exact(x, x, i_cold, 4) == 1.0
     assert recall_vs_exact(x, x, i_warm, 4) == 1.0
 
 
-def test_cache_eviction_bounded():
-    cache = DigcCache(max_entries=4)
-    for i in range(10):
-        cache.put("sq_y", f"k{i}", jnp.zeros((3,)))
-    assert cache.stats()["entries"] <= 4
-
-
 def test_vig_serve_engine_persists_state():
-    """VigServeEngine (jit mode, the default): the cluster tier serves
-    through the compiled forward with functional DigcState carried
-    across requests — no eager fallback, no DigcCache involvement."""
+    """VigServeEngine: the cluster tier serves through the compiled
+    forward with functional DigcState carried across requests."""
     from repro.models import vig
     from repro.models.module import init_params
     from repro.serve.engine import VigServeEngine
@@ -411,19 +371,16 @@ def test_vig_serve_engine_persists_state():
     assert out.shape == (2, 3) and bool(jnp.all(jnp.isfinite(out)))
     eng.infer(imgs)
     s = eng.stats()
-    assert s["requests_served"] == 4 and s["mode"] == "jit"
+    assert s["requests_served"] == 4
     # 2 blocks x 2 requests threaded the stage-0 state entry 4 times
-    # (layer 2 warm-starts from layer 1, request 2 from request 1) ...
+    # (layer 2 warm-starts from layer 1, request 2 from request 1).
     assert s["digc_state"][2]["stage0"] == 4
-    # ... and the host-side cache never engaged (fully jitted).
-    assert s["digc_cache"]["hits"] == 0 and s["digc_cache"]["entries"] == 0
 
 
 def test_vig_serve_engine_eager_shim_matches_jit():
-    """The legacy eager DigcCache shim (mode="eager") stays available
-    and parity-equal: same logits as the jitted functional-state path
-    for the cluster tier (deterministic seed), and its DigcCache still
-    engages across layers/requests."""
+    """infer() on the cluster tier equals vig_forward with the DigcState
+    threaded by hand, request over request (cold k-means on the first,
+    warm start from the carried centroids on the second)."""
     from repro.models import vig
     from repro.models.module import init_params
     from repro.serve.engine import VigServeEngine
@@ -434,16 +391,42 @@ def test_vig_serve_engine_eager_shim_matches_jit():
     )
     params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(0))
     imgs = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32, 3))
-    jit_eng = VigServeEngine(cfg, params, autotune=False)
-    eager_eng = VigServeEngine(cfg, params, autotune=False, mode="eager")
-    out_jit = jit_eng.infer(imgs)
-    out_eager = eager_eng.infer(imgs)
-    # First request: both sides cold-start the same k-means (same seed,
-    # same Lloyd schedule) — the shim and the pytree path must agree.
-    np.testing.assert_allclose(
-        np.asarray(out_jit), np.asarray(out_eager), rtol=1e-4, atol=1e-4
+    eng = VigServeEngine(cfg, params, autotune=False)
+    st = vig.init_vig_state(cfg, 2, "cluster")
+    for _ in range(2):
+        out_eng = eng.infer(imgs)
+        out_ref, st = vig.vig_forward(params, imgs, cfg,
+                                      digc_impl="cluster", state=st)
+        np.testing.assert_allclose(
+            np.asarray(out_eng), np.asarray(out_ref), rtol=1e-4, atol=1e-4
+        )
+    assert eng.state_steps()[2] == st.steps() == {"stage0": 4}
+
+
+def test_vig_serve_engine_state_bounded_over_requests():
+    """The served DigcState does not grow: after 5 infer() calls its
+    pytree has the shapes, dtypes and byte count it had at init."""
+    from repro.models import vig
+    from repro.models.module import init_params
+    from repro.serve.engine import VigServeEngine
+
+    cfg = vig.VIG_VARIANTS["vig_ti_iso"].replace(
+        image_size=32, embed_dims=(16,), depths=(2,), num_classes=3, k=3,
+        digc_impl="cluster",
     )
-    assert eager_eng.stats()["digc_cache"]["hits"] >= 1
+    params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(0))
+    eng = VigServeEngine(cfg, params, autotune=False)
+
+    def layout(state):
+        leaves = jax.tree_util.tree_leaves(state)
+        return ([(tuple(v.shape), v.dtype) for v in leaves],
+                sum(v.nbytes for v in leaves))
+
+    init = layout(vig.init_vig_state(cfg, 2, eng._impl_choice()))
+    for seed in range(5):
+        eng.infer(jax.random.normal(jax.random.PRNGKey(seed), (2, 32, 32, 3)))
+    assert eng.state_steps()[2] == {"stage0": 10}
+    assert layout(eng._compiled[2][1]) == init
 
 
 def test_vig_serve_engine_autotunes_blocked(tmp_path):
@@ -491,10 +474,11 @@ def test_vig_serve_engine_accepts_pretuned_schedule():
     assert eng.schedule is sched  # infer() did not re-tune over it
 
 
-def test_vig_serve_engine_eager_blocked_uses_tuned_schedule(tmp_path):
-    """mode="eager" must serve the blocked tier through the same tuned
-    schedule as jit mode (the modes differ only in state threading),
-    so warmup's measurement is never wasted."""
+def test_vig_serve_engine_eager_blocked_uses_tuned_schedule(tmp_path,
+                                                           monkeypatch):
+    """After warmup() the jitted infer() compiles its forward through
+    the tuned per-stage schedule, so warmup's measurement is never
+    wasted."""
     from repro.models import vig
     from repro.models.module import init_params
     from repro.serve.engine import VigServeEngine
@@ -503,16 +487,26 @@ def test_vig_serve_engine_eager_blocked_uses_tuned_schedule(tmp_path):
         image_size=32, embed_dims=(16,), depths=(1,), num_classes=3, k=3,
     )
     params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(0))
-    eng = VigServeEngine(cfg, params, batch=2, mode="eager",
+    eng = VigServeEngine(cfg, params, batch=2,
                          tuner_path=tmp_path / "tune.json")
+    eng.warmup()
+    assert eng.schedule is not None
+    traced = []
+    forward = vig.vig_forward
+
+    def spy(*args, **kw):
+        traced.append(kw["digc_impl"])
+        return forward(*args, **kw)
+
+    monkeypatch.setattr(vig, "vig_forward", spy)
     imgs = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32, 3))
     eng.infer(imgs)
-    assert eng.schedule is not None
-    assert eng._jit_fwd[0] is eng.schedule  # serving through the schedule
+    assert traced and all(c is eng.schedule for c in traced)
 
 
 def test_vig_forward_with_cache_matches_without():
-    """The cache must not change blocked-tier results (exact path)."""
+    """Threading a DigcState must not change blocked-tier results
+    (exact path)."""
     from repro.models import vig
     from repro.models.module import init_params
 
@@ -521,9 +515,10 @@ def test_vig_forward_with_cache_matches_without():
     )
     params = init_params(vig.vig_param_spec(cfg), jax.random.PRNGKey(0))
     imgs = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32, 3))
-    cache = DigcCache()
+    st = vig.init_vig_state(cfg, 2, "blocked")
     out_nc = vig.vig_forward(params, imgs, cfg)
-    out_c = vig.vig_forward(params, imgs, cfg, cache=cache)
+    out_c, st = vig.vig_forward(params, imgs, cfg, state=st)
+    assert st.steps() == {"stage0": 2}
     np.testing.assert_allclose(
         np.asarray(out_nc), np.asarray(out_c), rtol=1e-5, atol=1e-5
     )

@@ -330,12 +330,8 @@ def test_bucketed_compile_count_real_jit():
     assert all(r in (1, 2) for r in eng.stats()["bucket_ticks"])
 
 
-def test_bucketed_requires_jit_mode_and_valid_buckets():
+def test_bucketed_requires_valid_buckets():
     cfg, params = _tiny_vig("blocked")
-    eng = VigServeEngine(cfg, params, autotune=False, mode="eager")
-    eng.submit(VigRequest(uid=0, image=np.zeros((16, 16, 3), np.float32)))
-    with pytest.raises(RuntimeError, match="jit"):
-        eng.step()
     with pytest.raises(ValueError, match="buckets"):
         VigServeEngine(cfg, params, autotune=False, buckets=(0, 2))
     with pytest.raises(ValueError, match="active"):

@@ -1,6 +1,6 @@
 """Functional DIGC state (core/state.py): pytree round-trips through
-jitted forwards, runtime-gated warm starts, donation, and parity with
-the legacy eager DigcCache shim."""
+jitted forwards, runtime-gated warm starts, donation, and parity of the
+jitted forward with the un-jitted one."""
 
 import dataclasses
 
@@ -185,16 +185,6 @@ def test_digc_state_missing_entry_passthrough():
     np.testing.assert_array_equal(
         np.asarray(idx), np.asarray(digc(x, k=3, impl="blocked"))
     )
-
-
-def test_digc_state_and_cache_mutually_exclusive():
-    from repro.core.engine import DigcCache
-
-    rng = np.random.default_rng(2)
-    x = _rand(rng, 10, 4)
-    with pytest.raises(ValueError, match="not both"):
-        digc(x, k=2, impl="blocked", state=DigcState.init({}),
-             cache=DigcCache())
 
 
 def test_blocked_gallery_norms_jit_exact_and_counted():
@@ -436,23 +426,53 @@ def test_vig_forward_state_donation():
 
 
 def test_vig_forward_state_matches_eager_cache_shim():
-    """Pytree path vs the legacy eager DigcCache shim: same Lloyd
-    schedule (cold 5 iters, warm 2), so the cluster-tier logits agree
-    request over request."""
-    from repro.core.engine import DigcCache
-
+    """Jitted vs un-jitted functional forward on the cluster tier: same
+    Lloyd schedule (cold 5 iters, warm 2), so the logits and the carried
+    state agree request over request."""
     vig, cfg, params, imgs = _tiny_vig("cluster")
-    st = vig.init_vig_state(cfg, 2, "cluster")
-    cache = DigcCache()
+    fwd = jax.jit(lambda p, im, s: vig.vig_forward(
+        p, im, cfg, digc_impl="cluster", state=s))
+    st_j = st_e = vig.init_vig_state(cfg, 2, "cluster")
     for _ in range(2):
-        l_state, st = vig.vig_forward(params, imgs, cfg,
-                                      digc_impl="cluster", state=st)
-        l_cache = vig.vig_forward(params, imgs, cfg, digc_impl="cluster",
-                                  cache=cache)
+        l_jit, st_j = fwd(params, imgs, st_j)
+        l_eager, st_e = vig.vig_forward(params, imgs, cfg,
+                                        digc_impl="cluster", state=st_e)
         np.testing.assert_allclose(
-            np.asarray(l_state), np.asarray(l_cache), rtol=1e-4, atol=1e-4
+            np.asarray(l_jit), np.asarray(l_eager), rtol=1e-4, atol=1e-4
         )
-    assert cache.stats()["hits"] >= 1
+    assert st_j.steps() == st_e.steps() == {"stage0": 4}
+    np.testing.assert_allclose(
+        np.asarray(st_j.entries["stage0"].centroids),
+        np.asarray(st_e.entries["stage0"].centroids), rtol=1e-4, atol=1e-4,
+    )
+
+
+def test_jitted_state_forward_tracks_new_images():
+    """No stale constant across requests: one compiled forward with
+    state, fed two different image batches, equals the un-jitted
+    forward given the same state each time — nothing from the first
+    call is baked into the program."""
+    vig, cfg, params, imgs1 = _tiny_vig("cluster")
+    imgs2 = jax.random.normal(jax.random.PRNGKey(2), imgs1.shape)
+    traces = []
+
+    def forward(p, im, s):
+        traces.append(1)
+        return vig.vig_forward(p, im, cfg, digc_impl="cluster", state=s)
+
+    fwd = jax.jit(forward)
+    st = vig.init_vig_state(cfg, 2, "cluster")
+    outs = []
+    for imgs in (imgs1, imgs2):
+        l_eager, _ = vig.vig_forward(params, imgs, cfg,
+                                     digc_impl="cluster", state=st)
+        l_jit, st = fwd(params, imgs, st)
+        np.testing.assert_allclose(
+            np.asarray(l_jit), np.asarray(l_eager), rtol=1e-4, atol=1e-4
+        )
+        outs.append(np.asarray(l_jit))
+    assert not np.allclose(outs[0], outs[1])
+    assert len(traces) == 1  # one program served both requests
 
 
 def test_init_vig_state_pyramid_shapes():
