@@ -161,7 +161,7 @@ def digc_blocked(
     k: int,
     dilation: int = 1,
     pos_bias: Optional[Array] = None,
-    block_m: int = 256,
+    block_m: Optional[int] = None,
     block_n: Optional[int] = None,
     merge: Optional[str] = None,
     fuse_norms: bool = False,
@@ -179,17 +179,22 @@ def digc_blocked(
     backend; the Pallas kernel implements the same dataflow fused.
     Two-level tiling: the whole batch advances through each
     (block_n x block_m) tile together, so live memory is
-    O(B * block_n * block_m) — never O(B * N * M). ``merge`` selects
+    O(B * block_n * block_m). ``block_m=None`` sizes the co-node tile
+    from the shape (``engine.conode_tile``: the whole co-node set when
+    its distance block fits the engine's budget, else the fewest equal
+    tiles that do); an explicit ``block_m`` is kept. ``merge`` selects
     the per-tile LSM: "topk" (the default) one exact ``lax.top_k`` per
     tile, "select" exact grouped extraction in kd rounds, "packed"
     tie-tolerant packed keys;
     ``fuse_norms`` folds the norm terms into the distance matmul
     (tie-tolerant), ``mxu_bf16`` runs the contraction in bf16.
     """
-    from repro.core.engine import stream_topk
+    from repro.core.engine import conode_tile, stream_topk
 
     x3, y3, p3, squeeze = promote_batch(x, y, pos_bias)
     kd = k * dilation
+    b, n, _ = x3.shape
+    block_m = conode_tile(b, n, y3.shape[1], block_m, block_n)
     dist, idx = stream_topk(
         x3,
         None if y is None else y3,
@@ -546,7 +551,7 @@ def _build_blocked(x, y, pos_bias, spec: DigcSpec, state_entry=None,
     out = digc_blocked(
         x, y, k=spec.k, dilation=spec.dilation, pos_bias=pos_bias,
         causal=spec.causal, return_dists=True,
-        block_m=spec.block_m if spec.block_m is not None else 256,
+        block_m=spec.block_m,
         block_n=spec.block_n,
         merge=spec.merge,
         fuse_norms=bool(spec.fuse_norms),

@@ -76,9 +76,41 @@ MERGE_STRATEGIES = ("select", "topk", "packed")
 _SELECT_GROUP_W = 32
 _SELECT_GROUP_W_MAX = 64
 
+# Co-node tile sizing (``conode_tile``). Each tile's float32 distance
+# block is B x rows x block_m x 4 bytes. 64 MiB holds a whole 448 px
+# ViG graph (B 8, N = M = 784: 19.7 MB) and the pyramid's 224 px stage 0
+# (N 3136, M 196: 19.7 MB) in one tile, so those skip the second
+# top-k over per-tile survivors, while a 896 px self-graph (B 8, 3136^2:
+# 315 MB) still streams in a few tiles, a small share of a v5e's 16 GB.
+# Split tiles are 128-lane aligned and never narrower than 256 columns,
+# so no shape streams more tiles than a fixed 256-wide grid would.
+CONODE_TILE_BUDGET = 64 << 20
+CONODE_TILE_FLOOR = 256
+CONODE_TILE_ALIGN = 128
+
 
 def _ceil_to(v: int, mult: int) -> int:
     return ((v + mult - 1) // mult) * mult
+
+
+def conode_tile(b: int, n: int, m: int, block_m: Optional[int] = None,
+                block_n: Optional[int] = None) -> int:
+    """Co-node tile width of the streaming engine for B x N queries
+    against M co-nodes, the queries tiled by ``block_n`` when it is
+    below N. An explicit ``block_m`` is kept, clamped to [1, M].
+    Otherwise the whole co-node set is one tile when the float32
+    distance block of one query tile fits ``CONODE_TILE_BUDGET``; above
+    it, the fewest equal tiles that fit, 128-aligned, between
+    ``CONODE_TILE_FLOOR`` and M columns wide."""
+    if block_m is not None:
+        return max(1, min(block_m, m))
+    rows = block_n if block_n is not None and block_n < n else n
+    tile_bytes = b * rows * m * 4
+    if tile_bytes <= CONODE_TILE_BUDGET:
+        return m
+    t = -(-tile_bytes // CONODE_TILE_BUDGET)
+    width = _ceil_to(-(-m // t), CONODE_TILE_ALIGN)
+    return min(m, max(CONODE_TILE_FLOOR, width))
 
 
 # ---------------------------------------------------------------------------

@@ -587,8 +587,9 @@ class VigServeEngine:
         # Spans and counters of the tick (serve/tracer.py): off until a
         # caller that measures the engine switches it on.
         self.tracer = EngineTracer()
-        # (size, bucket) -> per-lane (lists, candidates), for the tracer
-        self._digc_work: dict[tuple, tuple[int, int]] = {}
+        # (size, bucket) -> per-lane (lists, candidates) and per-tick
+        # co-node tiles, for the tracer
+        self._digc_work: dict[tuple, tuple[int, int, int]] = {}
 
     # -- multi-resolution lattice plumbing (DESIGN.md §13) --------------
 
@@ -658,24 +659,33 @@ class VigServeEngine:
     def _count_digc(self, bucket: int, size: int, lanes: int) -> None:
         """Count the tick's DIGC work on the tracer, from the cell's
         stage plans (no pull): ``digc_lists``, the neighbour entries its
-        graphs hold (live lanes x N x k per block), and
-        ``digc_candidates``, what the top-(k*d) merge keeps before the
-        dilation stride (live lanes x N x k*d per block)."""
+        graphs hold (live lanes x N x k per block), ``digc_candidates``,
+        what the top-(k*d) merge keeps before the dilation stride (live
+        lanes x N x k*d per block), and ``digc_tiles``, the co-node tiles
+        the ``blocked`` tier's blocks stream (ceil(M / block_m) per
+        block, ``block_m`` as ``engine.conode_tile`` sizes it for the
+        tick's width; per tick, whatever the live lanes)."""
         if not self.tracer.recording:
             return
         key = (size, bucket)
         work = self._digc_work.get(key)
         if work is None:
+            from repro.core.engine import conode_tile
             from repro.models.vig import vig_stage_plans
 
             plans = vig_stage_plans(self.cfg, self._choice_for(bucket, size),
                                     grid=size // self.cfg.patch)
+            width = self._tick_width(bucket)
             work = self._digc_work[key] = (
                 sum(p.n * k for p in plans for k in p.k_effs),
                 sum(p.n * k * d for p in plans
-                    for k, d in zip(p.k_effs, p.dilations)))
+                    for k, d in zip(p.k_effs, p.dilations)),
+                sum(p.depth * -(-p.m // conode_tile(
+                    width, p.n, p.m, p.spec.block_m, p.spec.block_n))
+                    for p in plans if p.spec.impl == "blocked"))
         self.tracer.count("digc_lists", lanes * work[0])
         self.tracer.count("digc_candidates", lanes * work[1])
+        self.tracer.count("digc_tiles", work[2])
 
     # -- SLO-bounded admission scheduling (DESIGN.md §14) ---------------
 

@@ -12,9 +12,11 @@ engine switches it on:
   state-row resets and the slots they reset; ``digc_lists`` and
   ``digc_candidates``: the neighbour entries a tick's graphs hold and
   the top-(k*d) candidates DIGC keeps for them, summed over blocks and
-  live lanes from the stage plans). A span's self time is its
-  duration less the time its child spans cover, so the self times of a
-  root and everything under it add up to the root's duration.
+  live lanes from the stage plans; ``digc_tiles``: the co-node tiles
+  the ``blocked`` tier's blocks stream in a tick). A span's self time
+  is its duration less the time its child spans cover, so the self
+  times of a root and everything under it add up to the root's
+  duration.
 * ``annotating`` (with ``recording``, while a profiler trace runs) also
   enters a ``jax.profiler.TraceAnnotation`` of the span's name, which
   puts the span on the profiler's clock beside the device ops.

@@ -18,6 +18,10 @@ from _hypothesis_compat import given, settings, st
 from repro.core import BIG, DigcSpec, digc, digc_reference, pairwise_sq_dists
 from repro.core.digc import merge_topk
 from repro.core.engine import (
+    CONODE_TILE_ALIGN,
+    CONODE_TILE_BUDGET,
+    CONODE_TILE_FLOOR,
+    conode_tile,
     merge_packed_xla,
     select_topkd,
     stream_topk,
@@ -320,6 +324,50 @@ def test_stream_topk_self_graph_shares_norms():
     d_b, i_b = stream_topk(x, x, kd=4, block_m=16)
     np.testing.assert_array_equal(np.asarray(i_a), np.asarray(i_b))
     np.testing.assert_array_equal(np.asarray(d_a), np.asarray(d_b))
+
+
+@pytest.mark.parametrize("b,n,m,block_m,want", [
+    (1, 196, 196, None, 196),  # 224 px isotropic: one tile
+    (8, 196, 196, None, 196),
+    (8, 3136, 196, None, 196),  # 224 px pyramid stage 0: 19.7 MB
+    (1, 784, 784, None, 784),  # 448 px: one tile, not four of 256
+    (8, 784, 784, None, 784),
+    (8, 3136, 3136, None, 640),  # 896 px isotropic: 5 equal tiles
+    (8, 50176, 3136, None, 256),  # 896 px pyramid stage 0: the floor
+    (8, 784, 784, 256, 256),  # an explicit width is kept
+    (8, 196, 196, 256, 196),  # ... clamped to M
+    (2, 784, 784, 100, 100),
+])
+def test_conode_tile_sizes_from_the_shape(b, n, m, block_m, want):
+    width = conode_tile(b, n, m, block_m)
+    assert width == want
+    if block_m is None and width < m:
+        assert width % CONODE_TILE_ALIGN == 0
+        assert width >= CONODE_TILE_FLOOR
+        tiles = -(-m // width)
+        assert b * n * -(-m // (tiles - 1)) * 4 > CONODE_TILE_BUDGET
+    # a query tile of block_n rows sizes the co-node tile by its rows
+    assert conode_tile(b, n * 4, m, block_m, block_n=n) == want
+
+
+@pytest.mark.parametrize("b", [1, 2])
+@pytest.mark.parametrize("kd", [36, 72, 108])
+def test_engine_default_tile_matches_four_tiles_and_reference(kd, b):
+    """At 448 px (N = M = 784) the default co-node tile is the whole
+    M: its lists equal four tiles of 256 and a second top-k, and the
+    reference tier's, bit for bit. Integer features make every distance
+    exact whatever order a backend's matmul sums in (a CPU GEMM blocks
+    a 784-wide tile unlike a 256-wide one), and give thousands of exact
+    ties, which each path must break to the lowest column."""
+    rng = np.random.default_rng(kd * 10 + b)
+    x = jnp.asarray(rng.integers(-4, 5, (b, 784, 192)), jnp.float32)
+    i_1, d_1 = digc(x, k=kd, impl="blocked", return_dists=True)
+    i_4, d_4 = digc(x, k=kd, impl="blocked", block_m=256, return_dists=True)
+    i_r, d_r = digc(x, k=kd, impl="reference", return_dists=True)
+    assert (np.diff(np.asarray(d_1), axis=-1) == 0).any()
+    for i, d in ((i_4, d_4), (i_r, d_r)):
+        np.testing.assert_array_equal(np.asarray(i_1), np.asarray(i))
+        np.testing.assert_array_equal(np.asarray(d_1), np.asarray(d))
 
 
 def test_engine_unknown_merge_raises():

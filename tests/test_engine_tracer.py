@@ -126,7 +126,7 @@ def test_host_pulls_count_every_copy_to_the_host(maker, impl, guards):
     want = guard_pulls + _graph_pulls(state) + 1  # + the logits
     assert eng.tracer.totals()["counters"] == {
         "host_pulls": want, "reset_calls": 1, "reset_rows": n,
-        **_digc_work(cfg, n)}
+        **_digc_work(cfg, (n,))}
 
 
 def _lowered(cfg, impl):
@@ -186,14 +186,17 @@ def test_pallas_kernels_carry_stable_names():
 
 
 def _digc_work(cfg, lanes):
-    """The tick's DIGC counters from the plans: per block, live lanes x
-    N x k (``digc_lists``) and x k*d (``digc_candidates``)."""
+    """The DIGC counters of ticks of ``lanes`` live lanes each, from the
+    plans: per block, live lanes x N x k (``digc_lists``) and x k*d
+    (``digc_candidates``); and per tick one co-node tile per block
+    (``digc_tiles``: these small graphs fit one tile)."""
     plans = vig.vig_stage_plans(cfg)
-    return {"digc_lists": lanes * sum(p.n * k for p in plans
-                                      for k in p.k_effs),
-            "digc_candidates": lanes * sum(
+    return {"digc_lists": sum(lanes) * sum(p.n * k for p in plans
+                                           for k in p.k_effs),
+            "digc_candidates": sum(lanes) * sum(
                 p.n * k * d for p in plans
-                for k, d in zip(p.k_effs, p.dilations))}
+                for k, d in zip(p.k_effs, p.dilations)),
+            "digc_tiles": len(lanes) * sum(p.depth for p in plans)}
 
 
 def _ramp():
@@ -206,7 +209,8 @@ def test_digc_work_counters_follow_the_plans(maker):
     cfg = maker()
     eng = _engine(cfg)
     _serve(eng, _images(cfg, 4, 0))
-    assert "digc_lists" not in eng.tracer.totals()["counters"]
+    assert not {"digc_lists", "digc_tiles"} & set(
+        eng.tracer.totals()["counters"])
     eng.tracer.recording = True
     lanes = (3, 2)
     for t, n in enumerate(lanes):
@@ -214,10 +218,35 @@ def test_digc_work_counters_follow_the_plans(maker):
     if cfg.num_knn is not None:
         assert [k for p in vig.vig_stage_plans(cfg)
                 for k in p.k_effs] == [3, 3, 4, 5]
-    want = _digc_work(cfg, sum(lanes))
+    want = _digc_work(cfg, lanes)
     counters = eng.tracer.totals()["counters"]
     assert {k: counters[k] for k in want} == want
     eng.tracer.recording = False
     _serve(eng, _images(cfg, 2, 9), uid0=90)
     counters = eng.tracer.totals()["counters"]
     assert {k: counters[k] for k in want} == want
+
+
+@pytest.mark.parametrize("variant,size,block_m,tiles", [
+    ("vig_ti_iso", 224, None, 12),  # M = 196: one tile a block
+    ("vig_ti_iso", 448, None, 12),  # M = 784: one tile, not four
+    ("vig_ti_iso", 448, 256, 48),  # an explicit width streams four
+    ("vig_s_pyr", 224, None, 12),  # stage 0: N = 3136 over M = 196
+    ("vig_ti_iso", 896, None, 60),  # M = 3136: five tiles of 640
+])
+def test_digc_tiles_counts_conode_tiles_per_tick(variant, size, block_m,
+                                                 tiles):
+    """``digc_tiles`` counts the co-node tiles a bucket-8 tick's blocks
+    stream at published widths, once per tick whatever its live lanes,
+    and nothing while the tracer is off."""
+    cfg = vig.VIG_VARIANTS[variant]
+    params = jax.eval_shape(lambda: init_params(
+        vig.vig_param_spec(cfg), jax.random.PRNGKey(0)))
+    eng = VigServeEngine(cfg, params, image_sizes=(size,), autotune=False,
+                         digc_impl=DigcSpec(impl="blocked", block_m=block_m))
+    eng._count_digc(8, size, 3)
+    assert "digc_tiles" not in eng.tracer.totals()["counters"]
+    eng.tracer.recording = True
+    for lanes in (3, 8):
+        eng._count_digc(8, size, lanes)
+    assert eng.tracer.totals()["counters"]["digc_tiles"] == 2 * tiles
